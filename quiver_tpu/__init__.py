@@ -1,6 +1,6 @@
 """quiver_tpu — TPU-native graph-learning data engine.
 
-Ground-up JAX/XLA/Pallas re-design of torch-quiver (reference public API:
+Ground-up JAX/XLA re-design of torch-quiver (reference public API:
 srcs/python/quiver/__init__.py:2-17): GPU-class k-hop neighbor sampling over
 CSR topology, a tiered feature cache (chip HBM -> ICI peers -> host DRAM ->
 mmap disk), and multi-chip/multi-host scaling over ICI/DCN meshes.

@@ -15,7 +15,7 @@ Mini-batch GCN on sampled blocks follows DGL's GraphConv conventions:
   structural layout duplicates src nodes per edge, so out-degrees are all
   1 there — see the in-code note). The src-side out-degree count needs one
   scatter-add per layer over the hop's source width; scatters are the
-  expensive primitive on TPU (PERF_NOTES.md) — prefer "right" unless
+  expensive primitive on TPU (PERF.md (earlier claims)) — prefer "right" unless
   parity with a DGL norm='both' training run matters.
 """
 

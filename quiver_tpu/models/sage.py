@@ -72,7 +72,7 @@ class GraphSAGE(nn.Module):
     ``dtype=jnp.bfloat16`` runs every layer's compute in bf16 (params and
     returned logits stay float32, so losses/optimizers are unchanged) —
     the feature gather itself is row-rate-bound and dtype-invariant
-    (PERF_NOTES.md), so this buys matmul time and activation memory, not
+    (PERF.md (earlier claims)), so this buys matmul time and activation memory, not
     gather time."""
 
     hidden_dim: int

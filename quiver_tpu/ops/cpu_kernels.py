@@ -12,125 +12,107 @@ TPU-native replacement for the reference's two host/graph-too-big paths:
   host memory into kernels, so host-side sampling + async H2D is the
   replacement (SURVEY.md section 7.3 item 2).
 
-A pure-numpy fallback keeps everything working when the native lib is not
-built; outputs are bit-identical in shape/masking to the TPU path so models
-consume either interchangeably.
+The library is built from ``csrc/quiver_cpu.cpp`` whenever it is missing or
+older than that source, so what is loaded always matches the committed code.
+HOST-mode sampling needs it and fails loudly without it (`require_native`);
+the row gather and the reindex keep numpy mirrors that tests compare against.
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
+import subprocess
+import time
+import warnings
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 SENTINEL = np.iinfo(np.int64).max
 
+_CSRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "csrc")
+_SO = os.path.join(_CSRC, "libquiver_cpu.so")
+_SRC = os.path.join(_CSRC, "quiver_cpu.cpp")
+
 _LIB = None
 _LIB_TRIED = False
+# what the loader did in this process: seconds spent building (None = the
+# library on disk was already newer than the source) and, when the build or
+# the load failed, why
+_BUILD: Dict[str, object] = {"build_s": None, "error": None}
+
+_VP, _I64 = ctypes.c_void_p, ctypes.c_int64
+_SAMPLE_ARGS = [_VP, _VP, _I64, _VP, _I64, _I64, ctypes.c_uint64, _VP, _VP]
+# name -> argtypes (every entry point returns void). indptr/indices/seeds/
+# ids are int64*, masks uint8*, feature rows raw bytes
+_ABI = {
+    # indptr, indices, num_nodes, seeds, batch, k, rng seed, out nbrs [B*k],
+    # out valid [B*k]
+    "qt_sample_layer": _SAMPLE_ARGS,
+    # same, with float32 weights (CSR edge order) inserted third
+    "qt_sample_layer_weighted": _SAMPLE_ARGS[:2] + [_VP] + _SAMPLE_ARGS[2:],
+    # src [N, D] f32, N, D, ids [B], B, out [B, D]
+    "qt_gather_rows": [_VP, _I64, _I64, _VP, _I64, _VP],
+    # src bytes, N rows, row bytes, ids [B], B, out bytes
+    "qt_gather_rows_bytes": [_VP, _I64, _I64, _VP, _I64, _VP],
+    # head [seed_count], seed_count, nbrs [total], mask [total], total,
+    # out n_id [seed_count+total], out count, out local int32 [total]
+    "qt_reindex": [_VP, _I64, _VP, _VP, _I64, _VP, _VP, _VP],
+}
+
+
+def _build_native() -> None:
+    """Build libquiver_cpu.so unless it is already newer than its source.
+    The compiler writes a per-process temp name that is renamed into place,
+    so concurrent builders (spawned sampler workers) never load half a
+    file."""
+    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
+        return
+    tmp = f"libquiver_cpu.so.{os.getpid()}.tmp"
+    t0 = time.perf_counter()
+    try:
+        proc = subprocess.run(
+            ["make", "-C", _CSRC, f"TARGET={tmp}"],
+            capture_output=True, text=True, timeout=300,
+        )
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"make -C {_CSRC} failed ({proc.returncode}):\n"
+                + (proc.stderr or proc.stdout)[-2000:]
+            )
+        os.replace(os.path.join(_CSRC, tmp), _SO)
+    finally:
+        if os.path.exists(os.path.join(_CSRC, tmp)):
+            os.remove(os.path.join(_CSRC, tmp))
+    _BUILD["build_s"] = time.perf_counter() - t0
 
 
 def _load_native():
-    """Load libquiver_cpu.so, building it on first use if a toolchain is
-    around (see csrc/Makefile); else None and numpy fallbacks apply."""
+    """The loaded native library, or None when it cannot be built or loaded
+    here — said once, as a warning with the compiler's own words, never
+    silently."""
     global _LIB, _LIB_TRIED
     if _LIB_TRIED:
         return _LIB
     _LIB_TRIED = True
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    csrc = os.path.join(here, "csrc")
-    if not os.path.exists(os.path.join(csrc, "libquiver_cpu.so")) and os.path.exists(
-        os.path.join(csrc, "Makefile")
-    ):
-        import subprocess
-
-        try:
-            subprocess.run(
-                ["make", "-C", csrc],
-                check=False,
-                stdout=subprocess.DEVNULL,
-                stderr=subprocess.DEVNULL,
-                timeout=120,
-            )
-        except Exception:
-            pass
-    for cand in (
-        os.path.join(csrc, "libquiver_cpu.so"),
-        os.path.join(here, "libquiver_cpu.so"),
-    ):
-        if os.path.exists(cand):
-            try:
-                lib = ctypes.CDLL(cand)
-                lib.qt_sample_layer.argtypes = [
-                    ctypes.c_void_p,  # indptr int64*
-                    ctypes.c_void_p,  # indices int64*
-                    ctypes.c_int64,   # num_nodes
-                    ctypes.c_void_p,  # seeds int64*
-                    ctypes.c_int64,   # batch
-                    ctypes.c_int64,   # k
-                    ctypes.c_uint64,  # rng seed
-                    ctypes.c_void_p,  # out neighbors int64* [B*k]
-                    ctypes.c_void_p,  # out valid uint8* [B*k]
-                ]
-                lib.qt_sample_layer.restype = None
-                lib.qt_gather_rows.argtypes = [
-                    ctypes.c_void_p,  # src float32* [N, D]
-                    ctypes.c_int64,   # N
-                    ctypes.c_int64,   # D
-                    ctypes.c_void_p,  # ids int64* [B]
-                    ctypes.c_int64,   # B
-                    ctypes.c_void_p,  # out float32* [B, D]
-                ]
-                lib.qt_gather_rows.restype = None
-                try:
-                    lib.qt_sample_layer_weighted.argtypes = [
-                        ctypes.c_void_p,  # indptr int64*
-                        ctypes.c_void_p,  # indices int64*
-                        ctypes.c_void_p,  # weights float32* (CSR edge order)
-                        ctypes.c_int64,   # num_nodes
-                        ctypes.c_void_p,  # seeds int64*
-                        ctypes.c_int64,   # batch
-                        ctypes.c_int64,   # k
-                        ctypes.c_uint64,  # rng seed
-                        ctypes.c_void_p,  # out neighbors int64* [B*k]
-                        ctypes.c_void_p,  # out valid uint8* [B*k]
-                    ]
-                    lib.qt_sample_layer_weighted.restype = None
-                except AttributeError:
-                    pass  # stale .so; uniform native path still works
-                try:
-                    lib.qt_gather_rows_bytes.argtypes = [
-                        ctypes.c_void_p,  # src bytes*
-                        ctypes.c_int64,   # N rows
-                        ctypes.c_int64,   # row bytes
-                        ctypes.c_void_p,  # ids int64*
-                        ctypes.c_int64,   # batch
-                        ctypes.c_void_p,  # out bytes*
-                    ]
-                    lib.qt_gather_rows_bytes.restype = None
-                except AttributeError:
-                    pass  # stale .so; f32 gather + numpy fallback still work
-                try:
-                    lib.qt_reindex.argtypes = [
-                        ctypes.c_void_p,  # head int64* [seed_count]
-                        ctypes.c_int64,   # seed_count
-                        ctypes.c_void_p,  # nbrs int64* [total]
-                        ctypes.c_void_p,  # mask uint8* [total]
-                        ctypes.c_int64,   # total
-                        ctypes.c_void_p,  # out n_id int64* [seed_count+total]
-                        ctypes.c_void_p,  # out count int64*
-                        ctypes.c_void_p,  # out local int32* [total]
-                    ]
-                    lib.qt_reindex.restype = None
-                except AttributeError:
-                    # stale .so from before qt_reindex existed: the numpy
-                    # reindex fallback still applies, sampling stays native
-                    pass
-                _LIB = lib
-            except OSError:
-                _LIB = None
-            break
+    try:
+        _build_native()
+        lib = ctypes.CDLL(_SO)
+        for name, argtypes in _ABI.items():
+            fn = getattr(lib, name)
+            fn.argtypes = argtypes
+            fn.restype = None
+        _LIB = lib
+    except (OSError, RuntimeError, AttributeError, subprocess.SubprocessError) as exc:
+        _BUILD["error"] = f"{type(exc).__name__}: {exc}"
+        warnings.warn(
+            f"native host engine unavailable ({_BUILD['error']}); host "
+            "gathers fall back to numpy and HOST-mode sampling will refuse "
+            "to run",
+            RuntimeWarning,
+            stacklevel=2,
+        )
     return _LIB
 
 
@@ -138,39 +120,25 @@ def native_available() -> bool:
     return _load_native() is not None
 
 
-def _np_sample_layer(
-    indptr: np.ndarray,
-    indices: np.ndarray,
-    seeds: np.ndarray,
-    k: int,
-    seed: int,
-) -> Tuple[np.ndarray, np.ndarray]:
-    """Numpy fallback for one-hop sampling; exact k-subset w/o replacement,
-    copy-all when deg <= k (reference cuda_random.cu.hpp:33-38 semantics)."""
-    rng = np.random.default_rng(seed)
-    B = seeds.shape[0]
-    nbrs = np.zeros((B, k), np.int64)
-    valid = np.zeros((B, k), bool)
-    # mirror the native guard (csrc/quiver_cpu.cpp): out-of-range seeds
-    # produce an invalid (deg=0) row instead of wrapping/raising
-    node_count = indptr.shape[0] - 1
-    in_range = (seeds >= 0) & (seeds < node_count)
-    safe = np.where(in_range, seeds, 0)
-    starts = indptr[safe]
-    degs = np.where(in_range, indptr[safe + 1] - starts, 0)
-    for i in range(B):
-        deg = int(degs[i])
-        if deg <= 0:
-            continue
-        start = int(starts[i])
-        if deg <= k:
-            nbrs[i, :deg] = indices[start : start + deg]
-            valid[i, :deg] = True
-        else:
-            pos = rng.choice(deg, size=k, replace=False)
-            nbrs[i] = indices[start + pos]
-            valid[i] = True
-    return nbrs, valid
+def native_engine_info() -> Dict[str, object]:
+    """Which host engine this process runs: ``native`` (bool), ``build_s``
+    (seconds spent building it here, None when the library on disk was
+    already newer than the source) and ``error`` (why it is unavailable)."""
+    return {"native": native_available(), **_BUILD}
+
+
+def require_native(what: str):
+    """The native library, for a path that must not run without it: the
+    numpy stand-in for HOST-mode sampling is a per-row Python loop that
+    takes minutes at products scale, so quietly carrying on would hide the
+    engine the caller asked for."""
+    lib = _load_native()
+    if lib is None:
+        raise RuntimeError(
+            f"{what} needs the native host engine (make -C quiver_tpu/csrc): "
+            f"{_BUILD['error']}"
+        )
+    return lib
 
 
 def host_reindex(
@@ -189,7 +157,7 @@ def host_reindex(
     seeds = np.asarray(seeds, np.int64)
     head = seeds[:seed_count]
     lib = _load_native()
-    if lib is not None and hasattr(lib, "qt_reindex"):
+    if lib is not None:
         total = S * k
         head_c = np.ascontiguousarray(head, np.int64)
         nbrs_c = np.ascontiguousarray(nbrs, np.int64)
@@ -231,9 +199,8 @@ class HostSampler:
     ``weights`` (optional, float32, CSR edge order — e.g.
     ``CSRTopo.edge_weights``) switches every draw to the weighted k-subset
     engine (`qt_sample_layer_weighted`, same Efraimidis-Spirakis/Gumbel
-    distribution as the device op). Weighted mode requires the native lib
-    (no numpy fallback — the per-row weighted loop would be minutes-slow
-    at scale, and silence would hide it)."""
+    distribution as the device op). Construction fails without the native
+    library (`require_native`)."""
 
     def __init__(
         self,
@@ -243,14 +210,9 @@ class HostSampler:
     ):
         self.indptr = np.ascontiguousarray(indptr, np.int64)
         self.indices = np.ascontiguousarray(indices, np.int64)
-        self._lib = _load_native()
+        self._lib = require_native("HOST-mode sampling (HostSampler)")
         self.weights = None
         if weights is not None:
-            if self._lib is None or not hasattr(self._lib, "qt_sample_layer_weighted"):
-                raise RuntimeError(
-                    "weighted host sampling needs the native engine "
-                    "(make -C quiver_tpu/csrc); rebuild libquiver_cpu.so"
-                )
             self.weights = np.ascontiguousarray(weights, np.float32)
             if self.weights.shape[0] != self.indices.shape[0]:
                 raise ValueError(
@@ -264,30 +226,28 @@ class HostSampler:
 
     def sample_layer(self, seeds: np.ndarray, k: int, seed: int):
         seeds = np.ascontiguousarray(seeds, np.int64)
-        if self._lib is not None:
-            B = seeds.shape[0]
-            nbrs = np.empty((B, k), np.int64)
-            valid_u8 = np.empty((B, k), np.uint8)
-            # one arg list for both ABIs: the weighted entry point takes the
-            # identical signature with the weights pointer inserted third
-            args = [
-                self.indptr.ctypes.data,
-                self.indices.ctypes.data,
-                self.node_count,
-                seeds.ctypes.data,
-                B,
-                k,
-                ctypes.c_uint64(seed),
-                nbrs.ctypes.data,
-                valid_u8.ctypes.data,
-            ]
-            if self.weights is not None:
-                args.insert(2, self.weights.ctypes.data)
-                self._lib.qt_sample_layer_weighted(*args)
-            else:
-                self._lib.qt_sample_layer(*args)
-            return nbrs, valid_u8.astype(bool)
-        return _np_sample_layer(self.indptr, self.indices, seeds, k, seed)
+        B = seeds.shape[0]
+        nbrs = np.empty((B, k), np.int64)
+        valid_u8 = np.empty((B, k), np.uint8)
+        # one arg list for both ABIs: the weighted entry point takes the
+        # identical signature with the weights pointer inserted third
+        args = [
+            self.indptr.ctypes.data,
+            self.indices.ctypes.data,
+            self.node_count,
+            seeds.ctypes.data,
+            B,
+            k,
+            ctypes.c_uint64(seed),
+            nbrs.ctypes.data,
+            valid_u8.ctypes.data,
+        ]
+        if self.weights is not None:
+            args.insert(2, self.weights.ctypes.data)
+            self._lib.qt_sample_layer_weighted(*args)
+        else:
+            self._lib.qt_sample_layer(*args)
+        return nbrs, valid_u8.astype(bool)
 
     def sample_multilayer(
         self,
@@ -344,8 +304,8 @@ def gather_rows(table: np.ndarray, ids: np.ndarray) -> np.ndarray:
     (the reference's gather kernel is float32-only,
     quiver_feature.cu:65-69). Out-of-range ids (negative or >= N) return
     zero rows — one contract on EVERY path: the native byte/f32 engines
-    zero-fill in C, and the numpy fallback masks invalid ids and zeroes
-    their rows so behavior does not depend on which .so is loaded."""
+    zero-fill in C, and the numpy fallback (non-contiguous or object
+    tables, or no native library) masks invalid ids and zeroes their rows."""
     lib = _load_native()
     ids = np.ascontiguousarray(ids, np.int64)
     plain = (
@@ -354,26 +314,12 @@ def gather_rows(table: np.ndarray, ids: np.ndarray) -> np.ndarray:
         and not table.dtype.hasobject  # object rows are PyObject* — memcpy
         #                                would skip refcounting (crash at GC)
     )
-    if lib is not None and plain and hasattr(lib, "qt_gather_rows_bytes"):
+    if lib is not None and plain:
         out = np.empty((ids.shape[0], table.shape[1]), table.dtype)
         lib.qt_gather_rows_bytes(
             table.ctypes.data,
             table.shape[0],
             table.shape[1] * table.itemsize,
-            ids.ctypes.data,
-            ids.shape[0],
-            out.ctypes.data,
-        )
-        return out
-    if lib is not None and plain and table.dtype == np.float32:
-        # stale .so predating qt_gather_rows_bytes: the f32 entry point is
-        # still there — keep the hot cold-tier path multi-threaded (and its
-        # zero-OOB contract) instead of silently dropping to numpy
-        out = np.empty((ids.shape[0], table.shape[1]), np.float32)
-        lib.qt_gather_rows(
-            table.ctypes.data,
-            table.shape[0],
-            table.shape[1],
             ids.ctypes.data,
             ids.shape[0],
             out.ctypes.data,

@@ -58,7 +58,7 @@ def pad_widths(batch: int, sizes, caps=None):
 def row_windows(indptr: jax.Array, s: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """(row start, degree) for CLIPPED node ids ``s`` — as ONE dim-2 gather
     instead of two element gathers. TPU gathers are descriptor-rate bound
-    and width-invariant up to ~128 lanes (PERF_NOTES.md), so pairing
+    and width-invariant up to ~128 lanes (PERF.md (earlier claims)), so pairing
     (indptr[i], indptr[i+1]) into an [N, 2] table halves the degree-lookup
     descriptors (measured 43.6 -> 41.5 ms on the products e2e step). The
     stack is loop-invariant: CSE'd across hops and hoisted out of epoch
@@ -198,7 +198,7 @@ def weighted_sample_layer(
     w_rows = jnp.take(weights, lanes)
     pos, valid = gumbel_topk_positions(key, deg, k, w_rows)
     # NOT take_along_axis (a [B, k] per-row dynamic lane read lowers to a
-    # B*k-descriptor gather — the round-5 trap, PERF_NOTES.md grep rule) and
+    # B*k-descriptor gather — the round-5 trap, PERF.md (earlier claims) grep rule) and
     # not even the one-hot compare+sum: the lane window is AFFINE in the
     # drawn position (lanes[b, p] == clip(ptr[b] + p)), so the select is
     # plain address arithmetic — zero descriptors, bit-identical flat ids

@@ -202,7 +202,7 @@ def sharded_gather_hot_cold(
     hot_part = sharded_gather(hot_block, ids, ici_axes)
     # cold side: compact the cold ids to the front (argsort of the hot flag
     # is stable and costs ~0.5 ms/M lanes — sorts are the cheap primitive,
-    # PERF_NOTES.md), slice the static budget, gather grouped, scatter back.
+    # PERF.md (earlier claims)), slice the static budget, gather grouped, scatter back.
     # Out-of-range ids (padding sentinels: reindex pads with intmax) are
     # NEITHER hot nor cold — they must not consume budget lanes
     n_cold_global = cold_block.shape[0]

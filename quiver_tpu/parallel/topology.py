@@ -33,7 +33,7 @@ Two shard LAYOUTS share all of the collective machinery above:
   into the 128-lane tile layout of `ops.sample.build_tiled_host` — a local
   ``(base, degree)`` table plus a ``[M, 128]`` tile table — so position
   resolution rides 2-D ROW gathers + one-hot lane selects, the fetch shape
-  behind the single-chip 2.58x fused-SEPS win (PERF_NOTES.md "ROUND-5").
+  behind the single-chip 2.58x fused-SEPS win (PERF.md (earlier claims)).
   The collective payloads are IDENTICAL between layouts (same ``[W, k]``
   neighbor/valid return, same frontier all_gather); only the local HBM
   fetch shape changes — `sampling_comm_bytes(layout=...)` models both.
@@ -585,7 +585,7 @@ def sampling_comm_bytes(
     single elements under "flat"). Descriptor COUNTS match between layouts;
     what differs is the bytes per descriptor and — the reason tiled wins —
     the issue RATE: TPU row gathers stream ~1.4-2.6x faster than element
-    gathers (PERF_NOTES.md; `scaling.sharded_fetch_table` applies the
+    gathers (PERF.md (earlier claims); `scaling.sharded_fetch_table` applies the
     measured rates).
     """
     from .train import mesh_axes

@@ -28,9 +28,6 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax >= 0.6 top-level shard_map vs older experimental spelling: one
-# compat wrapper (utils.shard_map_compat) absorbs both the location and
-# the check_vma/check_rep rename
 from ..utils import axis_size_compat, shard_map_compat as _shard_map_fn
 
 from ..pyg.sage_sampler import (
@@ -226,7 +223,7 @@ def make_sharded_train_step(
     ``pipeline``: "dedup" (reference-parity per-hop reindex) or "fused"
     (no-dedup structural layout; per-hop ICI gathers interleave with
     sampling — the fastest path, same tradeoff as the single-chip
-    pipelines, PERF_NOTES.md).
+    pipelines, PERF.md (earlier claims)).
 
     ``hot_rows``/``cold_budget`` (multi-host meshes only) switch the feature
     gather to the replicated-hot layout (`sharded_gather_hot_cold`): the

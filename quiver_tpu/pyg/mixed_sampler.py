@@ -81,7 +81,16 @@ def _cpu_worker_loop(shm_names, shapes, sizes, caps, seed, task_q, result_q,
     array — workers then draw through the native weighted engine."""
     from multiprocessing import shared_memory
 
+    import jax
+
     from ..ops.cpu_kernels import HostSampler
+
+    # the parent holds the chip, and a chip belongs to one process. Nothing
+    # imported on the way here touches a device (pinned by
+    # tests/test_mixed_sampler.py), and the loop below is numpy + the native
+    # engine; should a later change reach for JAX here, it gets the CPU
+    # instead of hanging on the parent's chip.
+    jax.config.update("jax_platforms", "cpu")
 
     shms = [shared_memory.SharedMemory(name=n) for n in shm_names]
     indptr = np.ndarray(shapes[0], dtype=np.int64, buffer=shms[0].buf)
@@ -174,22 +183,14 @@ class MixedGraphSageSampler:
                     f"halves of one epoch would sample different "
                     f"distributions. Raise max_deg, or use CPU_ONLY/TPU_ONLY."
                 )
-        if weighted and num_workers > 0 and ("MIXED" in mode or mode == "CPU_ONLY"):
-            # fail HERE with the real reason: otherwise every spawned worker
-            # dies on HostSampler's RuntimeError in a detached process and
-            # the parent only sees a 120 s "workers stalled" timeout
-            from ..ops.cpu_kernels import _load_native
+        if num_workers > 0 and ("MIXED" in mode or mode == "CPU_ONLY"):
+            # fail HERE with the real reason (and build the library once,
+            # in the parent): otherwise every spawned worker dies on
+            # HostSampler's RuntimeError in a detached process and the
+            # parent only sees a 120 s "workers stalled" timeout
+            from ..ops.cpu_kernels import require_native
 
-            lib = _load_native()
-            if lib is None or not hasattr(lib, "qt_sample_layer_weighted"):
-                # mirror the exact worker-side requirement (a stale .so can
-                # be native_available() yet lack the weighted entry point)
-                raise RuntimeError(
-                    "weighted CPU workers need the native engine's "
-                    "qt_sample_layer_weighted (make -C quiver_tpu/csrc); "
-                    "rebuild libquiver_cpu.so or use num_workers=0 / "
-                    "mode='TPU_ONLY'"
-                )
+            require_native("MixedGraphSageSampler's CPU workers")
         self.job = job
         self.csr_topo = csr_topo
         self.sizes = tuple(int(s) for s in sizes)

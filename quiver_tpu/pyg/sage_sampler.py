@@ -33,6 +33,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..shard_tensor import _device_of
 from ..utils import CSRTopo
 from ..ops.sample import (
     pad_widths,
@@ -238,7 +239,7 @@ def sample_and_gather_dedup(
     the data flow:
 
     - the leaf aggregation becomes a slice+reshape (2.3x faster than the
-      equivalent take, PERF_NOTES.md) instead of a W_{L-1}*k_L-row gather
+      equivalent take, PERF.md (earlier claims)) instead of a W_{L-1}*k_L-row gather
       from computed activations;
     - that gather's backward scatter disappears entirely — the structural
       leaf rows read the CONSTANT feature table, so no gradient flows;
@@ -393,26 +394,30 @@ def probe_hop_counts(
     key: jax.Array,
     seeds_all: jax.Array,
     sizes: Tuple[int, ...],
-    sample_fn=None,
+    graph=None,
+    bind=None,
     cache: dict = None,
 ) -> np.ndarray:
     """Per-hop unique-frontier counts over ``m`` probe batches: ``[m, L]``.
 
     One jitted scan over the UNCAPPED dedup pipeline — one dispatch total,
-    so probing is cheap even through a high-latency link (PERF_NOTES.md
-    measurement discipline). The default flat-CSR path reuses one
-    module-level compiled program across calls. A custom ``sample_fn``
-    (the tiled DEFAULT layout and weighted samplers — caps MUST be
-    calibrated under the distribution they will serve) closes over its own
-    graph arrays, so its scan cannot live in the module-level cache; pass
-    ``cache`` (any dict owned by the caller, keyed here by ``sizes``) to
-    reuse the traced scan across calls — `GraphSageSampler.calibrate_caps`
-    passes a per-sampler dict, which is sound because a sampler's layout /
-    weighting / graph (everything ``sample_fn`` closes over) is fixed at
-    construction. Without ``cache``, each call retraces.
+    so probing costs one host round trip, however many probe batches. The
+    default flat-CSR path reuses one module-level compiled program across
+    calls. Other layouts and weighted samplers (caps MUST be calibrated
+    under the distribution they will serve) pass ``graph`` — the sampler's
+    device-array pytree — and ``bind(graph) -> sample_fn``
+    (`GraphSageSampler._graph_and_bind`): the arrays are ARGUMENTS of the
+    jitted scan, never closure constants (at products scale a closed-over
+    1.45 GB tile table is baked into the executable: minutes of compile and
+    a program too large for the persistent cache). Pass ``cache`` (any dict
+    owned by the caller, keyed here by ``sizes``) to reuse the traced scan
+    across calls — `GraphSageSampler.calibrate_caps` passes a per-sampler
+    dict, sound because everything ``bind`` closes over (layout, weighting,
+    ``max_deg``) is fixed at construction. Without ``cache``, each call
+    retraces.
     """
     seeds_all = jnp.asarray(seeds_all)
-    if sample_fn is None:
+    if bind is None:
         return np.asarray(
             _probe_hop_counts_scan(indptr, indices, key, seeds_all, tuple(sizes))
         )
@@ -422,7 +427,9 @@ def probe_hop_counts(
     if run is None:
 
         @jax.jit
-        def run(key0, batches):
+        def run(g, key0, batches):
+            sample_fn = bind(g)
+
             def body(_, i):
                 ds = sample_dense_pure(
                     None, None, jax.random.fold_in(key0, i), batches[i],
@@ -438,7 +445,7 @@ def probe_hop_counts(
         if cache is not None:
             cache[sizes_t] = run
 
-    return np.asarray(run(key, seeds_all))
+    return np.asarray(run(graph, key, seeds_all))
 
 
 def caps_from_counts(
@@ -564,9 +571,9 @@ class GraphSageSampler:
         # lanes and every draw takes a per-seed query time t
         self._temporal = None
         # per-sampler probe-scan cache: under the default layout='tiled'
-        # (and for weighted samplers) _engine() hands probe_hop_counts a
-        # fresh sample_fn closure per call, so without this the jitted
-        # probe scan would retrace on EVERY calibrate_caps call
+        # (and for weighted samplers) probe_hop_counts builds its jitted
+        # scan around this sampler's bind(), so without this it would
+        # retrace on EVERY calibrate_caps call
         self._probe_scan_cache: dict = {}
         if mode == "TPU":
             self.lazy_init_quiver()
@@ -574,8 +581,7 @@ class GraphSageSampler:
 
     def _device_obj(self):
         if isinstance(self.device, int):
-            local = jax.local_devices()
-            return local[self.device % len(local)]
+            return _device_of(self.device)
         return None
 
     # -- streaming graph binding (round 17; quiver_tpu.stream) -----------
@@ -612,11 +618,6 @@ class GraphSageSampler:
             )
         self._stream = stream
         self._dev_tiled = None
-        # the cached probe scan (calibrate_caps) bakes the graph arrays
-        # in as trace-time constants — sound for a frozen graph, stale
-        # the moment this sampler reads a stream (re-keyed per commit
-        # version in calibrate_caps)
-        self._probe_scan_cache.clear()
         return self
 
     # -- temporal binding (round 19; quiver_tpu.workloads) ----------------
@@ -679,7 +680,6 @@ class GraphSageSampler:
             self._stream = source
             self._dev_tiled = None
         self._temporal = (source, float(recency))
-        self._probe_scan_cache.clear()
         return self
 
     def temporal_graph_arrays(self):
@@ -757,7 +757,7 @@ class GraphSageSampler:
 
         ``graph`` is the device-array pytree the fused program must take as
         jit ARGUMENTS — never closure constants: big closure constants are
-        the remote-compile trap (NEXT.md; bit round 5's probe script).
+        the slow-compile trap (NEXT.md; bit round 5's probe script).
         ``bind(graph)`` rebuilds the one-hop ``sample_fn`` over the TRACED
         graph arrays inside the jit, mirroring `_engine()`'s eager
         closures. Raises TypeError when this sampler cannot be fused
@@ -772,6 +772,11 @@ class GraphSageSampler:
                 "program needs static caps (calibrate_caps first, or "
                 "construct with auto_grow_caps=False)"
             )
+        return self._graph_and_bind()
+
+    def _graph_and_bind(self):
+        """``(graph, bind, id_dtype)`` of `fused_sample_spec`, without its
+        serving-only checks (cap calibration probes with it too)."""
         if self.layout == "tiled":
             bd, tiles = self.lazy_init_quiver()
             if self.weighted:
@@ -1089,21 +1094,14 @@ class GraphSageSampler:
         if batches.ndim != 2:
             raise ValueError(f"probe_seeds must be [m, B]; got {batches.shape}")
         if self.mode == "TPU":
-            if self._stream is not None:
-                # the cached probe scan closes over the stream's graph
-                # arrays AS OF ITS TRACE — a delta commit leaves it
-                # probing a stale graph, so the cache lives one stream
-                # version only (probe_hop_counts keys entries by sizes;
-                # the version marker coexists under its own key)
-                ver = int(self._stream.version)
-                if self._probe_scan_cache.get("stream_version") != ver:
-                    self._probe_scan_cache.clear()
-                    self._probe_scan_cache["stream_version"] = ver
-            indptr, indices, sample_fn, id_dtype = self._engine()
+            # the graph arrays are ARGUMENTS of the cached probe scan, read
+            # here per call: a stream-bound sampler probes the graph as of
+            # its latest commit with no retrace (shapes never change)
+            graph, bind, id_dtype = self._graph_and_bind()
             counts = probe_hop_counts(
-                indptr, indices, self._next_key(),
+                None, None, self._next_key(),
                 jnp.asarray(batches.astype(np.dtype(id_dtype))), self.sizes,
-                sample_fn=sample_fn, cache=self._probe_scan_cache,
+                graph=graph, bind=bind, cache=self._probe_scan_cache,
             )
         else:
             rows = []

@@ -70,6 +70,7 @@ from .dist import (
     plan_migration_ranges,
     replay_fleet_oracle,
     replay_shard_oracle,
+    resolve_exchange_mode,
     shard_from_mask,
     shard_topology_by_owner,
     shard_topology_for_seeds,
@@ -129,6 +130,7 @@ __all__ = [
     "poisson_arrivals",
     "replay_fleet_oracle",
     "replay_shard_oracle",
+    "resolve_exchange_mode",
     "shard_from_mask",
     "shard_topology_by_owner",
     "shard_topology_for_seeds",

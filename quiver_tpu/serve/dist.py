@@ -142,6 +142,19 @@ class OwnerTimeout(RuntimeError):
     answer is discarded."""
 
 
+def resolve_exchange_mode(exchange: str, hosts: int) -> str:
+    """The exchange mode a fleet build runs: ``"auto"`` means
+    ``"collective"`` when this process sees at least ``hosts`` devices and
+    ``"host"`` otherwise. The ONE place that choice is made — it follows
+    the device count, so the same call gives another answer on another
+    machine; a built engine records the outcome as ``exchange_mode``."""
+    if exchange != "auto":
+        return exchange
+    import jax
+
+    return "collective" if len(jax.devices()) >= hosts else "host"
+
+
 def contiguous_partition(n_nodes: int, hosts: int) -> np.ndarray:
     """Balanced contiguous ``global2host`` map: host h owns rows
     ``[h*ceil(N/H), ...)`` (the same contiguous-range convention the
@@ -1359,9 +1372,7 @@ class DistServeEngine:
         out_dim = out_dim if out_dim is not None else getattr(model, "out_dim", None)
         if out_dim is None:
             raise ValueError("pass out_dim= (model has no out_dim attribute)")
-        mode = config.exchange
-        if mode == "auto":
-            mode = "collective" if len(jax.devices()) >= hosts else "host"
+        mode = resolve_exchange_mode(config.exchange, hosts)
         comm = None
         feat_comms: List[object] = []
         if mode == "collective":

@@ -73,8 +73,17 @@ class ShardTensorConfig:
 
 
 def _device_of(rank: int):
+    """Local device ``rank``. A rank this process does not have is an error:
+    wrapping it (``rank % n``) would stack every shard of a
+    ``device_list=[0, 1, 2, 3]`` on the one chip of a one-chip machine
+    without a word."""
     local = jax.local_devices()
-    return local[rank % len(local)]
+    if not 0 <= rank < len(local):
+        raise ValueError(
+            f"device rank {rank} out of range: this process has "
+            f"{len(local)} local device(s) ({local[0].platform})"
+        )
+    return local[rank]
 
 
 @jax.jit

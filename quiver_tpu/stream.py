@@ -32,7 +32,7 @@ device as **batched tile swaps**: the touched tile rows (and bd rows) go
 through one jitted bucketed row-scatter per commit
 (`shard_tensor._scatter_rows` semantics — the same idiom the round-14 tier
 promotions ride). Scatter-building big arrays is the compile trap
-PERF_NOTES pins; a bounded ``[K, 128]`` row scatter into an EXISTING
+PERF.md (earlier claims) pins; a bounded ``[K, 128]`` row scatter into an EXISTING
 same-shaped array is not. Every sampler path stays gather-only and
 bit-replayable: the device arrays keep their shapes for the life of the
 stream, so the sealed `inference.BucketPrograms` executables keep running
@@ -102,7 +102,7 @@ from .shard_tensor import _bucket, _scatter_rows
 # The batched tile-swap primitive: one bounded [K, ...] row scatter into
 # an existing same-shaped device table, out-of-range positions dropped as
 # padding (`shard_tensor._scatter_rows` — the round-14 promotion idiom,
-# NOT the PERF_NOTES scatter-build trap). Named here because every delta
+# NOT the PERF.md (earlier claims) scatter-build trap). Named here because every delta
 # consumer (tile sync below, `ClosureFeature.install_rows` in serve/dist)
 # must commit through this one shape-stable path.
 _swap_rows = _scatter_rows
@@ -769,7 +769,7 @@ def _bucketed(idx: np.ndarray, rows: np.ndarray, sentinel: int,
     """Pad a row-swap batch to a power-of-two bucket so the jitted
     `shard_tensor._scatter_rows` commit (one bounded [K, ...] row
     scatter into an existing same-shaped device table — the round-14
-    promotion idiom, NOT the PERF_NOTES scatter-build trap) compiles
+    promotion idiom, NOT the PERF.md (earlier claims) scatter-build trap) compiles
     once per bucket, not once per delta size."""
     b = _bucket(idx.shape[0], floor=floor)
     pos = np.full(b, sentinel, np.int32)

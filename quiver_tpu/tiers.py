@@ -22,7 +22,7 @@ TPU-native version, in two halves:
    :class:`TierPlacement` map (stored row -> tier, slot). Gathers stay
    GATHER-ONLY (the placement map is computed on host; per-tier gathers
    scatter-merge into the output exactly like `ShardTensor.__getitem__`
-   — no scatter builds of big arrays per gather, PERF_NOTES).
+   — no scatter builds of big arrays per gather, PERF.md (earlier claims)).
    :func:`plan_adaptive` turns the round-13 frequency sketch
    (`WorkloadMonitor.promotion_candidates`) into a bounded
    :class:`PlacementPlan`; `TierStore.apply` executes it in batches

@@ -63,6 +63,7 @@ from ..serve.dist import (
     _bounded_leg_schedule,
     closure_masks,
     contiguous_partition,
+    resolve_exchange_mode,
     shard_from_mask,
 )
 from ..serve.engine import (
@@ -422,9 +423,7 @@ class TemporalDistServeEngine(_PairServing, DistServeEngine):
                    else getattr(model, "out_dim", None))
         if out_dim is None:
             raise ValueError("pass out_dim= (model has no out_dim attribute)")
-        mode = config.exchange
-        if mode == "auto":
-            mode = "collective" if len(jax.devices()) >= hosts else "host"
+        mode = resolve_exchange_mode(config.exchange, hosts)
         comm = None
         if mode == "collective":
             if mesh is None:

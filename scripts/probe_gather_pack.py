@@ -1,6 +1,6 @@
 """Probe: XLA row-gather rate vs row width, and packed-row gather schemes.
 
-PERF_NOTES.md records the hot-gather descriptor wall: ~20M rows/s for
+PERF.md (earlier claims) records the hot-gather descriptor wall: ~20M rows/s for
 dim<=128, but ~26M rows/s at dim 256. If rate keeps rising with row width,
 storing the feature table packed ([N/p, p*D]) and selecting the needed
 D-slice on-chip beats the plain gather even with p-1 wasted lanes.
@@ -10,7 +10,7 @@ Two sections:
   2. end-to-end packed-select: deliver [W, 100] useful f32 rows from a
      pack-p table via take(ids >> log2 p) + per-row half select.
 
-Measurement discipline (PERF_NOTES.md): tables generated ON DEVICE, passed
+Measurement discipline (PERF.md (earlier claims)): tables generated ON DEVICE, passed
 as jit ARGUMENTS, iterations scanned in-jit, timing ended with a dependent
 float() fetch. Run with `python -u`, nothing else on the machine.
 """

@@ -1,6 +1,6 @@
 """Probe: does position LOCALITY change the element-gather rate? (honest
 windows — the round-3 'sort order is irrelevant' conclusion was measured
-under the RPC floor). If sorted positions gather meaningfully faster, a
+in windows too short to trust). If sorted positions gather meaningfully faster, a
 cheap sort (~0.5 ms/M) in front of the 1.07M-element neighbor fetch
 (~11 ms) would pay."""
 import os
@@ -37,7 +37,6 @@ def main():
         # pre-sort of each hop's row-major frontier would roughly give
         "block-sorted": np.sort(raw.reshape(-1, 8192), axis=1).reshape(-1),
     }
-    floor = bench.measure_rpc_floor()
 
     @jax.jit
     def run(tab, idx):
@@ -53,7 +52,7 @@ def main():
         int(run(tab, idx))
         t0 = time.time()
         int(run(tab, idx))
-        dt = time.time() - t0 - floor
+        dt = time.time() - t0
         print(f"  {name:12s}: {ITERS*W/dt/1e6:7.1f}M elems/s ({dt/ITERS*1e3:.2f} ms/iter)")
 
 

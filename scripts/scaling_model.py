@@ -221,8 +221,8 @@ def main():
                 "bench.py serve)"
             )
     if not step_s:
-        step_s = 0.0415  # PERF_NOTES.md round-4 measured products step (fused, floor-corrected)
-        source = "PERF_NOTES.md round-4 default 41.5 ms"
+        step_s = 0.0415  # PERF.md (earlier claims) round-4 measured products step (fused)
+        source = "PERF.md (earlier claims) round-4 default 41.5 ms"
 
     from quiver_tpu.parallel.scaling import (
         ShapeMesh,

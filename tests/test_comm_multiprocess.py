@@ -51,24 +51,9 @@ def _run_workers(mode=None):
             p.kill()
         pytest.fail("distributed workers timed out:\n" + "\n".join(outs))
     for pid, (p, out) in enumerate(zip(procs, outs)):
-        if p.returncode != 0 and _CPU_MULTIPROCESS_UNSUPPORTED in out:
-            # capability gap, not a code bug: jax <= 0.4.x cannot run
-            # multi-process computations on the CPU backend at all (the
-            # collectives path these tests exist to exercise). The tests
-            # stay live and run for real on any jax whose CPU backend has
-            # cross-process collectives.
-            pytest.skip(
-                "this jax's CPU backend does not implement multiprocess "
-                "computations; 2-process exchange untestable here"
-            )
         assert p.returncode == 0, f"worker {pid} failed:\n{out}"
         assert f"worker {pid} OK" in out, out
     return outs
-
-
-_CPU_MULTIPROCESS_UNSUPPORTED = (
-    "Multiprocess computations aren't implemented on the CPU backend"
-)
 
 
 pytestmark = pytest.mark.multiprocess  # 2-OS-process tests (see pytest.ini)

@@ -314,3 +314,23 @@ def test_gather_rows_zero_row_table_both_paths():
         finally:
             cpu_kernels._LIB, cpu_kernels._LIB_TRIED = saved
         assert got.shape == (3, 5) and got.dtype == dtype and (got == 0).all()
+
+
+def test_out_of_range_device_rank_is_an_error():
+    """device_list=[0, 1, 2, 3] on a one-chip machine used to wrap every
+    shard onto chip 0 (rank % n) without a word."""
+    import jax
+
+    from quiver_tpu.pyg import GraphSageSampler
+
+    n_dev = len(jax.local_devices())
+    arr = np.zeros((4, 3), np.float32)
+    st = ShardTensor(0, ShardTensorConfig({}))
+    st.append(arr, n_dev - 1)  # the last real device is fine
+    with pytest.raises(ValueError, match="out of range"):
+        st.append(arr, n_dev)
+    with pytest.raises(ValueError, match="out of range"):
+        Feature(rank=n_dev, device_list=[n_dev], device_cache_size="1M").from_cpu_tensor(arr)
+    topo = CSRTopo(edge_index=make_random_graph(20, 60))
+    with pytest.raises(ValueError, match="out of range"):
+        GraphSageSampler(topo, [2], device=n_dev, mode="TPU")

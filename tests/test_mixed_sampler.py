@@ -329,3 +329,25 @@ def test_all_workers_dead_fails_fast_and_heals_next_epoch(graph):
         assert seen == list(range(len(job)))
     finally:
         s.shutdown()
+
+
+def test_worker_imports_touch_no_device():
+    """The spawned CPU workers import this package (and chip_smoke.py as
+    their parent's main module) while the parent holds the chip, and a chip
+    belongs to one process: nothing at import time may initialise a JAX
+    backend."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import quiver_tpu.pyg.mixed_sampler, chip_smoke\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge.backends_are_initialized()\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], cwd=root, capture_output=True, text=True,
+        timeout=120, env=dict(os.environ, PYTHONPATH=root),
+    )
+    assert out.returncode == 0, out.stderr[-2000:]

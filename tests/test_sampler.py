@@ -383,7 +383,7 @@ def test_weighted_sampling_copy_all_and_zero_weight():
 
 
 def test_weighted_flat_window_select_draw_parity_with_take_along_axis(graph):
-    """Round-10 fix of the last hot-ish `take_along_axis` (PERF_NOTES.md
+    """Round-10 fix of the last hot-ish `take_along_axis` (PERF.md (earlier claims)
     round-5 grep rule): the flat weighted layer's [B, max_deg] window
     select is now plain address arithmetic (the window is affine in the
     drawn position). Draw parity pin: bit-identical (nbrs, valid) to the
@@ -767,3 +767,38 @@ def test_tiled_weighted_sampler_end_to_end(graph):
     np.testing.assert_array_equal(np.asarray(ds_t.n_id), np.asarray(ds_f.n_id))
     sampled = np.asarray(ds_t.n_id)[64 : int(ds_t.count)]
     assert (sampled % 2 == 0).all()
+
+
+def test_native_engine_built_from_source_and_required(tmp_path, monkeypatch):
+    """The library is rebuilt whenever it is missing or older than
+    quiver_cpu.cpp, and HOST-mode sampling refuses to run without it (no
+    quiet numpy stand-in)."""
+    import os
+    import shutil
+
+    from quiver_tpu.ops import cpu_kernels as ck
+
+    csrc = tmp_path / "csrc"
+    csrc.mkdir()
+    for name in ("Makefile", "quiver_cpu.cpp"):
+        shutil.copy(os.path.join(ck._CSRC, name), csrc / name)
+    assert "-march=native" not in (csrc / "Makefile").read_text()
+    monkeypatch.setattr(ck, "_CSRC", str(csrc))
+    monkeypatch.setattr(ck, "_SO", str(csrc / "libquiver_cpu.so"))
+    monkeypatch.setattr(ck, "_SRC", str(csrc / "quiver_cpu.cpp"))
+    monkeypatch.setitem(ck._BUILD, "build_s", None)
+    ck._build_native()                      # missing -> built
+    built = os.path.getmtime(ck._SO)
+    assert ck._BUILD["build_s"] > 0 and os.listdir(csrc).count("libquiver_cpu.so") == 1
+    ck._build_native()                      # newer than the source -> kept
+    assert os.path.getmtime(ck._SO) == built
+    os.utime(ck._SRC, (built + 10, built + 10))
+    ck._build_native()                      # older than the source -> rebuilt
+    assert os.path.getmtime(ck._SO) > built
+
+    monkeypatch.setattr(ck, "_LIB", None)
+    monkeypatch.setattr(ck, "_LIB_TRIED", True)
+    monkeypatch.setitem(ck._BUILD, "error", "RuntimeError: g++ not found")
+    assert ck.native_engine_info()["native"] is False
+    with pytest.raises(RuntimeError, match="g\\+\\+ not found"):
+        HostSampler(np.array([0, 1]), np.array([0]))

@@ -15,7 +15,7 @@ from quiver_tpu.parallel.scaling import (
 )
 
 
-STEP = 0.055  # measured single-chip products step (PERF_NOTES.md)
+STEP = 0.055  # measured single-chip products step (PERF.md (earlier claims))
 
 
 def test_dp_replicated_near_linear():
@@ -114,7 +114,7 @@ def test_hot_cold_tier_cuts_dcn():
 def test_sharded_fetch_table_flat_vs_tiled():
     """The round-6 layout comparison row: identical descriptor counts,
     tiled fetches more bytes but prices CHEAPER in time under the measured
-    descriptor rates (both regimes are issue-rate-bound, PERF_NOTES.md)."""
+    descriptor rates (both regimes are issue-rate-bound, PERF.md (earlier claims))."""
     from quiver_tpu.parallel.scaling import sharded_fetch_table
 
     mesh = ShapeMesh(("host", "dp", "ici"), {"host": 2, "dp": 2, "ici": 2})

@@ -1,0 +1,250 @@
+"""Ask the TPU's own compiler, with no TPU attached.
+
+The chip's compiler is installed here and compiles for a chip that is
+described, not attached (`jax.experimental.topologies`). Four programs of the
+main path, at the shapes `chip_smoke.py` runs, are lowered for a described
+``v5e:2x2`` and must be accepted and fit one chip's 16 GB:
+
+  (a) fused train step      (sample -> gather -> fwd/bwd -> adam, one program)
+  (b) dedup train step      (the same over the capped dedup sampler)
+  (c) one sealed serve bucket from `inference.make_serve_step`, seed buffer
+      donated as `BucketPrograms` donates it
+  (d) `make_sharded_topo_train_step(layout="tiled")` on the four described
+      devices (the layout is passed: `default_backend()` says cpu here)
+
+A compile that passes is not a chip run. To stay inside the suite's time
+limit the tests compile (b) at batch 64 and (c) at bucket 8 (the graph and
+feature tables, which decide what fits, keep their full size);
+``python tests/test_tpu_compile.py`` compiles all four at full size and
+prints what the compiler reports.
+"""
+
+import os
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from chip_smoke import PRODUCTS, SIZES, make_model, make_train_step, ring_sampler
+from quiver_tpu.inference import make_serve_step
+from quiver_tpu.ops.sample import LANE, tiled_sample_layer
+from quiver_tpu.parallel import make_sharded_topo_train_step, make_sharded_train_step
+from quiver_tpu.parallel.topology import TiledShardedTopology
+from quiver_tpu.pyg.sage_sampler import sample_dense_fused, sample_dense_pure
+
+HBM_BYTES = 16e9                      # one v5e chip
+N = PRODUCTS["nodes"]
+TILE_ROWS = 2_833_089                 # [M, 128] tile table of the seed-0 graph
+DEDUP_CAPS = (16384, 151552, 600064)  # at batch 1024, margin 1.2
+SERVE_BUCKET = 64
+# per-shard row / tile-row counts of the four-way edge-balanced split, with slack
+SHARD_ROWS, SHARD_TILE_ROWS = 700_000, 760_000
+
+
+def _struct(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding), tree
+    )
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu here: nothing to ask
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {exc!r}")
+    # a program compiled for a described device is written to the persistent
+    # cache but cannot be read back without a chip (the next run would warn
+    # and compile again): cache off around these compiles
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _graph_structs():
+    return _sds((N, 2), jnp.int32), _sds((TILE_ROWS, LANE), jnp.int32)
+
+
+def _train_step(model, tx, dedup_caps):
+    """The smoke's train step as ONE program: `sample_dense_fused` (or
+    `sample_dense_pure` with caps) over the tile layout -> gather ->
+    `chip_smoke.make_train_step`."""
+    train_step = make_train_step(model, tx)
+
+    def step(params, opt_state, bd, tiles, table, labels, key, seeds):
+        key, sub = jax.random.split(key)
+
+        def hop(cur, cur_valid, k, hkey):
+            return tiled_sample_layer(bd, tiles, cur, cur_valid, k, hkey)
+
+        if dedup_caps is None:
+            ds = sample_dense_fused(None, None, sub, seeds, SIZES, sample_fn=hop)
+        else:
+            ds = sample_dense_pure(None, None, sub, seeds, SIZES, dedup_caps,
+                                   sample_fn=hop)
+        x = jnp.take(table, jnp.clip(ds.n_id, 0, table.shape[0] - 1), axis=0)
+        return train_step(params, opt_state, key, x, ds.adjs, jnp.take(labels, seeds))
+
+    return step
+
+
+def _model_structs(model, tx):
+    """(params, opt_state, key) shapes, traced from a one-seed sample."""
+    ds = ring_sampler(dedup=False).sample_dense(np.arange(1))
+    x = jnp.zeros((ds.n_id.shape[0], PRODUCTS["dim"]), jnp.float32)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    params = jax.eval_shape(lambda k: model.init(k, x, ds.adjs), key)
+    return params, jax.eval_shape(tx.init, params), key
+
+
+def _fits(compiled, what):
+    m = compiled.memory_analysis()
+    need = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert need < HBM_BYTES, f"{what}: {need / 1e9:.2f} GB on one chip"
+    return {"arguments_gb": round(m.argument_size_in_bytes / 1e9, 2),
+            "temporaries_gb": round(m.temp_size_in_bytes / 1e9, 2)}
+
+
+def compile_train_step(v5e, dedup_caps, batch):
+    model, tx = make_model(PRODUCTS["classes"]), optax.adam(3e-3)
+    params, opt_state, key = _model_structs(model, tx)
+    bd, tiles = _graph_structs()
+    args = (params, opt_state, bd, tiles, _sds((N, PRODUCTS["dim"]), jnp.float32),
+            _sds((N,), jnp.int32), key, _sds((batch,), jnp.int32))
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    compiled = jax.jit(_train_step(model, tx, dedup_caps)).lower(
+        *_struct(args, one_chip)).compile()
+    return _fits(compiled, f"train step caps={dedup_caps} batch={batch}")
+
+
+def compile_serve_bucket(v5e, bucket=SERVE_BUCKET):
+    model, tx = make_model(PRODUCTS["classes"]), optax.adam(3e-3)
+    params, _, key = _model_structs(model, tx)
+    # a small real sampler supplies the serve step; the shapes it is lowered
+    # at are the products ones
+    serve_step, _, id_dtype = make_serve_step(model, ring_sampler())
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    args = _struct(
+        (params, key, _sds((bucket,), id_dtype),
+         _sds((N, PRODUCTS["dim"]), jnp.float32)), one_chip)
+    compiled = jax.jit(serve_step, donate_argnums=(2,)).lower(
+        *args, None, _struct(_graph_structs(), one_chip)).compile()
+    return _fits(compiled, f"serve bucket {bucket}")
+
+
+def compile_sharded_topo_step(v5e, n_devices=4, batch=PRODUCTS["batch"]):
+    """n_devices=1 is the one-device twin `chip_smoke.py --chips 4` compares
+    the sharded steps against (script only)."""
+    model, tx = make_model(PRODUCTS["classes"], dropout=0.0), optax.adam(3e-3)
+    params, opt_state, key = _model_structs(model, tx)
+    mesh = Mesh(np.array(v5e.devices[:n_devices]).reshape(1, n_devices), ("dp", "ici"))
+    rep = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P("ici", None))
+    blocks = NamedSharding(mesh, P("ici", None, None))
+    shard_rows, shard_tiles = (
+        (SHARD_ROWS, SHARD_TILE_ROWS) if n_devices == 4 else (N, TILE_ROWS))
+    stopo = TiledShardedTopology(
+        bd=jax.ShapeDtypeStruct((n_devices, shard_rows, 2), jnp.int32, sharding=blocks),
+        tiles=jax.ShapeDtypeStruct((n_devices, shard_tiles, LANE), jnp.int32,
+                                   sharding=blocks),
+        row_start=jax.ShapeDtypeStruct((n_devices + 1,), jnp.int32, sharding=rep),
+    )
+    n_pad = -(-N // n_devices) * n_devices
+    step = make_sharded_topo_train_step(
+        mesh, model, tx, SIZES, pipeline="fused", layout="tiled")
+    compiled = step.lower(
+        *_struct((params, opt_state, key), rep), stopo,
+        jax.ShapeDtypeStruct((n_pad, PRODUCTS["dim"]), jnp.float32, sharding=rows),
+        jax.ShapeDtypeStruct((N,), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((batch,), jnp.int32,
+                             sharding=NamedSharding(mesh, P("dp"))),
+    ).compile()
+    if n_devices > 1:
+        assert "all-reduce" in compiled.as_text(), (
+            "the sharded step compiled without a collective")
+    return _fits(compiled, f"tiled sharded-topology step on {n_devices} device(s)")
+
+
+def compile_sharded_feature_step(v5e, n_devices, batch=PRODUCTS["batch"]):
+    """`make_sharded_train_step` (graph replicated, feature rows striped) on a
+    (dp=1, ici=n_devices) mesh of described devices. Script only: the suite
+    keeps to four compiles, and this one takes two minutes (flat-CSR
+    sampling: element gathers from a 123M-entry 1-D array)."""
+    model, tx = make_model(PRODUCTS["classes"], dropout=0.0), optax.adam(3e-3)
+    params, opt_state, key = _model_structs(model, tx)
+    mesh = Mesh(np.array(v5e.devices[:n_devices]).reshape(1, n_devices), ("dp", "ici"))
+    rep = NamedSharding(mesh, P())
+    n_pad = -(-N // n_devices) * n_devices
+    edges = PRODUCTS["edges"]
+    step = make_sharded_train_step(mesh, model, tx, SIZES, pipeline="fused")
+    compiled = step.lower(
+        *_struct((params, opt_state, key, _sds((N + 1,), jnp.int32),
+                  _sds((edges,), jnp.int32)), rep),
+        jax.ShapeDtypeStruct((n_pad, PRODUCTS["dim"]), jnp.float32,
+                             sharding=NamedSharding(mesh, P("ici", None))),
+        jax.ShapeDtypeStruct((N,), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((batch,), jnp.int32,
+                             sharding=NamedSharding(mesh, P("dp"))),
+    ).compile()
+    return _fits(compiled, f"sharded feature step on {n_devices} device(s)")
+
+
+def test_fused_train_step_compiles_for_v5e(v5e):
+    compile_train_step(v5e, None, PRODUCTS["batch"])
+
+
+def test_dedup_train_step_compiles_for_v5e(v5e):
+    # batch 64 with the caps scaled to match: the batch-1024 compile alone
+    # takes two minutes (run this file as a script for it)
+    compile_train_step(v5e, (1024, 10240, 40960), 64)
+
+
+def test_serve_bucket_compiles_for_v5e(v5e):
+    compile_serve_bucket(v5e, 8)
+
+
+def test_tiled_sharded_topo_step_compiles_for_four_v5e(v5e):
+    compile_sharded_topo_step(v5e)
+
+
+if __name__ == "__main__":
+    from jax.experimental import topologies
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    for name, fn in (
+        ("fused train step, batch 1024",
+         lambda: compile_train_step(desc, None, PRODUCTS["batch"])),
+        ("dedup train step, batch 1024",
+         lambda: compile_train_step(desc, DEDUP_CAPS, PRODUCTS["batch"])),
+        ("serve bucket 64", lambda: compile_serve_bucket(desc)),
+        ("serve bucket 1", lambda: compile_serve_bucket(desc, 1)),
+        ("tiled sharded-topology step, 4 devices",
+         lambda: compile_sharded_topo_step(desc)),
+        ("tiled sharded-topology step, 1 device (the twin)",
+         lambda: compile_sharded_topo_step(desc, 1)),
+        ("sharded-feature step, 4 devices",
+         lambda: compile_sharded_feature_step(desc, 4)),
+    ):
+        t0 = time.time()
+        print(name, fn(), f"compiled in {time.time() - t0:.1f}s", flush=True)

@@ -97,3 +97,26 @@ def test_show_tensor_info_variants(tmp_path, capsys):
     assert "dev_arr" in line and "sharding=" in line
     out = capsys.readouterr().out
     assert out.count("\n") == 3  # each call printed one line
+
+
+def test_virtual_cpu_mesh_is_cpu_only(monkeypatch):
+    """A process that already holds an accelerator must not switch to
+    virtual CPU devices (it would carry on under the accelerator's name);
+    on the live 8-device CPU mesh the call is a no-op."""
+    import jax
+
+    from quiver_tpu.utils import force_virtual_cpu_devices
+
+    force_virtual_cpu_devices(8)  # what conftest already set up
+    assert len(jax.devices()) == 8
+
+    class FakeTpu:
+        platform = "tpu"
+
+    def refuse(key, value):
+        raise RuntimeError("config should be updated before backends are initialized")
+
+    monkeypatch.setattr(jax.config, "update", refuse)
+    monkeypatch.setattr(jax, "devices", lambda: [FakeTpu()])
+    with pytest.raises(RuntimeError, match="already holds the 'tpu' backend"):
+        force_virtual_cpu_devices(4)
