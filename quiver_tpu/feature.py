@@ -37,6 +37,7 @@ from .shard_tensor import (
     _device_of,
     normalize_dtype,
 )
+from .trace import trace_scope
 from .utils import CSRTopo, IciTopo, parse_size, reindex_feature
 
 
@@ -541,14 +542,15 @@ class Feature:
                 "use __getitem__ (tiered) or the mesh-sharded gather"
             )
         table = st.device_shards[0][1]
-        if self.feature_order is not None:
-            if self._order_dev is None:
-                self._order_dev = jnp.asarray(self.feature_order)
-            rows = _padded_gather_ordered(table, self._order_dev, node_idx)
-        else:
-            rows = _padded_gather(table, node_idx)
-        if valid is not None:
-            rows = rows * valid[:, None].astype(rows.dtype)
+        with trace_scope("quiver.feature.lookup"):
+            if self.feature_order is not None:
+                if self._order_dev is None:
+                    self._order_dev = jnp.asarray(self.feature_order)
+                rows = _padded_gather_ordered(table, self._order_dev, node_idx)
+            else:
+                rows = _padded_gather(table, node_idx)
+            if valid is not None:
+                rows = rows * valid[:, None].astype(rows.dtype)
         return rows
 
     def validate_ids(self, node_idx) -> np.ndarray:

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from ..shard_tensor import _device_of
+from ..trace import trace_scope
 from ..utils import CSRTopo
 from ..ops.sample import (
     pad_widths,
@@ -926,63 +927,69 @@ class GraphSageSampler:
                 "(bind_temporal first)"
             )
         if self.mode == "TPU":
-            indptr, indices, sample_fn, id_dtype = self._engine()
-            seeds = jnp.asarray(np.asarray(seeds), id_dtype)
-            if not self.dedup:
-                return sample_dense_fused(
-                    indptr, indices, self._next_key(), seeds, self.sizes,
-                    sample_fn=sample_fn,
-                )
-            ds = sample_dense_pure(
-                indptr, indices, self._next_key(), seeds, self.sizes, self.caps,
+            with trace_scope("quiver.sample", call=self._call):
+                return self._device_sample_dense(seeds)
+        return self._host_sample_dense(np.asarray(seeds))
+
+    def _device_sample_dense(self, seeds) -> DenseSample:
+        """The TPU path of `sample_dense`: eager, one jitted program per
+        hop (and per reindex, with ``dedup``)."""
+        indptr, indices, sample_fn, id_dtype = self._engine()
+        seeds = jnp.asarray(np.asarray(seeds), id_dtype)
+        if not self.dedup:
+            return sample_dense_fused(
+                indptr, indices, self._next_key(), seeds, self.sizes,
                 sample_fn=sample_fn,
             )
-            if self.auto_grow_caps and self.caps is not None:
-                # overflow ladder: regrow caps from the observed pre-cap
-                # counts and resample until nothing is dropped. raw_counts of
-                # hop l+1 are measured under hop l's (possibly capped)
-                # frontier, so one regrow can reveal more demand — iterate,
-                # bounded (caps_from_counts clips at the uncapped worst case,
-                # where overflow is impossible by construction).
-                for _ in range(len(self.sizes) + 1):
-                    if int(ds.cap_overflow) == 0:
-                        break
-                    grown = caps_from_counts(
-                        np.asarray(ds.raw_counts)[None, :], seeds.shape[0],
-                        self.sizes, margin=self.cap_margin,
-                        granule=self.cap_granule,
-                    )
-                    # monotone merge: one batch's raw_counts must only ever
-                    # RAISE caps — taking them wholesale would shrink hops
-                    # that didn't overflow this batch (raw_counts are a
-                    # single sample, not the calibrated max), ping-ponging
-                    # caps and recompiling every few batches. None stays
-                    # None: an uncapped hop cannot overflow, so capping it
-                    # would force a shape change no overflow ever demanded.
-                    self.caps = tuple(
-                        None if o is None else max(o, n)
-                        for o, n in zip(self.caps, grown)
-                    )
-                    ds = sample_dense_pure(
-                        indptr, indices, self._next_key(), seeds, self.sizes,
-                        self.caps, sample_fn=sample_fn,
-                    )
-                if int(ds.cap_overflow) > 0:
-                    # ladder bound exhausted (per-key count fluctuation can
-                    # outrun a small margin): surface it — the caller still
-                    # sees cap_overflow, but silence here would contradict
-                    # the "resample until nothing is dropped" contract
-                    import warnings
+        ds = sample_dense_pure(
+            indptr, indices, self._next_key(), seeds, self.sizes, self.caps,
+            sample_fn=sample_fn,
+        )
+        if self.auto_grow_caps and self.caps is not None:
+            # overflow ladder: regrow caps from the observed pre-cap
+            # counts and resample until nothing is dropped. raw_counts of
+            # hop l+1 are measured under hop l's (possibly capped)
+            # frontier, so one regrow can reveal more demand — iterate,
+            # bounded (caps_from_counts clips at the uncapped worst case,
+            # where overflow is impossible by construction).
+            for _ in range(len(self.sizes) + 1):
+                if int(ds.cap_overflow) == 0:
+                    break
+                grown = caps_from_counts(
+                    np.asarray(ds.raw_counts)[None, :], seeds.shape[0],
+                    self.sizes, margin=self.cap_margin,
+                    granule=self.cap_granule,
+                )
+                # monotone merge: one batch's raw_counts must only ever
+                # RAISE caps — taking them wholesale would shrink hops
+                # that didn't overflow this batch (raw_counts are a
+                # single sample, not the calibrated max), ping-ponging
+                # caps and recompiling every few batches. None stays
+                # None: an uncapped hop cannot overflow, so capping it
+                # would force a shape change no overflow ever demanded.
+                self.caps = tuple(
+                    None if o is None else max(o, n)
+                    for o, n in zip(self.caps, grown)
+                )
+                ds = sample_dense_pure(
+                    indptr, indices, self._next_key(), seeds, self.sizes,
+                    self.caps, sample_fn=sample_fn,
+                )
+            if int(ds.cap_overflow) > 0:
+                # ladder bound exhausted (per-key count fluctuation can
+                # outrun a small margin): surface it — the caller still
+                # sees cap_overflow, but silence here would contradict
+                # the "resample until nothing is dropped" contract
+                import warnings
 
-                    warnings.warn(
-                        f"auto_grow_caps: still dropping "
-                        f"{int(ds.cap_overflow)} nodes after regrowth to "
-                        f"caps={self.caps}; raise cap_margin/cap_granule",
-                        RuntimeWarning,
-                        stacklevel=2,
-                    )
-            return ds
-        return self._host_sample_dense(np.asarray(seeds))
+                warnings.warn(
+                    f"auto_grow_caps: still dropping "
+                    f"{int(ds.cap_overflow)} nodes after regrowth to "
+                    f"caps={self.caps}; raise cap_margin/cap_granule",
+                    RuntimeWarning,
+                    stacklevel=3,
+                )
+        return ds
 
     def _host_sample_dense(self, seeds: np.ndarray) -> DenseSample:
         eng = self._host()

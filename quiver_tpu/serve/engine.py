@@ -117,7 +117,10 @@ from ..trace import (
     WorkloadConfig,
     WorkloadMonitor,
     export_chrome_trace as _export_chrome_trace,
+    observe,
     register_hit_rate,
+    trace_enabled,
+    trace_scope,
 )
 from .cache import EmbeddingCache
 
@@ -1180,7 +1183,8 @@ class _Flush:
 
     __slots__ = ("keys", "slots", "params", "seeds", "bucket", "ds", "key",
                  "padded", "extra", "error", "fid", "ids", "rids",
-                 "tenant_ix", "graph_version", "binding")
+                 "tenant_ix", "graph_version", "binding", "t_dispatch",
+                 "t_done")
 
     def __init__(self, keys, slots, params):
         self.keys = keys
@@ -1208,6 +1212,9 @@ class _Flush:
         # draw (assemble and seal happen under one _seq hold, so nothing
         # can interleave an increment between them)
         self.fid = -1
+        # the dispatch stage's two stamps (`ServeEngine.flush`), kept for
+        # the per-request stage split `_observe_stages` adds when tracing
+        self.t_dispatch = self.t_done = 0.0
 
 
 def _admit_chunk_fast(eng, keys, nodes, tenants, i, now, events,
@@ -1441,6 +1448,27 @@ def _record_waiter_latency(eng, slots, now: float) -> None:
             by.setdefault(wt[1], []).append(ix)
         for ten, ixs in by.items():
             eng.stats.tenant_hist(ten).record_ms_many(ms[ixs])
+
+
+def _observe_stages(fl, t_res: float) -> None:
+    """The three stages of every request that rode the flush, from stamps
+    the engine holds already, added to the trace registry (`trace.observe`;
+    the caller has asked `trace_enabled`): ``quiver.serve.queue`` (the
+    waiter's submit stamp -> its flush's dispatch stamp),
+    ``quiver.serve.device`` (dispatch -> execute done) and
+    ``quiver.serve.resolved`` (execute done -> the stamp `_resolve` takes
+    under the lock, the end of the waiter's recorded latency). The stages
+    of `EventJournal.request_breakdown`, with one difference: a waiter that
+    coalesced onto the flush after a stage began is charged that stage
+    from its own arrival, so a request's three stages add up to the
+    latency ``stats.latency`` recorded for it."""
+    t0s = np.fromiter((w[0] for s in fl.slots for w in s.waiters), np.float64)
+    t_disp, t_done = fl.t_dispatch, fl.t_done
+    observe("quiver.serve.queue", np.maximum(t_disp - t0s, 0.0))
+    observe("quiver.serve.device",
+            np.maximum(t_done - np.maximum(t0s, t_disp), 0.0))
+    observe("quiver.serve.resolved",
+            np.maximum(t_res - np.maximum(t0s, t_done), 0.0))
 
 
 def _resolve_block(eng, fl, logits: np.ndarray, now: float) -> None:
@@ -1717,7 +1745,10 @@ class ServeEngine:
         shed. KEEP IN LOCKSTEP with `DistServeEngine.submit`
         (serve/dist.py): the distributed router's hosts=1 bit-parity
         contract rides this exact admission sequence."""
-        return self.submit_many((node_id,), tenant=tenant)[0]
+        if not trace_enabled():  # the one per-request site: off, no object
+            return self.submit_many((node_id,), tenant=tenant)[0]
+        with trace_scope("quiver.serve.submit"):
+            return self.submit_many((node_id,), tenant=tenant)[0]
 
     def submit_many(self, node_ids, t=None,
                     tenant: Union[None, str, Sequence[str]] = None,
@@ -2056,11 +2087,11 @@ class ServeEngine:
             self.stats.inflight_peak = max(
                 self.stats.inflight_peak, self._inflight_flushes
             )
+            # the caller holds _seq, so the index _seal_assembled will
+            # draw is exactly the next one
+            fl.fid = self._dispatch_index + 1
             jr = self.journal
             if jr.enabled:
-                # the caller holds _seq, so the index _seal_assembled will
-                # draw is exactly the next one
-                fl.fid = self._dispatch_index + 1
                 # a = the NODE id per the EVENT_KINDS contract (a
                 # temporal key is a (node, t_bucket) tuple); one batched
                 # ring append for the whole drain (round 20)
@@ -2196,7 +2227,7 @@ class ServeEngine:
         """Stage 3: per-flush slot resolution + cache writeback + stats.
         Safe out of dispatch order — only this flush's slots are touched.
         Always decrements the in-flight count and wakes the fence."""
-        with self._lock:
+        with self._lock, trace_scope("quiver.serve.resolve", fid=fl.fid):
             # one clock sample taken AFTER the lock is held: as the span
             # start it keeps lock-wait out of stage-overlap evidence, and
             # as the latency endpoint it keeps lock-wait IN each waiter's
@@ -2248,6 +2279,8 @@ class ServeEngine:
             self.stats.spans.record("resolve", t_res0, self._clock())
             self.journal.record_many((("resolve", -1, fl.fid,
                                        len(fl.keys), 0),))
+        if fl.error is None and trace_enabled():
+            _observe_stages(fl, now)
 
     def flush(self) -> int:
         """Dispatch up to ``max_batch`` pending unique seeds as one bucket-
@@ -2273,7 +2306,9 @@ class ServeEngine:
                 # full window) is idle, not working, and counting the wait
                 # would fake stage overlap
                 t0 = self._clock()
-                fl = self._assemble()
+                with trace_scope("quiver.serve.assemble",
+                                 fid=self._dispatch_index + 1):
+                    fl = self._assemble()
                 if fl is not None:
                     self.stats.spans.record("assemble", t0, self._clock())
                 if fl is None:
@@ -2295,7 +2330,8 @@ class ServeEngine:
                         jr.emit("window_wait", -1, fl.fid,
                                 self._clock() - t_w0)
                     t0 = self._clock()
-                    self._seal_assembled(fl)  # errors land in fl.error
+                    with trace_scope("quiver.serve.assemble", fid=fl.fid):
+                        self._seal_assembled(fl)  # errors land in fl.error
                     self.stats.spans.record("assemble", t0, self._clock())
                 finally:
                     # _seal_assembled's first act already closed admission
@@ -2308,10 +2344,12 @@ class ServeEngine:
             if fl.error is None:
                 t0 = self._clock()
                 try:
-                    logits = self._dispatch(fl)
+                    with trace_scope("quiver.serve.dispatch", fid=fl.fid):
+                        logits = self._dispatch(fl)
                 except BaseException as exc:
                     fl.error = exc
                 t1 = self._clock()
+                fl.t_dispatch, fl.t_done = t0, t1
                 self.stats.spans.record("dispatch", t0, t1)
                 if self.workload is not None:
                     # per-flush width + latency (owner 0: this engine is

@@ -2,41 +2,74 @@
 
 Re-design of the reference's observability surface (SURVEY.md section 5):
 
-- RAII scope timer (include/quiver/timer.hpp:7-28) -> :class:`timer` /
-  :func:`trace_scope` context managers;
+- RAII scope timer (include/quiver/timer.hpp:7-28) -> :class:`timer`;
 - compile-time TRACE_SCOPE macros gated by QUIVER_ENABLE_TRACE
-  (include/quiver/trace.hpp:6-14, setup.py:45-46) -> runtime gating by the
-  same env var, durations aggregated in a process-local registry;
+  (include/quiver/trace.hpp:6-14, setup.py:45-46) -> :class:`trace_scope`,
+  the library's one span primitive: on when the same env var is set or a
+  `jax.profiler` session is recording, each span written to the profiler
+  (on the device lines' clock) and aggregated in a process-local registry
+  (:func:`trace_report`); :func:`observe` adds durations a caller computed
+  from stamps it already held;
 - ad-hoc benchmark metrics (SEPS, benchmarks/sample/bench_sampler.py:14-16;
   GB/s, benchmarks/feature/bench_feature.py:44-46) -> :func:`seps` /
-  :func:`gbps` helpers so every bench reports identically;
-- GPU profiler gap -> `jax.profiler` pass-throughs (:func:`start_profile`)
-  producing TensorBoard/XProf traces with real TPU timelines.
+  :func:`gbps` helpers so every bench reports identically.
 """
 
 from __future__ import annotations
 
-import contextlib
+import bisect
+import math
 import os
 import threading
 import time
-from collections import defaultdict
-from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
+
+import jax
+import numpy as np
+from jax.profiler import TraceAnnotation
 
 TRACE_ENV = "QUIVER_ENABLE_TRACE"
 
-_registry: Dict[str, Tuple[int, float]] = defaultdict(lambda: (0, 0.0))
-# trace_scope aggregation is a read-modify-write on _registry[name]; serve
-# pollers and client threads trace concurrently, so an unlocked update
-# loses counts (two threads read the same (cnt, tot) and one increment
-# vanishes). One process-wide lock covers the update AND the
-# trace_report(reset=True) snapshot-then-clear, which would otherwise drop
-# scopes landing between the dict copy and the clear.
+# The jitted callables whose XLA module names (``jit_<__name__>``) outside
+# readers match by pattern (qbench/metrics/*.json: ``padded_gather``,
+# ``train_step``, ...). A rename at the site renames the program in every
+# device trace and silences those readers without an error, so the names
+# are held at both ends by tests/qbench/test_qbench_program_names.py.
+PROGRAM_NAMES = (
+    "tiled_sample_layer",       # ops/sample.py
+    "local_reindex",            # ops/reindex.py
+    "_padded_gather",           # feature.py
+    "_padded_gather_ordered",   # feature.py
+    "serve_step",               # inference.make_serve_step
+)
+
+# name -> [count, total seconds, longest seconds]
+_registry: Dict[str, list] = {}
+# aggregation is a read-modify-write on _registry[name]; serve pollers and
+# client threads trace concurrently, so an unlocked update loses counts
+# (two threads read the same entry and one increment vanishes). One
+# process-wide lock covers the update AND the trace_report(reset=True)
+# snapshot-then-clear, which would otherwise drop scopes landing between
+# the dict copy and the clear.
 _registry_lock = threading.Lock()
 
 
+# The gate runs once per request on the serve path, where the interpreter
+# lock is the saturated resource: a microsecond a request read as 3% of the
+# median latency on the chip (PERF.md, PR 26). `os.environ.get` of a name
+# that is NOT set costs that microsecond (it raises and catches KeyError
+# twice inside); the mapping behind it answers in 40 ns, and is what
+# `os.environ[...] = ...` and `del` (so `monkeypatch.setenv`) write.
+_ENV_DATA = os.environ._data
+_ENV_KEY = os.environ.encodekey(TRACE_ENV)
+_ENV_OFF = frozenset(os.environ.encodevalue(v) for v in ("0", "", "false", "False"))
+
+
 def trace_enabled() -> bool:
-    return os.environ.get(TRACE_ENV, "0") not in ("0", "", "false", "False")
+    """Whether spans record: a profiler session is open or
+    QUIVER_ENABLE_TRACE is set (read on every call)."""
+    return TraceAnnotation.is_enabled() or (
+        _ENV_DATA.get(_ENV_KEY, b"0") not in _ENV_OFF)
 
 
 class timer:
@@ -61,61 +94,86 @@ class timer:
             print(f"[quiver-tpu] {self.name}: {self.elapsed*1e3:.3f} ms")
 
 
-class _SyncBox:
-    """Mutable handle a scope can park device arrays in (``box.sync = out``)
-    so the scope waits for their EXECUTION, not just dispatch."""
+def _add(name: str, n: int, total: float, longest: float) -> None:
+    with _registry_lock:
+        entry = _registry.get(name)
+        if entry is None:
+            _registry[name] = [n, total, longest]
+        else:
+            entry[0] += n
+            entry[1] += total
+            if longest > entry[2]:
+                entry[2] = longest
 
-    __slots__ = ("sync",)
 
-    def __init__(self):
-        self.sync = None
-
-
-@contextlib.contextmanager
-def trace_scope(name: str, sync=None) -> Iterator["_SyncBox"]:
-    """TRACE_SCOPE analog (trace.hpp:6-14): no-op unless QUIVER_ENABLE_TRACE
-    is set; aggregates (count, total seconds) per scope name.
+class trace_scope:
+    """The span primitive (TRACE_SCOPE analog, trace.hpp:6-14). Off, which
+    is unless `trace_enabled`, entering and leaving is that one check: no
+    clock is read and nothing is recorded. On, the span is written to the
+    profiler as a ``TraceAnnotation(name, **ids)`` (it lands on the host
+    plane of the session's ``.xplane.pb``, on the clock of the device
+    lines, with ``ids`` as the event's stats) and ``(1, duration)`` is
+    added to the registry under ``name``. While `jax.jit` (or any other
+    transformation) traces the enclosing function nothing is recorded:
+    that would time the tracing, once, and not the work.
 
     JAX dispatch is asynchronous, so a bare wall clock measures *enqueue*
     time, not device time. Pass the scope's output arrays via ``sync=`` (or
-    assign them to the yielded box: ``with trace_scope("s") as b: b.sync =
-    out``) and the scope calls ``jax.block_until_ready`` before stopping the
-    clock."""
-    box = _SyncBox()
-    box.sync = sync
+    assign them inside: ``with trace_scope("s") as b: b.sync = out``) and
+    the scope calls ``jax.block_until_ready`` before stopping the clock.
+    No hot path does: a wait changes what it measures."""
+
+    __slots__ = ("name", "sync", "_ids", "_span", "_t0")
+
+    def __init__(self, name: str, sync=None, **ids):
+        self.name = name
+        self.sync = sync
+        self._ids = ids
+        self._span = None
+
+    def __enter__(self) -> "trace_scope":
+        if trace_enabled() and jax.core.trace_ctx.is_top_level():
+            self._span = TraceAnnotation(self.name, **self._ids)
+            self._span.__enter__()
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        span = self._span
+        if span is None:
+            return
+        if self.sync is not None:
+            jax.block_until_ready(self.sync)
+        dt = time.perf_counter() - self._t0
+        span.__exit__(*exc)
+        self._span = None
+        _add(self.name, 1, dt, dt)
+
+
+def observe(name: str, seconds) -> None:
+    """Add durations the caller computed itself, from stamps it already
+    holds, to the registry under ``name``: one number, or an array of them
+    (one per request of a flush). Same gate as `trace_scope`; a caller
+    whose durations cost something to compute asks `trace_enabled` first."""
     if not trace_enabled():
-        yield box
         return
-    t0 = time.perf_counter()
-    try:
-        yield box
-    finally:
-        if box.sync is not None:
-            import jax
-
-            jax.block_until_ready(box.sync)
-        dt = time.perf_counter() - t0
-        with _registry_lock:
-            cnt, tot = _registry[name]
-            _registry[name] = (cnt + 1, tot + dt)
+    arr = np.asarray(seconds, np.float64).reshape(-1)
+    if arr.size:
+        _add(name, arr.size, float(arr.sum()), float(arr.max()))
 
 
-def trace_report(reset: bool = False) -> Dict[str, Tuple[int, float]]:
-    """Snapshot of aggregated scopes: {name: (count, total_seconds)}.
+def trace_report(reset: bool = False, with_max: bool = False) -> Dict[str, Tuple]:
+    """Snapshot of aggregated spans: {name: (count, total_seconds)}, or
+    with ``with_max`` {name: (count, total_seconds, longest_seconds)}.
     ``reset=True`` snapshots and clears ATOMICALLY (same lock as the scope
     updates), so no concurrently-finishing scope falls between the copy
     and the clear."""
     with _registry_lock:
-        out = dict(_registry)
+        out = {k: tuple(v) if with_max else (v[0], v[1])
+               for k, v in _registry.items()}
         if reset:
             _registry.clear()
     return out
-
-
-def print_trace_report() -> None:
-    for name, (cnt, tot) in sorted(trace_report().items()):
-        avg = tot / max(cnt, 1)
-        print(f"[trace] {name}: n={cnt} total={tot:.4f}s avg={avg*1e3:.3f}ms")
 
 
 # -- benchmark metric helpers -------------------------------------------------
@@ -150,8 +208,6 @@ def dtype_bytes(dtype) -> int:
     np.int8, a numpy dtype, ...) — the helper quantized benches use so
     `gbps` reports WIRE bytes, not fp32-equivalent bytes. For a codec,
     pass ``codec.bytes_per_elem`` directly instead (int8 payload = 1)."""
-    import numpy as np
-
     if str(dtype) in ("bfloat16", "bf16"):
         import jax.numpy as jnp
 
@@ -173,12 +229,6 @@ def gbps(
 
 
 # -- stage-span overlap evidence ----------------------------------------------
-
-import bisect
-import math
-
-import numpy as np
-
 
 def _snapshot_deque(dq) -> tuple:
     """Consistent tuple copy of a deque under concurrent appends:
@@ -1425,29 +1475,3 @@ from .obs import (  # noqa: E402
     WorkloadMonitor,
     lru_hit_rate_che,
 )
-
-
-# -- jax profiler pass-throughs ----------------------------------------------
-
-def start_profile(logdir: str) -> None:
-    import jax
-
-    jax.profiler.start_trace(logdir)
-
-
-def stop_profile() -> None:
-    import jax
-
-    jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def profile(logdir: Optional[str] = None) -> Iterator[None]:
-    if logdir is None:
-        yield
-        return
-    start_profile(logdir)
-    try:
-        yield
-    finally:
-        stop_profile()

@@ -515,7 +515,7 @@ def test_train_mid_epoch_disk_error_contract(tmp_path):
     f = Feature(
         rank=0, device_cache_size=24 * rowb, host_memory_budget=48 * rowb,
         disk_path=os.path.join(str(tmp_path), "err.npy"),
-        read_pool=AsyncReadPool(2, chunk_rows=32),
+        read_pool=AsyncReadPool(2, chunk_rows=32, name="qt-err-read"),
     )
     f.from_cpu_tensor(feat)
     sampler = GraphSageSampler(topo, sizes=[5, 5], mode="TPU", seed=1)
@@ -545,7 +545,18 @@ def test_train_mid_epoch_disk_error_contract(tmp_path):
         return orig(ids)
 
     shard.read_block = failing
-    before = thread_names()
+
+    def own_threads():
+        """Live threads the pipeline under test started: its three stage
+        pools (named in `TrainPipeline._run`) and this test's read pool.
+        Not the process's whole census: an earlier test's pool workers or
+        serve daemons may end at any moment (a collected executor wakes
+        its workers to exit) and are none of this test's business."""
+        return [t for t in threading.enumerate() if t.name.startswith(
+            ("qt-sample", "qt-gather", "qt-upload", "qt-err-read"))]
+
+    before = sorted(t.name for t in own_threads())
+    assert before == ["qt-err-read_0", "qt-err-read_1"]
     try:
         t0 = time.perf_counter()
         with pytest.raises(OSError, match="disk died mid-epoch"):
@@ -555,7 +566,10 @@ def test_train_mid_epoch_disk_error_contract(tmp_path):
         shard.read_block = orig
     # unwind left no staged rows and no stray threads
     assert len(pipe._prefetch) == 0
-    assert thread_names() == before
+    for t in own_threads():
+        if t.name not in before:
+            t.join(timeout=5.0)  # one still ending is given a moment to
+    assert sorted(t.name for t in own_threads()) == before
     # the surviving pipeline trains a clean epoch
     _, _, losses = tp.run_epoch(seeds[:3], params, tx.init(params),
                                 jax.random.key(2))
