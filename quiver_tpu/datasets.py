@@ -202,8 +202,9 @@ def cache_hit_rate(
 ) -> float:
     """Hit rate of a degree-ordered hot prefix of size ``cache_ratio * N``
     against observed gather batches (reference test_partition.py:66-100
-    measures the same CDF). ``csr_topo.feature_order`` must be set (Feature
-    attaches it) or degrees are used directly."""
+    measures the same CDF). Reads ``csr_topo.feature_order`` where a tiered
+    Feature attached it, else (no Feature, or a wholly hot one, which does
+    not reorder) the degrees directly."""
     n = csr_topo.node_count
     cache_rows = int(n * cache_ratio)
     if csr_topo.feature_order is not None:

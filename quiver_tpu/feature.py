@@ -16,8 +16,11 @@ Cache policies (reference feature.py:43-45, docs/Introduction_en.md:104-119):
   uses ``quiver_tpu.parallel.collectives.sharded_gather`` inside shard_map.
 
 The degree-descending hot ordering comes from ``reindex_feature``
-(reference utils.py:230-248) when a ``csr_topo`` is attached; lookups remap
-through ``feature_order`` exactly like reference feature.py:296-333.
+(reference utils.py:230-248) when a ``csr_topo`` is attached AND a colder
+tier exists (host tail or disk); lookups then remap through
+``feature_order`` exactly like reference feature.py:296-333. A table the hot
+tier holds whole is stored in the caller's row order: ``feature_order`` stays
+``None`` and a lookup is the row gather alone.
 """
 
 from __future__ import annotations
@@ -138,15 +141,17 @@ def attribute_gather_tiers(shard_tensor, rank, stored_ids, counter,
             counter.hit(n - pre, tier="disk")
 
 
+# mode="clip", not clip-then-take: `jnp.take` defaults to mode="fill", whose
+# NaN fill is a select over the whole output that XLA keeps even when the ids
+# were clipped the line before (5 ms a step on 1.7 GB of igb-small rows)
 @jax.jit
 def _padded_gather(table: jax.Array, ids: jax.Array) -> jax.Array:
-    return jnp.take(table, jnp.clip(ids, 0, table.shape[0] - 1), axis=0)
+    return jnp.take(table, ids, axis=0, mode="clip")
 
 
 @jax.jit
 def _padded_gather_ordered(table: jax.Array, order: jax.Array, ids: jax.Array) -> jax.Array:
-    ids = jnp.take(order, jnp.clip(ids, 0, order.shape[0] - 1))
-    return jnp.take(table, jnp.clip(ids, 0, table.shape[0] - 1), axis=0)
+    return jnp.take(table, jnp.take(order, ids, mode="clip"), axis=0, mode="clip")
 
 
 class Feature:
@@ -159,6 +164,9 @@ class Feature:
     device_cache_size : per-chip hot bytes (int or "200M"/"4G" strings)
     cache_policy : "device_replicate" | "p2p_clique_replicate" | "ici_replicate"
     csr_topo : optional CSRTopo — enables degree-ordered hot placement
+        when the hot tier cannot hold every row (a wholly hot table with no
+        disk tier is stored as given: ``feature_order`` and
+        ``csr_topo.feature_order`` stay ``None``)
 
     Round 14 (disk tier — docs/api.md "Tiered storage"):
 
@@ -262,15 +270,19 @@ class Feature:
         self._n, self._dim = arr.shape
         row_bytes = self._dim * self.dtype.itemsize
         cache_rows = min(self.device_cache_size // row_bytes, self._n)
+        # chips whose HBM holds the hot set: this one, or the striped clique
+        clique = (list(self.topo.get_clique(self.rank))
+                  if self.cache_policy == "p2p_clique_replicate" else [self.rank])
+        hot_total = min(cache_rows * len(clique), self._n)
+        wholly_hot = hot_total >= self._n and self.disk_path is None
 
-        if self.csr_topo is not None and not self._local_order_applied:
+        if (self.csr_topo is not None and not self._local_order_applied
+                and not wholly_hot):
             # degree-descending reorder so the cache prefix is hot
-            # (reference feature.py:211-215)
-            if self.cache_policy == "p2p_clique_replicate":
-                clique = self.topo.get_clique(self.rank)
-                ratio = min(cache_rows * len(clique), self._n) / max(self._n, 1)
-            else:
-                ratio = cache_rows / max(self._n, 1)
+            # (reference feature.py:211-215). With every row hot there is no
+            # prefix to choose: the reorder would only permute the table and
+            # make each lookup gather through `feature_order` to undo it.
+            ratio = hot_total / max(self._n, 1)
             arr, order = reindex_feature(self.csr_topo, arr, ratio)
             self.feature_order = order
             self.csr_topo.feature_order = order
@@ -292,8 +304,6 @@ class Feature:
                 st.append(arr[cache_rows:], CPU_DEVICE)
         else:
             # hot set striped across the ICI clique (reference feature.py:225-265)
-            clique = [d for d in self.topo.get_clique(self.rank)]
-            hot_total = min(cache_rows * len(clique), self._n)
             per = hot_total // max(len(clique), 1)
             cursor = 0
             for dev in clique:
@@ -534,6 +544,13 @@ class Feature:
         Requires the feature to be fully device-resident (single hot shard on
         this chip covering all rows); multi-tier padded lookup goes through
         `quiver_tpu.parallel.collectives.sharded_gather` on a mesh.
+
+        Out-of-range ids are CLIPPED into the table (negative -> id 0,
+        ``>= N`` -> id N-1), never filled: see `validate_lookup_ids`. The
+        program is `_padded_gather`, or `_padded_gather_ordered` when a
+        ``feature_order`` (degree reorder of a tiered table,
+        `set_local_order`) stands between ids and stored rows; the span
+        carries which as ``ordered``.
         """
         st = self.shard_tensor
         if st is None or st.cpu_tensor is not None or len(st.device_shards) != 1:
@@ -542,8 +559,9 @@ class Feature:
                 "use __getitem__ (tiered) or the mesh-sharded gather"
             )
         table = st.device_shards[0][1]
-        with trace_scope("quiver.feature.lookup"):
-            if self.feature_order is not None:
+        ordered = self.feature_order is not None
+        with trace_scope("quiver.feature.lookup", ordered=int(ordered)):
+            if ordered:
                 if self._order_dev is None:
                     self._order_dev = jnp.asarray(self.feature_order)
                 rows = _padded_gather_ordered(table, self._order_dev, node_idx)

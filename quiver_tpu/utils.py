@@ -69,7 +69,8 @@ class CSRTopo:
         sage_sampler.py:143, but we keep the slot)
     feature_order : optional np.ndarray [N] new_order permutation produced by
         `reindex_by_config` / `Feature.from_cpu_tensor` (reference
-        utils.py:171-186)
+        utils.py:171-186); stays None under a `Feature` whose hot tier holds
+        the whole table (no reorder happens there)
     """
 
     def __init__(

@@ -206,21 +206,101 @@ def test_lookup_padded_clip_semantics_direct(table):
 def test_lookup_padded_clip_semantics_remapped(table):
     """Same pin for the feature_order-remapped path (_padded_gather_ordered):
     the CLIP happens in ORIGINAL id space first, so an oob id resolves to
-    the clamped original id's row — bit-identical to looking up id N-1."""
+    the clamped original id's row — bit-identical to looking up id N-1.
+    A wholly hot table has no degree reorder, so the order comes from
+    `set_local_order` (a permutation: every global id is owned)."""
     import jax.numpy as jnp
 
-    edge_index = make_random_graph(500, 4000, seed=9)
-    topo = CSRTopo(edge_index=edge_index)
-    feat = Feature(
-        rank=0, device_list=[0], device_cache_size=500 * 16 * 4, csr_topo=topo
-    )
-    feat.from_cpu_tensor(table)
+    local_order = np.random.default_rng(3).permutation(500)
+    feat = Feature(rank=0, device_list=[0], device_cache_size=500 * 16 * 4)
+    feat.from_cpu_tensor(table[local_order])  # local row i holds global id local_order[i]
+    feat.set_local_order(local_order)
     assert feat.feature_order is not None
     got = np.asarray(feat.lookup_padded(jnp.asarray(np.array([700, 499, -3, 0]))))
     np.testing.assert_allclose(got[0], table[499])  # oob -> clamped id 499's row
     np.testing.assert_allclose(got[1], table[499])
     np.testing.assert_allclose(got[2], table[0])    # negative -> id 0's row
     np.testing.assert_allclose(got[3], table[0])
+
+
+OOB_IDS = np.array([5, 100, 250, 499, 0, -1, -7, 500, 10_000])
+
+
+def assert_getitem_exact_and_zero_filled(feat, table):
+    """`feat[ids]`: the host table's rows bit for bit, zeros out of range."""
+    valid = (OOB_IDS >= 0) & (OOB_IDS < table.shape[0])
+    got = np.asarray(feat[OOB_IDS])
+    np.testing.assert_array_equal(got[valid], table[OOB_IDS[valid]])
+    assert not got[~valid].any()
+
+
+@pytest.mark.parametrize("policy", ["device_replicate", "p2p_clique_replicate"])
+def test_wholly_hot_with_csr_topo_keeps_the_given_order(table, policy):
+    """With every row in the hot tier there is no prefix to choose: no
+    degree reorder, no `feature_order` (on the Feature or the CSRTopo), and
+    the lookups return the host table's rows bit for bit — `lookup_padded`
+    clipping out-of-range ids, `__getitem__` zero-filling them."""
+    import jax.numpy as jnp
+
+    from quiver_tpu import IciTopo
+
+    topo = CSRTopo(edge_index=make_random_graph(500, 4000, seed=9))
+    feat = Feature(rank=0, device_list=[0], device_cache_size=table.nbytes,
+                   cache_policy=policy, csr_topo=topo)
+    feat.topo = IciTopo(cliques=[[0]])  # a one-chip clique, as on one v5e
+    feat.from_cpu_tensor(table)
+    assert feat.feature_order is None and topo.feature_order is None
+    assert feat.shard_tensor.cpu_tensor is None
+    stored = np.asarray(feat.shard_tensor.device_shards[0][1])
+    np.testing.assert_array_equal(stored, table)
+    got = np.asarray(feat.lookup_padded(jnp.asarray(OOB_IDS)))
+    np.testing.assert_array_equal(got, table[np.clip(OOB_IDS, 0, 499)])
+    assert_getitem_exact_and_zero_filled(feat, table)
+
+
+def test_wholly_hot_clique_stripes_keep_the_given_order(table):
+    """`hot_total >= n` across the clique's chips (8 here) is wholly hot
+    too: the stripes are slices of the table as given."""
+    topo = CSRTopo(edge_index=make_random_graph(500, 4000, seed=9))
+    feat = Feature(rank=0, device_list=[0, 1], device_cache_size=100 * 16 * 4,
+                   cache_policy="p2p_clique_replicate", csr_topo=topo)
+    assert len(feat.topo.get_clique(0)) * 100 >= 500
+    feat.from_cpu_tensor(table)
+    assert feat.feature_order is None and topo.feature_order is None
+    for _, shard, off in feat.shard_tensor.device_shards:
+        np.testing.assert_array_equal(np.asarray(shard), table[off.start:off.end])
+    assert_getitem_exact_and_zero_filled(feat, table)
+
+
+def test_disk_tier_keeps_the_reorder_with_a_whole_table_cache(tmp_path, table):
+    """A disk tier keeps the degree reorder whatever the cache holds: the
+    adaptive store moves rows between tiers later, by stored position."""
+    topo = CSRTopo(edge_index=make_random_graph(500, 4000, seed=9))
+    feat = Feature(rank=0, device_list=[0], device_cache_size=table.nbytes,
+                   csr_topo=topo, disk_path=str(tmp_path / "tail.npy"))
+    feat.from_cpu_tensor(table)
+    assert feat.feature_order is not None and topo.feature_order is not None
+    np.testing.assert_array_equal(np.asarray(feat[OOB_IDS[:5]]), table[OOB_IDS[:5]])
+
+
+@pytest.mark.parametrize("program", ["_padded_gather", "_padded_gather_ordered"])
+def test_gather_programs_lower_to_the_gathers_alone(program):
+    """The programs `lookup_padded` launches hold one gather of the table
+    (and one of `order`) and no select: `jnp.take`'s default mode="fill"
+    brings a select over the whole output that XLA keeps on the chip even
+    behind a clip (5 ms a step on igb-small's 1.7 GB of rows)."""
+    import jax
+    import jax.numpy as jnp
+
+    from quiver_tpu import feature as feature_mod
+
+    table = jax.ShapeDtypeStruct((500, 16), jnp.float32)
+    order = jax.ShapeDtypeStruct((500,), jnp.int64)
+    ids = jax.ShapeDtypeStruct((64,), jnp.int32)
+    args = (table, ids) if program == "_padded_gather" else (table, order, ids)
+    text = getattr(feature_mod, program).lower(*args).as_text()
+    assert text.count('"stablehlo.gather"(') == len(args) - 1
+    assert "select" not in text
 
 
 def test_validate_ids_opt_in(table):

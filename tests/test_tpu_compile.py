@@ -11,6 +11,8 @@ main path, at the shapes `chip_smoke.py` runs, are lowered for a described
       donated as `BucketPrograms` donates it
   (d) `make_sharded_topo_train_step(layout="tiled")` on the four described
       devices (the layout is passed: `default_backend()` says cpu here)
+  (e) the two programs of `Feature.lookup_padded`, at the shapes of the
+      benchmark's train cells: the row gather and nothing beside it
 
 A compile that passes is not a chip run. To stay inside the suite's time
 limit the tests compile (b) at batch 64 and (c) at bucket 8 (the graph and
@@ -20,6 +22,7 @@ prints what the compiler reports.
 """
 
 import os
+import re
 import sys
 import time
 
@@ -35,6 +38,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from chip_smoke import PRODUCTS, SIZES, make_model, make_train_step, ring_sampler
+from quiver_tpu.feature import _padded_gather, _padded_gather_ordered
 from quiver_tpu.inference import make_serve_step
 from quiver_tpu.ops.sample import LANE, tiled_sample_layer
 from quiver_tpu.parallel import make_sharded_topo_train_step, make_sharded_train_step
@@ -225,6 +229,43 @@ def test_serve_bucket_compiles_for_v5e(v5e):
 
 def test_tiled_sharded_topo_step_compiles_for_four_v5e(v5e):
     compile_sharded_topo_step(v5e)
+
+
+def compile_feature_gather(v5e, program, rows, dim, positions):
+    """The entry computation of a `lookup_padded` program as
+    ``[(shape, opcode)]``."""
+    args = [_sds((rows, dim), jnp.float32), _sds((positions,), jnp.int32)]
+    if program is _padded_gather_ordered:
+        args.insert(1, _sds((rows,), jnp.int32))  # `order`
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    text = program.lower(*_struct(args, one_chip)).compile().as_text()
+    return re.findall(r"^\s+(?:ROOT )?%\S+ = (\S+?)\{\S* ([\w-]+)\(",
+                      text[text.index("ENTRY"):], re.M)
+
+
+@pytest.mark.parametrize("program", [_padded_gather, _padded_gather_ordered],
+                         ids=lambda p: p.__name__)
+@pytest.mark.parametrize("rows,dim,positions,table_ops", [
+    # products-sage.train-fused. The TPU keeps f32[N, 100] column-major, so
+    # the program still transposes the whole table before it gathers rows
+    # (PERF.md section 7): the one operation left that is not the gather
+    (N, PRODUCTS["dim"], 1_081_344, ["parameter", "copy"]),
+    # igb-small-sage.train-dedup: 1024 lanes, row-major as it is
+    (1_000_000, 1024, 417_792, ["parameter"]),
+])
+def test_feature_gather_is_the_row_gather_alone_on_v5e(
+        v5e, program, rows, dim, positions, table_ops):
+    """No select over the gathered rows (mode="clip"): beside the gathers
+    only index clamps and layout copies."""
+    ops = compile_feature_gather(v5e, program, rows, dim, positions)
+    table_shape, out_shape = f"f32[{rows},{dim}]", f"f32[{positions},{dim}]"
+    assert [op for shape, op in ops if shape == table_shape] == table_ops, ops
+    assert sum(shape == out_shape and op == "fusion" for shape, op in ops) == 1, ops
+    assert {op for _, op in ops} <= {"parameter", "fusion", "copy", "copy-start",
+                                     "copy-done"}, ops
+    # a clamp and a gather for the table, the same again for `order`
+    assert sum(op == "fusion" for _, op in ops) == (
+        4 if program is _padded_gather_ordered else 2), ops
 
 
 if __name__ == "__main__":

@@ -241,6 +241,28 @@ def test_sampler_and_feature_sites(tmp_path):
     assert sum(e[1] == "quiver.feature.lookup" for e in events) == 3
 
 
+def test_feature_lookup_span_says_which_program_ran(tmp_path):
+    """`quiver.feature.lookup` carries ``ordered``: 0 while a wholly hot
+    table is stored as given (`_padded_gather`), 1 once an order stands
+    between ids and stored rows (`_padded_gather_ordered`, here after
+    `set_local_order`); with tracing off nothing is recorded."""
+    feat = np.random.default_rng(1).standard_normal((N_NODES, DIM)).astype(np.float32)
+    feature = Feature(rank=0, device_list=[0], device_cache_size=feat.nbytes,
+                      csr_topo=make_topo())
+    feature.from_cpu_tensor(feat)
+    ids = jnp.arange(8)
+    feature.lookup_padded(ids)  # no session: not recorded
+    assert trace_report() == {}
+    with session(tmp_path) as s:
+        feature.lookup_padded(ids)
+        feature.lookup_padded(ids)
+        feature.set_local_order(np.arange(N_NODES))
+        feature.lookup_padded(ids)
+    assert trace_report()["quiver.feature.lookup"][0] == 3
+    events = host_events(s["path"], "quiver.feature.lookup")
+    assert [e[3] for e in events] == [{"ordered": 0}, {"ordered": 0}, {"ordered": 1}]
+
+
 def drive(eng, nodes, clients=4):
     """Threaded single-request clients through submit/result (the path the
     benchmark's serve cell drives); rows in request order."""
