@@ -464,7 +464,7 @@ def serve_phase(cfg: dict, data: Data, topo, model, params, seed: int,
 def four_chip_phase(cfg: dict, data: Data, seed: int, watch: CompileWatch) -> None:
     """The sharded train steps on a (dp=1, ici=4) mesh — feature rows striped
     over four chips with the graph replicated, then the graph row-sharded
-    too (``layout=None``, so the library picks it from the backend) — against
+    too (``layout=None``, so the library resolves it from the graph) — against
     the one-device step on the same key and seeds. dp=1 because data-parallel
     groups fold their index into the sampling key: only one group has a
     one-device twin."""
@@ -486,15 +486,16 @@ def four_chip_phase(cfg: dict, data: Data, seed: int, watch: CompileWatch) -> No
         ShardedTopology,
         TiledShardedTopology,
         resolve_topology_layout,
+        tile_slots_per_edge,
     )
     from quiver_tpu.serve import resolve_exchange_mode
 
     platform = jax.devices()[0].platform
-    layout = resolve_topology_layout(None)
+    layout = resolve_topology_layout(None, data.indptr)
     exchange = resolve_exchange_mode("auto", hosts=4)
-    emit(phase="choices", topology_layout=layout, dist_exchange_hosts4=exchange)
-    if platform == "tpu":
-        check(layout == "tiled", f"layout=None resolved to {layout!r} on a TPU")
+    emit(phase="choices", topology_layout=layout,
+         tile_slots_per_edge=round(tile_slots_per_edge(data.indptr), 2),
+         dist_exchange_hosts4=exchange)
     check(exchange == "collective", f"auto exchange is {exchange!r} on 4 devices")
 
     topo = CSRTopo(indptr=data.indptr, indices=data.indices)
@@ -571,8 +572,7 @@ def four_chip_phase(cfg: dict, data: Data, seed: int, watch: CompileWatch) -> No
             stopo, feat),
     }
     # the twin is the row-sharded step on a one-device mesh (one shard = the
-    # whole graph): draws are identical across layouts, and its tiled
-    # sampling compiles in seconds where the flat-CSR step takes minutes
+    # whole graph): the same code, the same draws from the same key
     mesh1 = make_mesh(1)
     want = run("one_device", mesh1,
                make_sharded_topo_train_step(mesh1, model, tx, SIZES,

@@ -219,16 +219,13 @@ def _tiled_bd_lookup(bd, seeds, seed_valid):
     return both[:, 0], jnp.where(seed_valid, both[:, 1], 0)
 
 
-def _tiled_resolve(tiles, base, pos, k):
-    """Resolve drawn positions to neighbor ids through the tile table:
-    k-split row gathers + one-hot lane selects (k separate [B]-row
-    gathers measured faster than one [B*k]: probe_tiled_variants 6.2 vs
-    7.1 ms; one-hot instead of take_along_axis — the descriptor trap,
-    probe_fetch_final). Shared by the uniform and weighted tiled layers
-    so the fetch pattern is tuned in ONE place."""
-    rows = base[:, None] + lax.shift_right_logical(pos, LANE.bit_length() - 1)
-    rows = jnp.clip(rows, 0, tiles.shape[0] - 1)
-    lane = jnp.bitwise_and(pos, LANE - 1)
+def _select_lanes(tiles, rows, lane, k):
+    """``tiles[rows[b, j], lane[b, j]]`` (rows in range) as k-split row gathers + one-hot
+    lane selects (k separate [B]-row gathers measured faster than one
+    [B*k]: probe_tiled_variants 6.2 vs 7.1 ms; one-hot instead of
+    take_along_axis — the descriptor trap, probe_fetch_final). The ONE
+    position fetch: the tiled layers and the flat sharded layer all ride
+    it, so the fetch pattern is tuned in one place."""
     ar = jnp.arange(LANE, dtype=jnp.int32)
     cols = []
     for j in range(k):
@@ -236,6 +233,31 @@ def _tiled_resolve(tiles, base, pos, k):
         oh = lane[:, j][:, None] == ar[None, :]
         cols.append(jnp.where(oh, win, 0).sum(axis=1))
     return jnp.stack(cols, axis=1).astype(tiles.dtype)
+
+
+def _tiled_resolve(tiles, base, pos, k):
+    """Resolve drawn positions to neighbor ids through the tile table:
+    position ``p`` of a node sits at tile row ``base + p // 128``, lane
+    ``p % 128``. Shared by the uniform and weighted tiled layers."""
+    rows = base[:, None] + lax.shift_right_logical(pos, LANE.bit_length() - 1)
+    rows = jnp.clip(rows, 0, tiles.shape[0] - 1)
+    return _select_lanes(tiles, rows, jnp.bitwise_and(pos, LANE - 1), k)
+
+
+def flat_resolve(indices, ptr, pos, k):
+    """Resolve drawn positions through a FLAT edge array seen as 128-lane
+    rows (``indices`` [E], E a multiple of 128: the reshape is free):
+    position ``p`` of a node whose list starts at ``ptr`` sits at row
+    ``(ptr + p) // 128``, lane ``(ptr + p) % 128``. The same row-gather
+    fetch as the tile layout with no per-node padding — a list may straddle
+    two rows, which costs nothing: every position is its own descriptor
+    either way. (One-element gathers from a 1-D array of 1e8 entries take
+    the TPU compiler minutes and run at half the row rate.)"""
+    off = jnp.clip(ptr[:, None] + pos.astype(ptr.dtype), 0, indices.shape[0] - 1)
+    shift = LANE.bit_length() - 1
+    rows = lax.shift_right_logical(off, jnp.asarray(shift, off.dtype))
+    lane = jnp.bitwise_and(off, LANE - 1).astype(jnp.int32)
+    return _select_lanes(indices.reshape(-1, LANE), rows.astype(jnp.int32), lane, k)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "max_deg"))

@@ -27,12 +27,14 @@ from .scaling import (
 from .train import (
     calibrate_cold_budget,
     make_mesh,
+    make_sharded_topo_sample,
     make_sharded_topo_train_step,
     make_sharded_train_step,
     mesh_axes,
     replicate,
     shard_feature_hot_cold,
     shard_feature_rows,
+    step_comm_bytes,
 )
 
 __all__ = [
@@ -47,6 +49,7 @@ __all__ = [
     "predict_layout",
     "products_scaling_table",
     "make_mesh",
+    "make_sharded_topo_sample",
     "make_sharded_topo_train_step",
     "make_sharded_train_step",
     "mesh_axes",
@@ -58,6 +61,7 @@ __all__ = [
     "sharded_gather_hot_cold",
     "shard_topology_rows",
     "sharded_gather",
+    "step_comm_bytes",
     "sharded_gather_a2a",
     "sharded_gather_grouped",
     "sharded_sample_layer",

@@ -238,3 +238,43 @@ def pad_to_multiple(arr, multiple: int, axis: int = 0):
     pad_width = [(0, 0)] * arr.ndim
     pad_width[axis] = (0, target - n)
     return np.pad(np.asarray(arr), pad_width)
+
+
+def place_shards(mesh, axes, shapes, make):
+    """Arrays of ``shapes``, each split in dim 0 over the mesh axes
+    ``axes`` (replicated over the others), built and uploaded SHARD BY
+    SHARD: ``make(p)`` returns the host blocks of shard ``p`` (one per
+    array, dim 0 a ``1/P`` share of the array's) and they go straight to
+    the devices that hold shard ``p``. No device ever holds more than its
+    own blocks and the host never holds a copy of the whole: one shard's
+    blocks at a time (on four v5e chips a 28 GB table went up in 30-33 s
+    from four threads and 30-32 s from this loop; four flat topology blocks
+    took 5-6 s from four threads and 8 s from this loop: 3 s of a 70 s
+    set-up did not pay for a pool,
+    PERF.md). Returns one `jax.Array` per shape.
+    """
+    import math
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    axes = (axes,) if isinstance(axes, str) else tuple(axes)
+    shapes = [tuple(int(d) for d in s) for s in shapes]
+    shardings = [
+        NamedSharding(mesh, P(axes, *([None] * (len(s) - 1)))) for s in shapes
+    ]
+    parts = math.prod(mesh.shape[a] for a in axes)
+    if any(s[0] % parts for s in shapes):
+        raise ValueError(f"dim 0 of {shapes} does not split {parts} ways")
+    share = shapes[0][0] // parts
+    holders = {}
+    index_map = shardings[0].addressable_devices_indices_map(shapes[0])
+    for dev, index in index_map.items():
+        holders.setdefault((index[0].start or 0) // max(share, 1), []).append(dev)
+    placed = [[] for _ in shapes]
+    for p in sorted(holders):
+        for i, blk in enumerate(make(p)):
+            placed[i] += [jax.device_put(blk, dev) for dev in holders[p]]
+    return tuple(
+        jax.make_array_from_single_device_arrays(s, sh, arrs)
+        for s, sh, arrs in zip(shapes, shardings, placed)
+    )

@@ -27,19 +27,24 @@ lane count matches the all_gather/psum formulation while adding sorts.
 Two shard LAYOUTS share all of the collective machinery above:
 
 - ``layout="flat"`` (`ShardedTopology`): each shard keeps its contiguous CSR
-  block as a local indptr + flat indices array and resolves drawn positions
-  with one-element gathers (`ops.sample.row_windows`);
+  block as a local indptr + flat indices array; a drawn position is read
+  from the indices seen as 128-lane rows (`ops.sample.flat_resolve`). Bytes
+  follow the EDGES: 4 B an edge + 4 B a node;
 - ``layout="tiled"`` (`TiledShardedTopology`): each shard's block is rebuilt
   into the 128-lane tile layout of `ops.sample.build_tiled_host` — a local
-  ``(base, degree)`` table plus a ``[M, 128]`` tile table — so position
-  resolution rides 2-D ROW gathers + one-hot lane selects, the fetch shape
-  behind the single-chip 2.58x fused-SEPS win (PERF.md (earlier claims)).
-  The collective payloads are IDENTICAL between layouts (same ``[W, k]``
-  neighbor/valid return, same frontier all_gather); only the local HBM
-  fetch shape changes — `sampling_comm_bytes(layout=...)` models both.
-  Tiled is the TPU-mode default (`resolve_topology_layout`), matching the
-  single-chip ``GraphSageSampler(layout="tiled")`` default; SCALING.md
-  carries the flat-vs-tiled comparison.
+  ``(base, degree)`` table plus a ``[M, 128]`` tile table in which every
+  node's list starts on a row of its own. Bytes follow the NODES: at least
+  512 B + 8 B a node, whatever its degree.
+  Both resolve positions with the same 2-D ROW gathers + one-hot lane
+  selects (`ops.sample._select_lanes`), the fetch shape behind the
+  single-chip 2.58x fused-SEPS win (PERF.md (earlier claims)); collective
+  payloads and draws are IDENTICAL between layouts (same ``[W, k]``
+  neighbor/valid return, same key -> same neighbours). `shard_topology_rows`
+  resolves ``layout=None`` from the graph (`resolve_topology_layout`):
+  tiled while the tile table costs at most 4 lanes per edge (ogbn-products:
+  2.9), flat past that (ogbn-papers100M: 8.8, 28 GB of tiles for 3.2 GB of
+  edges). `sampling_comm_bytes(layout=...)` still models the flat fetch as
+  the one-element gathers it was before (SCALING.md's comparison).
 """
 
 from __future__ import annotations
@@ -61,6 +66,7 @@ from ..ops.sample import (
     _tiled_resolve,
     build_tiled_host,
     fisher_yates_positions,
+    flat_resolve,
     pad_widths,
     row_windows,
 )
@@ -136,13 +142,48 @@ def tiled_topology_specs(feat_axes) -> "TiledShardedTopology":
     )
 
 
-def resolve_topology_layout(layout: Optional[str]) -> str:
-    """Default the sharded-topology layout per backend: ``None`` means
-    "tiled" on TPU (matching the single-chip `GraphSageSampler` TPU
-    default) and "flat" elsewhere (virtual CPU meshes keep the layout the
-    hermetic tests were seeded with unless they opt in explicitly)."""
+# A tile table costs 128 lanes per started tile row PER NODE; the flat block
+# costs one lane per edge. Up to this ratio of tile slots to edges the tile
+# layout is taken (ogbn-products, mean degree 50: 2.9), past it the flat one
+# (ogbn-papers100M, mean degree 14.6: 8.8, 28 GB of tiles for 3.2 GB of
+# edges). The threshold separates what fits from what does not, not fast
+# from slow: at the products shape on four v5e chips the step reads 55.56 ms
+# flat (128 MB a chip, placed in 0.6 s) and 55.44 ms tiled (373 MB, 10.1 s),
+# one run each, same losses (PERF.md section 6, PR 28). The tile layout of
+# `parallel/` buys nothing there; deleting it is queued (ROADMAP S9).
+TILE_SLOTS_PER_EDGE_MAX = 4.0
+
+
+def tile_slots_per_edge(indptr) -> float:
+    """Lanes the 128-lane tile layout would hold per edge of this graph
+    (`ops.sample.tiled_base_host`'s row count, without building anything)."""
+    deg = np.diff(np.asarray(indptr))
+    return _tile_rows(deg) * LANE / max(int(deg.sum()), 1)
+
+
+def _tile_rows(deg: np.ndarray) -> int:
+    """Rows of the tile table for these degrees: every list starts a row."""
+    return int((-(-deg // LANE)).sum())
+
+
+def resolve_topology_layout(layout: Optional[str], indptr=None) -> str:
+    """The shard layout to build: a given ``layout`` is checked and kept;
+    ``None`` is resolved from the GRAPH (``indptr``): "tiled" where the
+    tile table stays within `TILE_SLOTS_PER_EDGE_MAX` lanes per edge,
+    "flat" where it would not (low mean degree: the tile layout's cost is
+    per node, the flat layout's per edge). Both layouts fetch drawn
+    positions as 128-lane row gathers and draw the same neighbours from the
+    same key, on every backend."""
     if layout is None:
-        layout = "tiled" if jax.default_backend() == "tpu" else "flat"
+        if indptr is None:
+            raise ValueError(
+                "layout=None is resolved from the graph: pass its indptr "
+                "(shard_topology_rows does), or name a layout"
+            )
+        layout = (
+            "tiled" if tile_slots_per_edge(indptr) <= TILE_SLOTS_PER_EDGE_MAX
+            else "flat"
+        )
     if layout not in ("flat", "tiled"):
         raise ValueError(f"unsupported topology layout: {layout!r}")
     return layout
@@ -165,6 +206,68 @@ def partition_rows_by_edges(indptr: np.ndarray, n_shards: int) -> np.ndarray:
     return np.maximum.accumulate(row_start)  # enforce monotone under ties
 
 
+def _padded(size: int, multiple: int) -> int:
+    """``size`` rounded up to a multiple of ``multiple`` and of a power of
+    two between 1/32 and 1/16 of it. Block lengths follow the graph (where
+    the edge-balanced cuts fall), and a program is compiled per shape: with
+    a coarse granule another graph of the same size and degree profile
+    (another seed dealing the same degrees) lands on the SAME shapes and
+    compiles nothing (per-seed shapes cost the papers100M cell an 11 s
+    compile in seven runs of eight; PERF.md). At most 1/16 is padding."""
+    granule = int(np.lcm(multiple, 1 << max(int(size).bit_length() - 5, 0)))
+    return max(-(-size // granule) * granule, granule)
+
+
+def _flat_plan(indptr: np.ndarray, n_shards: int, pad_multiple: int):
+    """(row_start, r_max, e_pad) of the flat shard blocks."""
+    # whole (8, 128) device tiles: `flat_resolve` reads the edges as lane
+    # rows, and a block whose length is no multiple of 1024 is COPIED every
+    # step where the program drops its shard axis (3.7 ms for 808 MB)
+    pad_multiple = int(np.lcm(pad_multiple, 8 * LANE))
+    row_start = partition_rows_by_edges(indptr, n_shards)
+    r_max = max(int(np.max(np.diff(row_start))) if n_shards else 0, 1)
+    r_max = _padded(r_max + 1, 8 * LANE) - 1  # the indptr block: r_max + 1
+    e_pad = max(
+        (int(indptr[row_start[p + 1]] - indptr[row_start[p]])
+         for p in range(n_shards)),
+        default=0,
+    )
+    return row_start, r_max, _padded(e_pad, pad_multiple)
+
+
+def _flat_block(indptr, indices, row_start, p: int, r_max: int, e_pad: int,
+                id_dtype) -> Tuple[np.ndarray, np.ndarray]:
+    """Shard ``p``'s (local indptr [r_max+1], indices [e_pad]) alone."""
+    lo, hi = int(row_start[p]), int(row_start[p + 1])
+    ptr_dt = np.int32 if e_pad < 2**31 else np.int64
+    local = (indptr[lo : hi + 1] - indptr[lo]).astype(ptr_dt)
+    ptr = np.empty(r_max + 1, ptr_dt)
+    ptr[: hi - lo + 1] = local
+    # edge-pad: rows past this shard's range read as degree 0
+    ptr[hi - lo + 1 :] = local[-1] if local.size else 0
+    idx = np.zeros(e_pad, id_dtype)
+    blk = indices[int(indptr[lo]) : int(indptr[hi])]
+    idx[: blk.shape[0]] = blk
+    return ptr, idx
+
+
+def _row_start_dtype(row_start: np.ndarray):
+    return np.int32 if int(row_start[-1]) < 2**31 else np.int64
+
+
+def _stacked(plan, block, indptr, indices, n_shards: int):
+    """All shards' blocks of a plan, stacked on the host (the public
+    ``build_*_shards``; placement goes block by block instead)."""
+    row_start, *sizes = plan
+    blocks = [block(indptr, indices, row_start, p, *sizes, indices.dtype)
+              for p in range(n_shards)]
+    return (
+        np.stack([b[0] for b in blocks]),
+        np.stack([b[1] for b in blocks]),
+        row_start.astype(_row_start_dtype(row_start)),
+    )
+
+
 def build_topology_shards(
     indptr: np.ndarray,
     indices: np.ndarray,
@@ -172,29 +275,13 @@ def build_topology_shards(
     pad_multiple: int = 512,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Host-side shard construction: (indptr_blocks, indices_blocks,
-    row_start) as stacked numpy arrays (see `ShardedTopology`)."""
+    row_start) as stacked numpy arrays (see `ShardedTopology`). The stack is
+    a second copy of the graph: `shard_topology_rows` places block by block
+    and never builds it."""
     indptr = np.asarray(indptr)
     indices = np.asarray(indices)
-    row_start = partition_rows_by_edges(indptr, n_shards)
-    r_max = int(np.max(row_start[1:] - row_start[:-1])) if n_shards else 0
-    r_max = max(r_max, 1)
-    e_pad = 0
-    for p in range(n_shards):
-        e_pad = max(e_pad, int(indptr[row_start[p + 1]] - indptr[row_start[p]]))
-    e_pad = max(-(-e_pad // pad_multiple) * pad_multiple, pad_multiple)
-    ptr_dt = np.int32 if e_pad < 2**31 else np.int64
-    indptr_blocks = np.zeros((n_shards, r_max + 1), ptr_dt)
-    indices_blocks = np.zeros((n_shards, e_pad), indices.dtype)
-    for p in range(n_shards):
-        lo, hi = int(row_start[p]), int(row_start[p + 1])
-        local = (indptr[lo : hi + 1] - indptr[lo]).astype(ptr_dt)
-        indptr_blocks[p, : hi - lo + 1] = local
-        # edge-pad: rows past this shard's range read as degree 0
-        indptr_blocks[p, hi - lo + 1 :] = local[-1] if local.size else 0
-        blk = indices[int(indptr[lo]) : int(indptr[hi])]
-        indices_blocks[p, : blk.shape[0]] = blk
-    rs_dt = np.int32 if int(row_start[-1]) < 2**31 else np.int64
-    return indptr_blocks, indices_blocks, row_start.astype(rs_dt)
+    return _stacked(_flat_plan(indptr, n_shards, pad_multiple), _flat_block,
+                    indptr, indices, n_shards)
 
 
 def build_tiled_topology_shards(
@@ -214,27 +301,38 @@ def build_tiled_topology_shards(
     padded to the max (rounded up to ``pad_multiple`` tile rows) so the
     blocks stack into one ``[P, M_max, 128]`` device array; bd blocks are
     row-padded with degree-0 entries so out-of-range lookups draw nothing.
+    `shard_topology_rows` places block by block and never builds the stack.
     """
     indptr = np.asarray(indptr)
     indices = np.asarray(indices)
+    return _stacked(_tiled_plan(indptr, n_shards, pad_multiple), _tiled_block,
+                    indptr, indices, n_shards)
+
+
+def _tiled_plan(indptr: np.ndarray, n_shards: int, pad_multiple: int):
+    """(row_start, r_max, m_max) of the tiled shard blocks: tile-row counts
+    from the degrees alone, nothing is built."""
     row_start = partition_rows_by_edges(indptr, n_shards)
-    r_max = int(np.max(row_start[1:] - row_start[:-1])) if n_shards else 0
-    r_max = max(r_max, 1)
-    blocks = []
+    r_max = max(int(np.max(np.diff(row_start))) if n_shards else 0, 1)
+    m_max = 1
     for p in range(n_shards):
-        lo, hi = int(row_start[p]), int(row_start[p + 1])
-        local_ptr = (indptr[lo : hi + 1] - indptr[lo]).astype(np.int64)
-        local_idx = indices[int(indptr[lo]) : int(indptr[hi])]
-        blocks.append(build_tiled_host(local_ptr, local_idx, indices.dtype))
-    m_max = max(max(t.shape[0] for _, t in blocks), 1)
-    m_max = -(-m_max // pad_multiple) * pad_multiple
-    bd_blocks = np.zeros((n_shards, r_max, 2), np.int32)
-    tiles_blocks = np.zeros((n_shards, m_max, LANE), indices.dtype)
-    for p, (bd, tiles) in enumerate(blocks):
-        bd_blocks[p, : bd.shape[0]] = bd
-        tiles_blocks[p, : tiles.shape[0]] = tiles
-    rs_dt = np.int32 if int(row_start[-1]) < 2**31 else np.int64
-    return bd_blocks, tiles_blocks, row_start.astype(rs_dt)
+        deg = np.diff(indptr[int(row_start[p]) : int(row_start[p + 1]) + 1])
+        m_max = max(m_max, _tile_rows(deg))
+    return row_start, r_max, -(-m_max // pad_multiple) * pad_multiple
+
+
+def _tiled_block(indptr, indices, row_start, p: int, r_max: int, m_max: int,
+                 id_dtype) -> Tuple[np.ndarray, np.ndarray]:
+    """Shard ``p``'s (bd [r_max, 2], tiles [m_max, 128]) alone."""
+    lo, hi = int(row_start[p]), int(row_start[p + 1])
+    local_ptr = (indptr[lo : hi + 1] - indptr[lo]).astype(np.int64)
+    local_idx = indices[int(indptr[lo]) : int(indptr[hi])]
+    bd, tiles = build_tiled_host(local_ptr, local_idx, id_dtype)
+    bd_blk = np.zeros((r_max, 2), np.int32)
+    bd_blk[: bd.shape[0]] = bd
+    tiles_blk = np.zeros((m_max, LANE), id_dtype)
+    tiles_blk[: tiles.shape[0]] = tiles
+    return bd_blk, tiles_blk
 
 
 def shard_topology_rows(
@@ -248,44 +346,65 @@ def shard_topology_rows(
     Each device ends up holding ONLY its contiguous CSR block (~E/P edges;
     edge-balanced), so total graph capacity scales with chip count — the
     papers100M axis the reference serves with UVA (quiver_sample.cu:361-421).
+    Blocks are built and uploaded shard by shard (`collectives.place_shards`):
+    no device ever holds more than its block, and the host one block at a
+    time, never the stack.
 
     ``axes`` defaults to the mesh's feature axes ((host, ici) on a 3-axis
     mesh, else (ici,)); the blocks are replicated over the remaining axes.
 
     ``layout`` picks the per-shard block format: "flat" (`ShardedTopology`)
     or "tiled" (`TiledShardedTopology`, the 128-lane tile layout). ``None``
-    resolves per backend (`resolve_topology_layout`: tiled on TPU). Pair
-    with the same ``layout`` on `make_sharded_topo_train_step`.
+    resolves from the graph (`resolve_topology_layout`: tiled unless the
+    tile table would cost more than 4 lanes per edge). The train step takes
+    either (`make_sharded_topo_train_step(layout=None)`).
     """
+    from ..trace import trace_scope
+    from ..utils import _best_id_dtype
+    from .collectives import place_shards
     from .train import mesh_axes
 
-    layout = resolve_topology_layout(layout)
+    indptr = np.asarray(topo.indptr)
+    indices = np.asarray(topo.indices)
+    layout = resolve_topology_layout(layout, indptr)
     if axes is None:
         _, axes, _ = mesh_axes(mesh)
     axes = (axes,) if isinstance(axes, str) else tuple(axes)
     n_shards = 1
     for a in axes:
         n_shards *= mesh.shape[a]
-    rep = NamedSharding(mesh, P())
+    id_dtype = _best_id_dtype(indptr.shape[0])  # node ids, not edge ids
+    if id_dtype == np.int64 and not jax.config.jax_enable_x64:
+        raise ValueError(
+            "graph needs int64 node ids on device but jax x64 is disabled — "
+            "see CSRTopo.to_device"
+        )
     if layout == "tiled":
-        bd_b, tiles_b, row_start = build_tiled_topology_shards(
-            topo.indptr, topo.indices, n_shards
+        row_start, r_max, m_max = _tiled_plan(indptr, n_shards, 8)
+        shapes = ((n_shards, r_max, 2), (n_shards, m_max, LANE))
+        cls, block = TiledShardedTopology, _tiled_block
+        plan = (r_max, m_max)
+    else:
+        row_start, r_max, e_pad = _flat_plan(indptr, n_shards, 512)
+        shapes = ((n_shards, r_max + 1), (n_shards, e_pad))
+        cls, block = ShardedTopology, _flat_block
+        plan = (r_max, e_pad)
+    chip_bytes = sum(int(np.prod(s[1:])) for s in shapes) * 4
+    with trace_scope("quiver.shard.topology", tiled=int(layout == "tiled"),
+                     chip_bytes=chip_bytes) as span:
+        first, second = place_shards(
+            mesh, axes, shapes,
+            lambda p: tuple(
+                b[None] for b in block(indptr, indices, row_start, p, *plan,
+                                       id_dtype)
+            ),
         )
-        blk3 = NamedSharding(mesh, P(axes, None, None))
-        return TiledShardedTopology(
-            bd=jax.device_put(jnp.asarray(bd_b), blk3),
-            tiles=jax.device_put(jnp.asarray(tiles_b), blk3),
-            row_start=jax.device_put(jnp.asarray(row_start), rep),
+        rs = jax.device_put(
+            row_start.astype(_row_start_dtype(row_start)),
+            NamedSharding(mesh, P()),
         )
-    indptr_b, indices_b, row_start = build_topology_shards(
-        topo.indptr, topo.indices, n_shards
-    )
-    blk_sharding = NamedSharding(mesh, P(axes, None))
-    return ShardedTopology(
-        indptr=jax.device_put(jnp.asarray(indptr_b), blk_sharding),
-        indices=jax.device_put(jnp.asarray(indices_b), blk_sharding),
-        row_start=jax.device_put(jnp.asarray(row_start), rep),
-    )
+        span.sync = (first, second, rs)
+    return cls(first, second, rs)
 
 
 def _flat_axis_index(axes: Tuple[str, ...]):
@@ -374,15 +493,13 @@ def _sample_layer_partial(
     start = jnp.take(row_start, idx)
     end = jnp.take(row_start, idx + 1)
     r_max = indptr_blk.shape[0] - 1
-    e_pad = indices_blk.shape[0]
     local = (cur - start).astype(jnp.int32)
     mine = cur_valid & (cur >= start) & (cur < end)
     s = jnp.clip(local, 0, r_max - 1)
     ptr, deg = row_windows(indptr_blk, s)
     deg = jnp.where(mine, deg, 0)
     pos, valid = fisher_yates_positions(key, deg, k)
-    flat = jnp.clip(ptr[:, None] + pos.astype(ptr.dtype), 0, e_pad - 1)
-    nbrs = jnp.take(indices_blk, flat)
+    nbrs = flat_resolve(indices_blk, ptr, pos, k)
     nbrs = jnp.where(valid, nbrs, 0)
     return nbrs, valid.astype(jnp.int32)
 

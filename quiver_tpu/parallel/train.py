@@ -287,6 +287,73 @@ def make_sharded_train_step(
     return jax.jit(sharded)
 
 
+def _topo_sample_local(pipeline, sizes, caps, has_host, hot_cold, feat_axes,
+                       hot_rows, cold_budget, key, stopo, feat_block, seeds):
+    """What a sharded-topology step samples and gathers, inside shard_map:
+    ``(ds, x, dropout_key, overflow_acc)``. The train step and
+    `make_sharded_topo_sample` both run exactly this, so the same key and
+    seeds give the same draws and rows in either program. The shard layout
+    is the type of ``stopo``."""
+    from .topology import (
+        TiledShardedTopology,
+        sharded_sample_layer,
+        sharded_sample_layer_grouped,
+        tiled_sharded_sample_layer,
+        tiled_sharded_sample_layer_grouped,
+    )
+
+    overflow_acc = []
+    gather_rows = _make_gather_rows(
+        has_host, hot_cold, feat_axes, hot_rows, cold_budget, overflow_acc
+    )
+    row_start = stopo.row_start     # [P+1] replicated boundaries
+    # this shard's blocks, the leading shard axis of length 1 dropped by a
+    # reshape: ``block[0]`` compiles to a COPY of the block every step
+    # (3.7 ms for the 808 MB edge block of the papers100M cell; PERF.md)
+    if isinstance(stopo, TiledShardedTopology):
+        # [R_max, 2] (base, deg) and the [M_max, 128] tile table
+        blocks = (stopo.bd, stopo.tiles)
+        plain, grouped = tiled_sharded_sample_layer, tiled_sharded_sample_layer_grouped
+    else:
+        # [R_max+1] shard-local indptr and the [E_pad] edge block
+        blocks = (stopo.indptr, stopo.indices)
+        plain, grouped = sharded_sample_layer, sharded_sample_layer_grouped
+    blocks = tuple(b.reshape(b.shape[1:]) for b in blocks)
+
+    def sample_fn(cur, cur_valid, k, sub):
+        if not has_host:
+            return plain(*blocks, row_start, cur, cur_valid, k, sub, feat_axes)
+        return grouped(*blocks, row_start, cur, cur_valid, k, sub, feat_axes, "host")
+
+    key, dropout_key = jax.random.split(_fold_group_key(key, has_host))
+    if pipeline == "fused":
+        ds, x = sample_and_gather_fused(
+            None, None, feat_block, key, seeds, tuple(sizes),
+            gather_fn=gather_rows, sample_fn=sample_fn,
+        )
+    else:
+        ds, x = sample_and_gather_dedup(
+            None, None, feat_block, key, seeds, tuple(sizes), caps,
+            gather_fn=gather_rows, sample_fn=sample_fn,
+        )
+    return ds, x, dropout_key, overflow_acc
+
+
+def _check_stopo_layout(layout: Optional[str], stopo) -> None:
+    """A named ``layout`` has to be the one ``stopo`` was built in (checked
+    where the program is traced; ``None`` takes either)."""
+    from .topology import TiledShardedTopology, resolve_topology_layout
+
+    if layout is None:
+        return
+    built = "tiled" if isinstance(stopo, TiledShardedTopology) else "flat"
+    if resolve_topology_layout(layout) != built:
+        raise ValueError(
+            f"layout={layout!r} but stopo is a {type(stopo).__name__}: build it "
+            f"with shard_topology_rows(layout={layout!r})"
+        )
+
+
 def make_sharded_topo_train_step(
     mesh: Mesh,
     model,
@@ -313,126 +380,169 @@ def make_sharded_topo_train_step(
     first all_gathered over it (hosts sample different seeds), mirroring the
     grouped feature gather.
 
-    ``layout`` selects the shard block format ``stopo`` must carry —
-    "flat" (`ShardedTopology`) or "tiled" (`TiledShardedTopology`, the
-    128-lane tile layout whose row-gather fetch shape won the single-chip
-    2.58x fused-SEPS round). ``None`` resolves per backend
-    (`topology.resolve_topology_layout`: tiled on TPU, matching the
-    single-chip `GraphSageSampler` default). Build ``stopo`` with the SAME
-    ``layout`` on `shard_topology_rows`; collective payloads and sampling
-    draws are identical between layouts (same key -> same neighbors).
+    The shard block format is the TYPE of ``stopo`` — `ShardedTopology`
+    ("flat", bytes follow the edges) or `TiledShardedTopology` ("tiled", the
+    128-lane tile layout, bytes follow the nodes) — as `shard_topology_rows`
+    built it (it resolves ``layout=None`` from the graph); the step is traced
+    once per type it is called with. ``layout``, if named, is held against
+    that type. Both layouts fetch positions as 128-lane row gathers;
+    collective payloads and sampling draws are identical (same key -> same
+    neighbors).
 
     ``hot_rows``/``cold_budget`` compose the replicated-hot feature tier
     with the sharded topology (multi-host meshes; same contract as
     `make_sharded_train_step`): pass ``(hot_block, cold_block)`` from
     `shard_feature_hot_cold` as ``feat_block``.
 
-    Per-step collective traffic for this layout is statically modeled by
-    `topology.sampling_comm_bytes` — log it next to any multichip artifact.
+    The compiled program is named ``sharded_topo_train_step``
+    (`trace.STEP_PROGRAM_NAMES`). Per-step collective traffic is statically
+    modeled by `topology.sampling_comm_bytes` (`step_comm_bytes`: one number
+    a step). `make_sharded_topo_sample` returns what a step sampled and
+    gathered.
     """
-    from .topology import (
-        resolve_topology_layout,
-        sharded_sample_layer,
-        sharded_sample_layer_grouped,
-        tiled_sharded_sample_layer,
-        tiled_sharded_sample_layer_grouped,
-    )
-
-    layout = resolve_topology_layout(layout)
     has_host, data_axes, feat_axes, hot_cold = _validate_step_config(
         mesh, pipeline, caps, hot_rows, cold_budget
     )
+    feat_spec, out_specs = _step_specs(hot_cold, feat_axes)
 
     def step_local(params, opt_state, key, stopo, feat_block, labels, seeds):
-        overflow_acc = []
-        gather_rows = _make_gather_rows(
-            has_host, hot_cold, feat_axes, hot_rows, cold_budget, overflow_acc
+        ds, x, dropout_key, overflow_acc = _topo_sample_local(
+            pipeline, sizes, caps, has_host, hot_cold, feat_axes,
+            hot_rows, cold_budget, key, stopo, feat_block, seeds,
         )
-
-        row_start = stopo.row_start     # [P+1] replicated boundaries
-        if layout == "tiled":
-            bd_blk = stopo.bd[0]        # [R_max, 2] this shard's (base, deg)
-            tiles_blk = stopo.tiles[0]  # [M_max, 128] this shard's tile table
-
-            def sample_fn(cur, cur_valid, k, sub):
-                if not has_host:
-                    return tiled_sharded_sample_layer(
-                        bd_blk, tiles_blk, row_start, cur, cur_valid, k,
-                        sub, feat_axes,
-                    )
-                return tiled_sharded_sample_layer_grouped(
-                    bd_blk, tiles_blk, row_start, cur, cur_valid, k, sub,
-                    feat_axes, "host",
-                )
-        else:
-            indptr_blk = stopo.indptr[0]    # [R_max+1] shard-local indptr
-            indices_blk = stopo.indices[0]  # [E_pad] this shard's edge block
-
-            def sample_fn(cur, cur_valid, k, sub):
-                if not has_host:
-                    return sharded_sample_layer(
-                        indptr_blk, indices_blk, row_start, cur, cur_valid, k,
-                        sub, feat_axes,
-                    )
-                return sharded_sample_layer_grouped(
-                    indptr_blk, indices_blk, row_start, cur, cur_valid, k, sub,
-                    feat_axes, "host",
-                )
-
-        key, dropout_key = jax.random.split(_fold_group_key(key, has_host))
-        if pipeline == "fused":
-            ds, x = sample_and_gather_fused(
-                None, None, feat_block, key, seeds, tuple(sizes),
-                gather_fn=gather_rows, sample_fn=sample_fn,
-            )
-        else:
-            ds, x = sample_and_gather_dedup(
-                None, None, feat_block, key, seeds, tuple(sizes), caps,
-                gather_fn=gather_rows, sample_fn=sample_fn,
-            )
         return _loss_and_update(
             model, tx, train, data_axes, hot_cold, overflow_acc,
             params, opt_state, dropout_key, ds, x, labels, seeds,
         )
 
-    from .topology import tiled_topology_specs, topology_specs
+    def sharded_topo_train_step(params, opt_state, key, stopo, feat_block,
+                                labels, seeds):
+        _check_stopo_layout(layout, stopo)
+        return _shard_map_fn(
+            step_local,
+            mesh=mesh,
+            in_specs=(
+                P(),            # params (replicated)
+                P(),            # opt_state
+                P(),            # rng key
+                stopo.specs(feat_axes),  # CSR blocks + boundaries
+                feat_spec,      # feature rows (see docstring)
+                P(),            # labels
+                P(data_axes),   # seeds sharded over (host?,) dp
+            ),
+            out_specs=out_specs,
+            check_vma=False,
+        )(params, opt_state, key, stopo, feat_block, labels, seeds)
 
-    topo_specs = (
-        tiled_topology_specs(feat_axes) if layout == "tiled"
-        else topology_specs(feat_axes)
+    return jax.jit(sharded_topo_train_step)
+
+
+def make_sharded_topo_sample(
+    mesh: Mesh,
+    sizes: Sequence[int],
+    caps: Optional[Sequence[Optional[int]]] = None,
+    pipeline: str = "dedup",
+    hot_rows: Optional[int] = None,
+    cold_budget=None,
+    layout: Optional[str] = None,
+):
+    """What `make_sharded_topo_train_step` samples, as a program of its own:
+    ``sample(key, stopo, feat_block, seeds) -> (ds, x)``, the step's own
+    code up to its loss (`_topo_sample_local`), so the same ``key`` and
+    ``seeds`` give the `DenseSample` and the gathered rows ``x`` that the
+    step trained on — for evaluation, for debugging a loss, for holding a
+    step against the host graph. Every array of ``ds`` and ``x`` gains a
+    leading axis of one entry per data-parallel group (groups fold their
+    index into the key and sample their own share of ``seeds``)."""
+    has_host, data_axes, feat_axes, hot_cold = _validate_step_config(
+        mesh, pipeline, caps, hot_rows, cold_budget
     )
-    feat_spec, out_specs = _step_specs(hot_cold, feat_axes)
-    sharded = _shard_map_fn(
-        step_local,
-        mesh=mesh,
-        in_specs=(
-            P(),            # params (replicated)
-            P(),            # opt_state
-            P(),            # rng key
-            topo_specs,     # row-sharded CSR blocks + replicated boundaries
-            feat_spec,      # feature rows (see docstring)
-            P(),            # labels
-            P(data_axes),   # seeds sharded over (host?,) dp
-        ),
-        out_specs=out_specs,
-        check_vma=False,
-    )
-    return jax.jit(sharded)
+    n_groups = mesh_axes(mesh)[2]
+    feat_spec, _ = _step_specs(hot_cold, feat_axes)
+
+    def sample_local(key, stopo, feat_block, seeds):
+        ds, x, _, _ = _topo_sample_local(
+            pipeline, sizes, caps, has_host, hot_cold, feat_axes,
+            hot_rows, cold_budget, key, stopo, feat_block, seeds,
+        )
+        # the static batch size is no array: put back outside the program
+        return jax.tree_util.tree_map(
+            lambda a: a[None], (ds._replace(batch_size=None), x))
+
+    @jax.jit
+    def sharded_topo_sample(key, stopo, feat_block, seeds):
+        _check_stopo_layout(layout, stopo)
+        return _shard_map_fn(
+            sample_local,
+            mesh=mesh,
+            in_specs=(P(), stopo.specs(feat_axes), feat_spec, P(data_axes)),
+            out_specs=P(data_axes),
+            check_vma=False,
+        )(key, stopo, feat_block, seeds)
+
+    def sample(key, stopo, feat_block, seeds):
+        ds, x = sharded_topo_sample(key, stopo, feat_block, seeds)
+        return ds._replace(batch_size=seeds.shape[0] // n_groups), x
+
+    return sample
+
+
+def step_comm_bytes(mesh: Mesh, sizes: Sequence[int], batch_per_group: int,
+                    feature_dim: int, caps=None, **model) -> float:
+    """The modelled collective bytes a chip moves in ONE sharded-topology
+    step (`topology.sampling_comm_bytes` with the per-hop feature gathers:
+    neighbour and mask all-reduces plus the row all-reduces, ring costs,
+    float32 rows). A static number of the step's shapes: compute it once,
+    where the step is built, and count it per dispatched step with
+    ``trace.observe("quiver.step.comm_bytes", total)`` (recorded only while
+    tracing is on)."""
+    from .topology import sampling_comm_bytes
+
+    return sampling_comm_bytes(
+        mesh, sizes, batch_per_group, feature_dim=feature_dim, caps=caps, **model
+    )["total_bytes"]
+
+
+def _place_rows(mesh: Mesh, axes, table) -> jax.Array:
+    """``table`` [N, D] row-striped over ``axes`` (replicated over the other
+    mesh axes), zero-padded to a multiple of the shard count, uploaded
+    shard by shard from slices of the host table (`place_shards`): no device
+    holds more than its rows and the host makes no padded copy of the
+    whole. ``table`` needs only ``shape``, ``dtype`` and row slicing (a
+    numpy array, a memmap)."""
+    import math
+
+    import numpy as np
+
+    from ..trace import trace_scope
+    from .collectives import place_shards
+
+    shards = math.prod(mesh.shape[a] for a in axes)
+    n, dim = table.shape
+    rows = -(-n // shards)
+
+    def block(p):
+        part = np.asarray(table[p * rows : min((p + 1) * rows, n)])
+        if part.shape[0] < rows:  # the tail shard alone is padded
+            part = np.concatenate(
+                [part, np.zeros((rows - part.shape[0], dim), part.dtype)])
+        return (part,)
+
+    chip_bytes = rows * dim * np.dtype(table.dtype).itemsize
+    with trace_scope("quiver.shard.features", chip_bytes=chip_bytes, shards=shards) as span:
+        (placed,) = place_shards(mesh, axes, [(rows * shards, dim)], block)
+        span.sync = placed
+    return placed
 
 
 def shard_feature_rows(mesh: Mesh, table) -> jax.Array:
     """Place a [N, D] host table row-striped over the feature axes — ici,
     plus host when the mesh has the DCN axis (replicated over dp); pads N
-    to a multiple of the shard count."""
-    from .collectives import pad_to_multiple
-
+    to a multiple of the shard count. Uploaded shard by shard
+    (`_place_rows`): a table larger than one chip is placed as long as a
+    shard fits."""
     _, feat_axes, _ = mesh_axes(mesh)
-    shards = 1
-    for a in feat_axes:
-        shards *= mesh.shape[a]
-    padded = pad_to_multiple(table, shards)
-    sharding = NamedSharding(mesh, P(feat_axes, None))
-    return jax.device_put(jnp.asarray(padded), sharding)
+    return _place_rows(mesh, feat_axes, table)
 
 
 def shard_feature_hot_cold(
@@ -446,29 +556,14 @@ def shard_feature_hot_cold(
     (``Feature`` degree order / `utils.reindex_by_config`) — the analog of
     the reference's replicate-hottest preprocessing
     (mag240m preprocess.py:117-179)."""
-    import numpy as np
-
-    from .collectives import pad_to_multiple
-
     _, feat_axes, _ = mesh_axes(mesh)
     ici_axes = tuple(a for a in feat_axes if a != "host")
     if ici_axes == feat_axes:
         raise ValueError("hot/cold placement needs a multi-host mesh")
-    ici = 1
-    for a in ici_axes:
-        ici *= mesh.shape[a]
-    shards = ici
-    for a in feat_axes:
-        if a == "host":
-            shards *= mesh.shape[a]
-    table = np.asarray(table)
     if not 0 < hot_rows < table.shape[0]:
         raise ValueError(f"hot_rows {hot_rows} out of range for {table.shape}")
-    hot = pad_to_multiple(table[:hot_rows], ici)
-    cold = pad_to_multiple(table[hot_rows:], shards)
-    hot_dev = jax.device_put(jnp.asarray(hot), NamedSharding(mesh, P(ici_axes, None)))
-    cold_dev = jax.device_put(jnp.asarray(cold), NamedSharding(mesh, P(feat_axes, None)))
-    return hot_dev, cold_dev
+    return (_place_rows(mesh, ici_axes, table[:hot_rows]),
+            _place_rows(mesh, feat_axes, table[hot_rows:]))
 
 
 def calibrate_cold_budget(
@@ -506,6 +601,6 @@ def calibrate_cold_budget(
 
 
 def replicate(mesh: Mesh, x):
-    """Place an array or pytree fully replicated on the mesh."""
-    x = jax.tree_util.tree_map(jnp.asarray, x)
+    """Place an array or pytree fully replicated on the mesh (a host array
+    goes to each device directly, not through the default device)."""
     return jax.device_put(x, NamedSharding(mesh, P()))

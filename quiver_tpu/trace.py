@@ -43,6 +43,15 @@ PROGRAM_NAMES = (
     "serve_step",               # inference.make_serve_step
 )
 
+# Whole-step programs of `parallel/`: sampling, the row exchange, the model
+# and the optimizer in ONE program a step. Named so that the benchmark's
+# patterns for "the step" ("train_step") match them and its patterns for
+# the sampler's and the gather's own programs do not. Held against the
+# jitted callable by tests/qbench/test_qbench_sharded_manifest.py.
+STEP_PROGRAM_NAMES = (
+    "sharded_topo_train_step",  # parallel/train.make_sharded_topo_train_step
+)
+
 # name -> [count, total seconds, longest seconds]
 _registry: Dict[str, list] = {}
 # aggregation is a read-modify-write on _registry[name]; serve pollers and

@@ -13,6 +13,10 @@ main path, at the shapes `chip_smoke.py` runs, are lowered for a described
       devices (the layout is passed: `default_backend()` says cpu here)
   (e) the two programs of `Feature.lookup_padded`, at the shapes of the
       benchmark's train cells: the row gather and nothing beside it
+  (f) `make_sharded_topo_train_step` over the FLAT layout at the shard sizes
+      of the benchmark's four-chip cell (half of ogbn-papers100M: 7.1 GB of
+      feature rows and 0.86 GB of graph a chip): eleven seconds, where
+      one-element gathers from a 1-D edge array of that size took minutes
 
 A compile that passes is not a chip run. To stay inside the suite's time
 limit the tests compile (b) at batch 64 and (c) at bucket 8 (the graph and
@@ -35,14 +39,15 @@ import optax
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
 from chip_smoke import PRODUCTS, SIZES, make_model, make_train_step, ring_sampler
 from quiver_tpu.feature import _padded_gather, _padded_gather_ordered
 from quiver_tpu.inference import make_serve_step
 from quiver_tpu.ops.sample import LANE, tiled_sample_layer
 from quiver_tpu.parallel import make_sharded_topo_train_step, make_sharded_train_step
-from quiver_tpu.parallel.topology import TiledShardedTopology
+from quiver_tpu.parallel.topology import ShardedTopology, TiledShardedTopology
 from quiver_tpu.pyg.sage_sampler import sample_dense_fused, sample_dense_pure
 
 HBM_BYTES = 16e9                      # one v5e chip
@@ -189,6 +194,67 @@ def compile_sharded_topo_step(v5e, n_devices=4, batch=PRODUCTS["batch"]):
     return _fits(compiled, f"tiled sharded-topology step on {n_devices} device(s)")
 
 
+# papers100M-sage.train-sharded4 (qbench/configs/papers100M-sage.json): nodes,
+# edges, lanes, classes; the per-shard block sizes `shard_topology_rows` gives every seed
+PAPERS = dict(nodes=55_529_978, edges=807_842_936, dim=128, classes=172,
+              shard_rows=14_155_775, shard_edges=209_715_200)
+
+
+def compile_flat_sharded_topo_step_at_papers_size(v5e, batch=1024):
+    """What the four-chip cell runs, for four described chips: the layout is
+    the one `shard_topology_rows` resolves for that graph, handed over as
+    shapes (``layout=None`` takes whichever the `stopo` is)."""
+    from quiver_tpu.models import GraphSAGE
+
+    model = GraphSAGE(hidden_dim=256, out_dim=PAPERS["classes"], num_layers=3, dropout=0.0)
+    tx = optax.adam(1e-3)
+    ds = ring_sampler(dedup=False).sample_dense(np.arange(1))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    params = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((ds.n_id.shape[0], PAPERS["dim"])), ds.adjs), key)
+    mesh = Mesh(np.array(v5e.devices[:4]).reshape(1, 4), ("dp", "ici"))
+    rep = NamedSharding(mesh, P())
+    blocks = NamedSharding(mesh, P("ici", None))
+    stopo = ShardedTopology(
+        indptr=jax.ShapeDtypeStruct((4, PAPERS["shard_rows"] + 1), jnp.int32, sharding=blocks),
+        indices=jax.ShapeDtypeStruct((4, PAPERS["shard_edges"]), jnp.int32, sharding=blocks),
+        row_start=jax.ShapeDtypeStruct((5,), jnp.int32, sharding=rep))
+    step = make_sharded_topo_train_step(mesh, model, tx, SIZES, pipeline="fused")
+    compiled = step.lower(
+        *_struct((params, jax.eval_shape(tx.init, params), key), rep), stopo,
+        jax.ShapeDtypeStruct((-(-PAPERS["nodes"] // 4) * 4, PAPERS["dim"]), jnp.float32,
+                             sharding=blocks),
+        jax.ShapeDtypeStruct((PAPERS["nodes"],), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=NamedSharding(mesh, P("dp"))),
+    ).compile()
+    text = compiled.as_text()
+    assert "HloModule jit_sharded_topo_train_step" in text
+    assert " all-reduce(" in text, "the sharded step compiled without a collective"
+    # the edge block is read as 128-lane rows where it lies: no copy of it
+    lane_rows = f"s32[{PAPERS['shard_edges'] // LANE},{LANE}]"
+    assert re.search(rf"= {re.escape(lane_rows)}\S* bitcast\(", text), "no bitcast to lane rows"
+    assert not re.search(rf"= {re.escape(lane_rows)}\S* copy\(", text)
+    # nor of either block where the program drops its shard axis of length 1
+    # (blocks are padded to whole (8, 128) tiles for this: `_flat_plan`)
+    assert not re.search(r" reduce\(%param", text[text.index("ENTRY"):])
+    return (_fits(compiled, "flat sharded-topology step at the papers100M cell's size"),
+            _entry_operations(compiled))
+
+
+def _entry_operations(compiled):
+    """The entry computation's instructions as a device trace names them:
+    one line each, operands with their shapes."""
+    from jax._src.lib import xla_client
+
+    options = xla_client._xla.HloPrintOptions()
+    options.print_operand_shape = True
+    options.print_metadata = options.print_backend_config = False
+    (module,) = compiled.runtime_executable().hlo_modules()
+    text = module.to_string(options)
+    return [line.strip().removeprefix("ROOT ")
+            for line in text[text.index("ENTRY"):].splitlines() if " = " in line]
+
+
 def compile_sharded_feature_step(v5e, n_devices, batch=PRODUCTS["batch"]):
     """`make_sharded_train_step` (graph replicated, feature rows striped) on a
     (dp=1, ici=n_devices) mesh of described devices. Script only: the suite
@@ -229,6 +295,33 @@ def test_serve_bucket_compiles_for_v5e(v5e):
 
 def test_tiled_sharded_topo_step_compiles_for_four_v5e(v5e):
     compile_sharded_topo_step(v5e)
+
+
+def test_flat_sharded_topo_step_compiles_for_four_v5e_at_papers_size(v5e):
+    fit, operations = compile_flat_sharded_topo_step_at_papers_size(v5e)
+    assert 7.5 < fit["arguments_gb"] < 9.0  # ~48% of a chip, as the cell states
+    # the benchmark's per-layer patterns for this one-program step, against
+    # the program: which operations each metric of the cell would add up
+    import json
+
+    def matched(metric):
+        path = os.path.join(REPO, "qbench", "metrics", f"{metric}.json")
+        with open(path) as f:
+            params = json.load(f)["params"]
+        return [op for op in operations
+                if any(re.search(p, op) for p in params["include"])
+                and not any(re.search(p, op) for p in params.get("exclude", ()))]
+
+    assert len(matched("collective_ms.train")) == 5  # 3 neighbour/mask pairs, 2 of rows
+    gathers = matched("shard_gather_ms.train")
+    # seeds, hop 1, hop 2 and the leaves: a row gather and an owner-mask select each
+    assert len(gathers) == 8 and all(re.match(r"%\S+ = f32\[\d+,128\]", op) for op in gathers)
+    sampling = matched("shard_sample_ms.train")
+    lane_rows = [op for op in sampling
+                 if re.search(r"= s32\[\d+,128\]\S* fusion\(s32\[\d+,128\]", op)]
+    assert len(lane_rows) == sum(SIZES)  # one lane-row gather a drawn position
+    assert not any(re.match(r"%\S+ = \(?(f32|bf16)\[", op) for op in sampling)
+    assert not set(sampling) & set(gathers)
 
 
 def compile_feature_gather(v5e, program, rows, dim, positions):
@@ -286,6 +379,8 @@ if __name__ == "__main__":
          lambda: compile_sharded_topo_step(desc, 1)),
         ("sharded-feature step, 4 devices",
          lambda: compile_sharded_feature_step(desc, 4)),
+        ("flat sharded-topology step at the papers100M cell's size, 4 devices",
+         lambda: compile_flat_sharded_topo_step_at_papers_size(desc)[0]),
     ):
         t0 = time.time()
         print(name, fn(), f"compiled in {time.time() - t0:.1f}s", flush=True)
