@@ -33,6 +33,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .ops import gather_sum
 from .shard_tensor import (
     CPU_DEVICE,
     ShardTensor,
@@ -275,6 +276,10 @@ class Feature:
                   if self.cache_policy == "p2p_clique_replicate" else [self.rank])
         hot_total = min(cache_rows * len(clique), self._n)
         wholly_hot = hot_total >= self._n and self.disk_path is None
+        if wholly_hot:
+            # rows `lookup_padded` will hand a model: if they are the
+            # aggregation kernel's, its imports start beside the upload
+            gather_sum.prefetch_pallas(self.dtype, self._dim)
 
         if (self.csr_topo is not None and not self._local_order_applied
                 and not wholly_hot):

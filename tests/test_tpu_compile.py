@@ -17,6 +17,9 @@ main path, at the shapes `chip_smoke.py` runs, are lowered for a described
       of the benchmark's four-chip cell (half of ogbn-papers100M: 7.1 GB of
       feature rows and 0.86 GB of graph a chip): eleven seconds, where
       one-element gathers from a 1-D edge array of that size took minutes
+  (g) the jitted optax step over `GraphSAGE` at the shapes of the benchmark's
+      igb cell (explicit ``cols``): the first layer's k-fold gather is in no
+      buffer, and the program's temporaries stay under 2 GiB
 
 A compile that passes is not a chip run. To stay inside the suite's time
 limit the tests compile (b) at batch 64 and (c) at bucket 8 (the graph and
@@ -279,6 +282,66 @@ def compile_sharded_feature_step(v5e, n_devices, batch=PRODUCTS["batch"]):
     return _fits(compiled, f"sharded feature step on {n_devices} device(s)")
 
 
+# igb-small-sage.train-dedup (qbench/configs/igb-small-sage.json and the cell's
+# caps): batch, fan-out innermost last, row lanes, hidden, classes
+IGB = dict(batch=10240, fanout=(10, 15), dim=1024, hidden=128, classes=19)
+IGB_CAPS = (73728, 417792)           # `calibrate_caps(margin=1.1)`, as the cell fixes them
+IGB_CAPS_DEFAULT = (81920, 458752)   # the same probes at its default margin 1.2
+
+
+def compile_igb_model_step(v5e, caps):
+    """The step of `examples/reddit_sage.py` over explicit-``cols`` hops at the
+    igb cell's widths: (entry operations as `(shape, opcode)`, temporaries)."""
+    from quiver_tpu.models import GraphSAGE
+    from quiver_tpu.pyg.sage_sampler import DenseAdj
+
+    model = GraphSAGE(hidden_dim=IGB["hidden"], out_dim=IGB["classes"],
+                      num_layers=len(IGB["fanout"]), dropout=0.0)
+    tx = optax.adam(0.01)
+    widths = (IGB["batch"],) + tuple(caps)
+    scalar = _sds((), jnp.int32)
+    # outermost hop first: hop i aggregates widths[i + 1] source rows into widths[i]
+    adjs = tuple(
+        DenseAdj(cols=_sds((widths[i], k), jnp.int32), mask=_sds((widths[i], k), jnp.bool_),
+                 n_src=scalar, n_dst=scalar)
+        for i, k in reversed(list(enumerate(IGB["fanout"]))))
+    x = _sds((widths[-1], IGB["dim"]), jnp.float32)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    params = jax.eval_shape(model.init, key, x, adjs)
+    args = (params, jax.eval_shape(tx.init, params), key, x, adjs,
+            _sds((IGB["batch"],), jnp.int32))
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    compiled = make_train_step(model, tx).lower(*_struct(args, one_chip)).compile()
+    _fits(compiled, f"igb model step at caps {caps}")
+    text = compiled.as_text()
+    ops = re.findall(r"^\s+(?:ROOT )?%\S+ = (\S+?)\{\S* ([\w-]+)\((?:.*custom_call_target=\"(\w+)\")?",
+                     text[text.index("ENTRY"):], re.M)
+    # a Pallas kernel is a custom-call to "tpu_custom_call": name it so
+    ops = [(shape, target or op) for shape, op, target in ops]
+    return ops, compiled.memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize("caps,temp_gib", [(IGB_CAPS, 2.0), (IGB_CAPS_DEFAULT, 2.25)],
+                         ids=["margin1.1", "margin1.2"])
+def test_igb_model_step_holds_no_k_fold_gather_on_v5e(v5e, caps, temp_gib):
+    """ROADMAP S2 / R-M6: the first layer's `[W_dst * k, D]` gather (4.5 GB at
+    the cell's caps) is in no buffer of the step, in either layout; the rows
+    go through `gather_masked_sum`'s kernel; the program loads at
+    `calibrate_caps`' default margin too."""
+    ops, temp_bytes = compile_igb_model_step(v5e, caps)
+    w_dst, k = caps[0], IGB["fanout"][-1]
+    shapes = {shape for shape, _ in ops}
+    assert f"f32[{w_dst * k},{IGB['dim']}]" not in shapes, shapes
+    assert f"f32[{w_dst},{k},{IGB['dim']}]" not in shapes
+    assert f"f32[{k},{w_dst},{IGB['dim']}]" not in shapes
+    # one kernel call, for the 4 KB rows of layer 1; layer 2's 512 B rows stay XLA's
+    assert [shape for shape, op in ops if op == "tpu_custom_call"] == [
+        f"f32[{w_dst},1,{IGB['dim']}]"], ops
+    # the relaid x (1.6 GiB at the cell's caps) and the kernel's output, no more:
+    # 1.88 and 2.06 GiB, where the `take -> sum` form needed 8.72 GiB in one block
+    assert temp_bytes < temp_gib * 2**30, temp_bytes / 2**30
+
+
 def test_fused_train_step_compiles_for_v5e(v5e):
     compile_train_step(v5e, None, PRODUCTS["batch"])
 
@@ -381,6 +444,10 @@ if __name__ == "__main__":
          lambda: compile_sharded_feature_step(desc, 4)),
         ("flat sharded-topology step at the papers100M cell's size, 4 devices",
          lambda: compile_flat_sharded_topo_step_at_papers_size(desc)[0]),
+        ("igb model step, caps at margin 1.1: temporaries GiB",
+         lambda: compile_igb_model_step(desc, IGB_CAPS)[1] / 2**30),
+        ("igb model step, caps at margin 1.2: temporaries GiB",
+         lambda: compile_igb_model_step(desc, IGB_CAPS_DEFAULT)[1] / 2**30),
     ):
         t0 = time.time()
         print(name, fn(), f"compiled in {time.time() - t0:.1f}s", flush=True)
