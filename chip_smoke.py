@@ -464,8 +464,7 @@ def serve_phase(cfg: dict, data: Data, topo, model, params, seed: int,
 def four_chip_phase(cfg: dict, data: Data, seed: int, watch: CompileWatch) -> None:
     """The sharded train steps on a (dp=1, ici=4) mesh — feature rows striped
     over four chips with the graph replicated, then the graph row-sharded
-    too (``layout=None``, so the library resolves it from the graph) — against
-    the one-device step on the same key and seeds. dp=1 because data-parallel
+    too — against the one-device step on the same key and seeds. dp=1 because data-parallel
     groups fold their index into the sampling key: only one group has a
     one-device twin."""
     import jax
@@ -482,31 +481,23 @@ def four_chip_phase(cfg: dict, data: Data, seed: int, watch: CompileWatch) -> No
         shard_feature_rows,
         shard_topology_rows,
     )
-    from quiver_tpu.parallel.topology import (
-        ShardedTopology,
-        TiledShardedTopology,
-        resolve_topology_layout,
-        tile_slots_per_edge,
-    )
+    from quiver_tpu.parallel.topology import ShardedTopology
     from quiver_tpu.serve import resolve_exchange_mode
 
     platform = jax.devices()[0].platform
-    layout = resolve_topology_layout(None, data.indptr)
     exchange = resolve_exchange_mode("auto", hosts=4)
-    emit(phase="choices", topology_layout=layout,
-         tile_slots_per_edge=round(tile_slots_per_edge(data.indptr), 2),
-         dist_exchange_hosts4=exchange)
     check(exchange == "collective", f"auto exchange is {exchange!r} on 4 devices")
 
     topo = CSRTopo(indptr=data.indptr, indices=data.indices)
     mesh = make_mesh(4, dp=1)
     t0 = time.perf_counter()
     feat = shard_feature_rows(mesh, data.features)
-    stopo = shard_topology_rows(mesh, topo, layout=None)
+    stopo = shard_topology_rows(mesh, topo)
     jax.block_until_ready((feat, stopo))
     t_shard = time.perf_counter() - t0
-    check(isinstance(stopo, TiledShardedTopology if layout == "tiled"
-                     else ShardedTopology), f"unexpected {type(stopo).__name__}")
+    emit(phase="choices", topology_layout=type(stopo).__name__,
+         dist_exchange_hosts4=exchange)
+    check(isinstance(stopo, ShardedTopology), f"unexpected {type(stopo).__name__}")
     sharded = {"features": feat}
     sharded.update({f"topology.{k}": v for k, v in stopo._asdict().items()
                     if k != "row_start"})
@@ -567,17 +558,15 @@ def four_chip_phase(cfg: dict, data: Data, seed: int, watch: CompileWatch) -> No
             replicate(mesh, ip32), replicate(mesh, ix32), feat),
         "sharded_topology": run(
             "sharded_topology", mesh,
-            make_sharded_topo_train_step(mesh, model, tx, SIZES,
-                                         pipeline="fused", layout=None),
+            make_sharded_topo_train_step(mesh, model, tx, SIZES, pipeline="fused"),
             stopo, feat),
     }
     # the twin is the row-sharded step on a one-device mesh (one shard = the
     # whole graph): the same code, the same draws from the same key
     mesh1 = make_mesh(1)
     want = run("one_device", mesh1,
-               make_sharded_topo_train_step(mesh1, model, tx, SIZES,
-                                            pipeline="fused", layout=None),
-               shard_topology_rows(mesh1, topo, layout=None),
+               make_sharded_topo_train_step(mesh1, model, tx, SIZES, pipeline="fused"),
+               shard_topology_rows(mesh1, topo),
                shard_feature_rows(mesh1, data.features))
     for label, losses in got.items():
         check(np.allclose(losses, want, rtol=1e-3, atol=1e-5),

@@ -556,7 +556,7 @@ def time_eval_split(
     dispatch costs `parallel.scaling.serve_table` wants instead of a
     train-step proxy. Warms one full untimed pass first; each timed leg
     syncs once at the end (raw averages). One shared implementation so
-    `bench.py` and `scripts/serve_probe.py` report the same methodology."""
+    every caller (`scripts/serve_probe.py`) reports the same methodology."""
     import time
 
     ds = sample_batch(sampler, padded_batch)

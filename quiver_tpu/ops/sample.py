@@ -96,7 +96,7 @@ def fisher_yates_positions(key: jax.Array, deg: jax.Array, k: int) -> Tuple[jax.
         in_head = j < k
         # one-hot select, NOT take_along_axis: a per-row dynamic lane read
         # lowers to a B-descriptor gather per scan step (~5 ms/hop at
-        # products hop-3 shape — measured, scripts/probe_fetch_final.py);
+        # products hop-3 shape — measured);
         # the one-hot compare+sum is pure VPU work
         head_val = jnp.where(ar_k[None, :] == j[:, None], head, 0).sum(axis=1)
         match = tail_j == j[:, None]  # [B, k]
@@ -222,8 +222,8 @@ def _tiled_bd_lookup(bd, seeds, seed_valid):
 def _select_lanes(tiles, rows, lane, k):
     """``tiles[rows[b, j], lane[b, j]]`` (rows in range) as k-split row gathers + one-hot
     lane selects (k separate [B]-row gathers measured faster than one
-    [B*k]: probe_tiled_variants 6.2 vs 7.1 ms; one-hot instead of
-    take_along_axis — the descriptor trap, probe_fetch_final). The ONE
+    [B*k]: 6.2 vs 7.1 ms; one-hot instead of take_along_axis — the
+    descriptor trap). The ONE
     position fetch: the tiled layers and the flat sharded layer all ride
     it, so the fetch pattern is tuned in one place."""
     ar = jnp.arange(LANE, dtype=jnp.int32)
@@ -355,8 +355,7 @@ def build_tiled_host(
     ``i`` then lives at tile row ``base[i] + p // 128``, lane ``p % 128``
     — so the neighbor fetch becomes 2-D ROW gathers (measured ~115-145M
     rows/s on v5e) + an in-register one-hot lane select, instead of
-    one-element gathers (~45-90M/s): scripts/probe_rowgather_width.py,
-    probe_tiled_variants.py, probe_fetch_final.py. Exact for every
+    one-element gathers (~45-90M/s). Exact for every
     degree — no copy-all/hub split. Memory: ceil-padding to 128 costs
     ~(E + 64*N)/E x the flat CSR (products: 1.45 GB vs 0.49 GB).
 
@@ -558,7 +557,7 @@ def tiled_sample_layer(
     Draw-identical to :func:`sample_layer` on the same key (same
     Fisher-Yates positions; only the fetch path differs): positions are
     resolved via k 2-D row gathers + one-hot lane selects. Measured at
-    products hop-3 shape: fetch 6.5 vs 9.0 ms (scripts/probe_fetch_final.py).
+    products hop-3 shape: fetch 6.5 vs 9.0 ms.
     """
     base, deg = _tiled_bd_lookup(bd, seeds, seed_valid)
     pos, valid = fisher_yates_positions(key, deg, k)

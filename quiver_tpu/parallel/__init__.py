@@ -8,15 +8,10 @@ from .collectives import (
 )
 from .topology import (
     ShardedTopology,
-    TiledShardedTopology,
-    build_tiled_topology_shards,
-    resolve_topology_layout,
     sampling_comm_bytes,
     shard_topology_rows,
     sharded_sample_layer,
     sharded_sample_layer_grouped,
-    tiled_sharded_sample_layer,
-    tiled_sharded_sample_layer_grouped,
 )
 from .collectives import sharded_gather_hot_cold
 from .scaling import (
@@ -39,12 +34,7 @@ from .train import (
 
 __all__ = [
     "ShardedTopology",
-    "TiledShardedTopology",
-    "build_tiled_topology_shards",
     "calibrate_cold_budget",
-    "resolve_topology_layout",
-    "tiled_sharded_sample_layer",
-    "tiled_sharded_sample_layer_grouped",
     "collective_payload_bytes",
     "predict_layout",
     "products_scaling_table",

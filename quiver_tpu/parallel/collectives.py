@@ -64,7 +64,6 @@ def _partial_rows(table_block: jax.Array, ids: jax.Array, axes) -> jax.Array:
 
 def sharded_gather_grouped(
     table_block: jax.Array, ids: jax.Array, feat_axes, group_axis: str,
-    via: str = "scatter",
 ) -> jax.Array:
     """`sharded_gather` for id lists that DIFFER across ``group_axis`` (one
     of the table's striping axes, typically "host").
@@ -72,27 +71,12 @@ def sharded_gather_grouped(
     `sharded_gather` requires ids identical across every psum axis; when
     data-parallel groups span the host axis, each host samples different
     seeds, so the lists are first all_gathered over ``group_axis`` and
-    gathered once for all groups. The return trip has two spellings:
-
-    - ``via="scatter"`` (default): `psum_scatter` the ``[G, W, D]`` partial
-      rows over ``group_axis`` (each group receives only ITS slice, reduced
-      on the way — ring cost (G-1)/G of the payload), then psum the ``[W,
-      D]`` remainder over the other striping axes. DCN row-bytes: (G-1)*W*D.
-    - ``via="psum"``: full psum over every striping axis, slice own answer
-      (round-3 layout). DCN row-bytes: 2*(G-1)*W*D, and the non-group axes
-      carry the G-fold width too — G x the ICI payload of "scatter".
-
-    Both produce identical rows; "scatter" strictly dominates the byte
-    model and the hermetic 8-device measurement (SCALING.md round-4 table,
-    tests/test_parallel.py::test_grouped_gather_scatter_matches_psum), so
-    "psum" remains only as the reference spelling for that comparison.
+    gathered once for all groups. On the return trip the ``[G, W, D]``
+    partial rows are `psum_scatter`ed over ``group_axis`` (each group
+    receives only ITS slice, reduced on the way — ring cost (G-1)/G of the
+    payload), then the ``[W, D]`` remainder is psummed over the other
+    striping axes. DCN row-bytes: (G-1)*W*D.
     """
-    if via == "psum":
-        all_ids = lax.all_gather(ids, group_axis)  # identical across group_axis
-        rows = sharded_gather(table_block, all_ids, feat_axes)
-        return rows[lax.axis_index(group_axis)]
-    if via != "scatter":
-        raise ValueError(f"unknown via {via!r}")
     if isinstance(feat_axes, str):
         axes = (feat_axes,)
     else:
@@ -100,8 +84,8 @@ def sharded_gather_grouped(
     if group_axis not in axes:
         # table not striped over the group axis: every group participant
         # holds identical partials, so a scatter-reduce would G-fold-count
-        # them; the psum+slice spelling is the correct (and equally cheap,
-        # no reduction rides group_axis at all) form there
+        # them; a full psum + slice is the correct (and equally cheap, no
+        # reduction rides group_axis at all) form there
         all_ids = lax.all_gather(ids, group_axis)
         rows = sharded_gather(table_block, all_ids, axes)
         return rows[lax.axis_index(group_axis)]
@@ -123,14 +107,13 @@ def sharded_gather_a2a(
     ids: [B_local] this chip's request list (global ids).
     Returns [B_local, D]: rows for this chip's ids.
 
-    This is exactly `sharded_gather_grouped(via="scatter")` specialized to
-    one axis that is both the striping and the group axis, so it DELEGATES
-    there (one return-trip implementation; the reference's id/feature
-    exchange pattern, comm.py:127-182, collapsed into two XLA collectives).
+    This is exactly `sharded_gather_grouped` specialized to one axis that
+    is both the striping and the group axis, so it DELEGATES there (one
+    return-trip implementation; the reference's id/feature exchange
+    pattern, comm.py:127-182, collapsed into two XLA collectives).
 
     When to use which (measured compiled-HLO payloads at W=512, D=32,
-    P=8 — scripts/compare_grouped_return.py a2a section + SCALING.md
-    round-5 table): with a SHARDED consumer, a2a moves 10240 B/chip
+    P=8 — SCALING.md round-5 table): with a SHARDED consumer, a2a moves 10240 B/chip
     (2048 request all-gather + 8192 reduce-scatter) vs the
     replicated-request `sharded_gather`'s 65536 B all-reduce — 6.4x
     cheaper. But if the consumer needs the FULL row set (every train step
@@ -142,8 +125,7 @@ def sharded_gather_a2a(
     partitions).
     """
     return sharded_gather_grouped(
-        table_block, ids, feat_axes=axis_name, group_axis=axis_name,
-        via="scatter",
+        table_block, ids, feat_axes=axis_name, group_axis=axis_name
     )
 
 
@@ -155,7 +137,6 @@ def sharded_gather_hot_cold(
     group_axis: str,
     hot_rows: int,
     cold_budget: int,
-    cold_via: str = "scatter",
 ):
     """Grouped gather with a per-host REPLICATED hot prefix — the in-jit
     analog of the reference's `PartitionInfo.replicate` hot set
@@ -214,9 +195,7 @@ def sharded_gather_hot_cold(
     sel = order[:cold_budget]
     lane_ok = jnp.arange(cold_budget, dtype=jnp.int32) < n_cold
     cold_local = jnp.where(lane_ok, jnp.take(ids, sel) - hot_rows, -1)
-    cold_rows = sharded_gather_grouped(
-        cold_block, cold_local, feat_axes, group_axis, via=cold_via
-    )
+    cold_rows = sharded_gather_grouped(cold_block, cold_local, feat_axes, group_axis)
     cold_rows = jnp.where(lane_ok[:, None], cold_rows, jnp.zeros_like(cold_rows))
     out = hot_part.at[sel].add(cold_rows, mode="drop")
     overflow = jnp.maximum(n_cold - cold_budget, 0)

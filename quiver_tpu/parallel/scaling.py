@@ -8,7 +8,7 @@ framework's multichip evidence is split: hermetic correctness on the virtual
 CPU mesh (tests/test_parallel.py, `__graft_entry__.dryrun_multichip`) plus
 THIS static cost model, which predicts step/epoch time on N chips from
 
-- the single-chip measured step time (bench.py context), and
+- the single-chip measured step time, and
 - per-step collective bytes counted statically from the same layout the
   jitted programs use (`topology.sampling_comm_bytes` ring model), divided
   by explicit, overridable link-bandwidth assumptions.
@@ -312,83 +312,6 @@ def products_scaling_table(
             )
         )
     return rows
-
-
-# Measured single-chip HBM gather rates (PERF.md (earlier claims), v5e): at
-# the hop-3 probe shape (W=135168 rows, k=5 -> 811,008 descriptors/hop,
-# scripts/probe_fetch_final.py) the flat element fetch ran 8.95 ms/hop and
-# the 128-lane tile fetch 6.48 ms/hop. Expressed as descriptor issue rates
-# so the model scales to other hop shapes; both are descriptor-rate-bound
-# regimes, not bandwidth-bound, which is why tiled wins despite fetching
-# 128x the bytes per position descriptor.
-MEASURED_FETCH_DESC_PER_S = {
-    "flat": 811_008 / 8.95e-3,   # ~90.6M element-gather descriptors/s
-    "tiled": 811_008 / 6.48e-3,  # ~125.2M 128-lane row-gather descriptors/s
-}
-
-
-class FetchPrediction(NamedTuple):
-    layout: str
-    hbm_descriptors: float
-    hbm_fetch_bytes: float
-    fetch_s: float
-
-
-def sharded_fetch_table(
-    mesh: ShapeMesh,
-    sizes: Sequence[int],
-    batch_per_group: int,
-    caps: Optional[Sequence[Optional[int]]] = None,
-    rates: Optional[Dict[str, float]] = None,
-) -> List[FetchPrediction]:
-    """Flat-vs-tiled shard-LOCAL fetch cost for the sharded-topology step.
-
-    The collective payloads are layout-invariant (same ``[W, k]`` return
-    trip — `sampling_comm_bytes` and the dryrun LAYOUT-TABLE both show it),
-    so the layouts differ ONLY in this per-chip HBM fetch term: descriptor
-    counts from `sampling_comm_bytes(layout=...)` divided by the measured
-    single-chip issue rates (`MEASURED_FETCH_DESC_PER_S`). This is the row
-    that makes the flat-vs-tiled sharded choice comparable without a pod;
-    ``rates`` overrides the measured constants for other hardware.
-    """
-    from .topology import sampling_comm_bytes
-
-    r = dict(MEASURED_FETCH_DESC_PER_S)
-    if rates:
-        r.update(rates)
-    rows = []
-    for layout in ("flat", "tiled"):
-        c = sampling_comm_bytes(
-            mesh, sizes, batch_per_group, caps=caps, layout=layout
-        )
-        rows.append(
-            FetchPrediction(
-                layout=layout,
-                hbm_descriptors=c["hbm_descriptors"],
-                hbm_fetch_bytes=c["hbm_fetch_bytes"],
-                fetch_s=c["hbm_descriptors"] / r[layout],
-            )
-        )
-    return rows
-
-
-def format_fetch_markdown(rows: Sequence[FetchPrediction]) -> str:
-    lines = [
-        "| shard layout | HBM descriptors/step | HBM bytes/step | fetch ms/step (measured rates) |",
-        "|---|---|---|---|",
-    ]
-    for row in rows:
-        lines.append(
-            f"| {row.layout} | {row.hbm_descriptors:.0f} "
-            f"| {row.hbm_fetch_bytes:.0f} | {row.fetch_s*1e3:.2f} |"
-        )
-    lines.append("")
-    lines.append(
-        "Rates: flat ~90.6M element-gather desc/s, tiled ~125.2M 128-lane "
-        "row-gather desc/s (PERF.md (earlier claims) ROUND-5 hop-3 probe; both "
-        "descriptor-rate-bound, so tiled wins despite moving more bytes)."
-    )
-    return "\n".join(lines)
 
 
 class QuantPrediction(NamedTuple):

@@ -39,6 +39,12 @@ from .collectives import (
     sharded_gather_grouped,
     sharded_gather_hot_cold,
 )
+from .topology import (
+    _check_layout,
+    sampling_comm_bytes,
+    sharded_sample_layer,
+    sharded_sample_layer_grouped,
+)
 
 
 def make_mesh(
@@ -292,38 +298,24 @@ def _topo_sample_local(pipeline, sizes, caps, has_host, hot_cold, feat_axes,
     """What a sharded-topology step samples and gathers, inside shard_map:
     ``(ds, x, dropout_key, overflow_acc)``. The train step and
     `make_sharded_topo_sample` both run exactly this, so the same key and
-    seeds give the same draws and rows in either program. The shard layout
-    is the type of ``stopo``."""
-    from .topology import (
-        TiledShardedTopology,
-        sharded_sample_layer,
-        sharded_sample_layer_grouped,
-        tiled_sharded_sample_layer,
-        tiled_sharded_sample_layer_grouped,
-    )
-
+    seeds give the same draws and rows in either program."""
     overflow_acc = []
     gather_rows = _make_gather_rows(
         has_host, hot_cold, feat_axes, hot_rows, cold_budget, overflow_acc
     )
     row_start = stopo.row_start     # [P+1] replicated boundaries
-    # this shard's blocks, the leading shard axis of length 1 dropped by a
-    # reshape: ``block[0]`` compiles to a COPY of the block every step
-    # (3.7 ms for the 808 MB edge block of the papers100M cell; PERF.md)
-    if isinstance(stopo, TiledShardedTopology):
-        # [R_max, 2] (base, deg) and the [M_max, 128] tile table
-        blocks = (stopo.bd, stopo.tiles)
-        plain, grouped = tiled_sharded_sample_layer, tiled_sharded_sample_layer_grouped
-    else:
-        # [R_max+1] shard-local indptr and the [E_pad] edge block
-        blocks = (stopo.indptr, stopo.indices)
-        plain, grouped = sharded_sample_layer, sharded_sample_layer_grouped
-    blocks = tuple(b.reshape(b.shape[1:]) for b in blocks)
+    # this shard's blocks, the [R_max+1] local indptr and the [E_pad] edge
+    # block, the leading shard axis of length 1 dropped by a reshape:
+    # ``block[0]`` compiles to a COPY of the block every step (3.7 ms for the
+    # 808 MB edge block of the papers100M cell; PERF.md)
+    blocks = tuple(b.reshape(b.shape[1:]) for b in (stopo.indptr, stopo.indices))
 
     def sample_fn(cur, cur_valid, k, sub):
         if not has_host:
-            return plain(*blocks, row_start, cur, cur_valid, k, sub, feat_axes)
-        return grouped(*blocks, row_start, cur, cur_valid, k, sub, feat_axes, "host")
+            return sharded_sample_layer(
+                *blocks, row_start, cur, cur_valid, k, sub, feat_axes)
+        return sharded_sample_layer_grouped(
+            *blocks, row_start, cur, cur_valid, k, sub, feat_axes, "host")
 
     key, dropout_key = jax.random.split(_fold_group_key(key, has_host))
     if pipeline == "fused":
@@ -337,21 +329,6 @@ def _topo_sample_local(pipeline, sizes, caps, has_host, hot_cold, feat_axes,
             gather_fn=gather_rows, sample_fn=sample_fn,
         )
     return ds, x, dropout_key, overflow_acc
-
-
-def _check_stopo_layout(layout: Optional[str], stopo) -> None:
-    """A named ``layout`` has to be the one ``stopo`` was built in (checked
-    where the program is traced; ``None`` takes either)."""
-    from .topology import TiledShardedTopology, resolve_topology_layout
-
-    if layout is None:
-        return
-    built = "tiled" if isinstance(stopo, TiledShardedTopology) else "flat"
-    if resolve_topology_layout(layout) != built:
-        raise ValueError(
-            f"layout={layout!r} but stopo is a {type(stopo).__name__}: build it "
-            f"with shard_topology_rows(layout={layout!r})"
-        )
 
 
 def make_sharded_topo_train_step(
@@ -380,14 +357,8 @@ def make_sharded_topo_train_step(
     first all_gathered over it (hosts sample different seeds), mirroring the
     grouped feature gather.
 
-    The shard block format is the TYPE of ``stopo`` — `ShardedTopology`
-    ("flat", bytes follow the edges) or `TiledShardedTopology` ("tiled", the
-    128-lane tile layout, bytes follow the nodes) — as `shard_topology_rows`
-    built it (it resolves ``layout=None`` from the graph); the step is traced
-    once per type it is called with. ``layout``, if named, is held against
-    that type. Both layouts fetch positions as 128-lane row gathers;
-    collective payloads and sampling draws are identical (same key -> same
-    neighbors).
+    ``stopo`` is the `ShardedTopology` that `shard_topology_rows` placed.
+    ``layout`` chooses nothing (`topology._check_layout`, ROADMAP D13).
 
     ``hot_rows``/``cold_budget`` compose the replicated-hot feature tier
     with the sharded topology (multi-host meshes; same contract as
@@ -400,6 +371,7 @@ def make_sharded_topo_train_step(
     a step). `make_sharded_topo_sample` returns what a step sampled and
     gathered.
     """
+    _check_layout(layout)
     has_host, data_axes, feat_axes, hot_cold = _validate_step_config(
         mesh, pipeline, caps, hot_rows, cold_budget
     )
@@ -417,7 +389,6 @@ def make_sharded_topo_train_step(
 
     def sharded_topo_train_step(params, opt_state, key, stopo, feat_block,
                                 labels, seeds):
-        _check_stopo_layout(layout, stopo)
         return _shard_map_fn(
             step_local,
             mesh=mesh,
@@ -453,7 +424,9 @@ def make_sharded_topo_sample(
     step trained on — for evaluation, for debugging a loss, for holding a
     step against the host graph. Every array of ``ds`` and ``x`` gains a
     leading axis of one entry per data-parallel group (groups fold their
-    index into the key and sample their own share of ``seeds``)."""
+    index into the key and sample their own share of ``seeds``). ``layout``
+    as on the step."""
+    _check_layout(layout)
     has_host, data_axes, feat_axes, hot_cold = _validate_step_config(
         mesh, pipeline, caps, hot_rows, cold_budget
     )
@@ -471,7 +444,6 @@ def make_sharded_topo_sample(
 
     @jax.jit
     def sharded_topo_sample(key, stopo, feat_block, seeds):
-        _check_stopo_layout(layout, stopo)
         return _shard_map_fn(
             sample_local,
             mesh=mesh,
@@ -496,8 +468,6 @@ def step_comm_bytes(mesh: Mesh, sizes: Sequence[int], batch_per_group: int,
     where the step is built, and count it per dispatched step with
     ``trace.observe("quiver.step.comm_bytes", total)`` (recorded only while
     tracing is on)."""
-    from .topology import sampling_comm_bytes
-
     return sampling_comm_bytes(
         mesh, sizes, batch_per_group, feature_dim=feature_dim, caps=caps, **model
     )["total_bytes"]

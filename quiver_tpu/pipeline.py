@@ -855,7 +855,7 @@ def make_tiered_train_step(model, tx, labels: jax.Array, hot_table: jax.Array):
     """Jitted ``step(params, opt_state, key, batch)`` fusing the hot gather
     into fwd/bwd. ``labels``/``hot_table`` enter the jitted program as
     ARGUMENTS (closure capture would embed a million-row table as an XLA
-    constant — minutes of compile, see bench.py)."""
+    constant — minutes of compile)."""
     import optax
 
     hot_table = jnp.asarray(hot_table)

@@ -465,7 +465,7 @@ def caps_from_counts(
     ``max`` over the probe batches x ``margin`` safety factor, rounded up to
     ``granule`` (shape granularity keeps recompiles away when recalibrating),
     clipped to the uncapped worst case ``B*prod(1+k)``. This is the policy
-    the round-2 bench hand-rolled (bench.py:275-286) promoted into the
+    the round-2 bench hand-rolled, promoted into the
     library — the reference needs no caps (ragged CUDA shapes); static-shape
     TPU pipelines do, so choosing them is the framework's job.
     """

@@ -15,8 +15,7 @@ Pieces:
   ``make_tiered_train_step``).
 
 Byte/capacity accounting lives in
-``quiver_tpu.parallel.scaling.quant_fetch_table``; the synthetic
-fp32-vs-int8 training probe is ``scripts/quant_probe.py``.
+``quiver_tpu.parallel.scaling.quant_fetch_table``.
 """
 
 from .codecs import (

@@ -123,7 +123,7 @@ def make_quantized_train_step(
     :class:`TieredBatch`; the batch's ``cold_rows`` arrive in storage
     dtype from a ``TieredFeaturePipeline`` built over a
     :class:`QuantizedFeature`). Tables/labels enter as jit ARGUMENTS —
-    closure capture would bake them in as XLA constants (see bench.py).
+    closure capture would bake them in as XLA constants.
     """
     import optax
 

@@ -547,8 +547,7 @@ class ServeConfig:
                      OBSERVE-ONLY: events never feed control flow, so
                      enabling it changes no served bit (pinned in
                      tests/test_obs.py); cost is one deque append per
-                     event, cheap enough to leave on (bench.py
-                     ``serve_obs_overhead_frac``).
+                     event, cheap enough to leave on.
     workload       : a `trace.WorkloadConfig` enables the round-13
                      workload telemetry (None = off, zero cost): a
                      `trace.WorkloadMonitor` taps every submitted seed
@@ -560,8 +559,7 @@ class ServeConfig:
                      lock), never wall time, so sketch state is
                      replay-bit-stable. Same OBSERVE-ONLY contract as the
                      journal: enabling it changes no served bit (pinned
-                     in tests/test_skew.py; measured price: bench.py
-                     ``serve_skew_overhead_frac``).
+                     in tests/test_skew.py).
                      ``engine.workload.skew_report()`` is the read side.
     tier_promote_batch : max row MOVES per adaptation pass (round 14;
                      bounds the apply batch's disk read + device

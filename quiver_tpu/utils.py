@@ -481,7 +481,7 @@ def force_virtual_cpu_devices(n_devices: int) -> None:
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache and return its directory
     — the ONE place the repo decides where compiled programs are kept
-    (chip_smoke.py, bench.py and the probes that import it, tests/conftest).
+    (chip_smoke.py, tests/conftest).
 
     ``JAX_COMPILATION_CACHE_DIR`` set: JAX has already read it, and no
     directory is set in code (a machine that provides the variable keeps

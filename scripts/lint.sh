@@ -6,7 +6,7 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-python -m compileall -q quiver_tpu quiver tests examples scripts benchmarks bench.py __graft_entry__.py setup.py
+python -m compileall -q quiver_tpu quiver tests examples scripts benchmarks __graft_entry__.py setup.py
 
 fail=0
 if grep -rn --include='*.py' -P '^\t' quiver_tpu quiver tests examples scripts; then

@@ -225,10 +225,8 @@ def main():
         source = "PERF.md (earlier claims) round-4 default 41.5 ms"
 
     from quiver_tpu.parallel.scaling import (
-        ShapeMesh,
         delta_table,
         format_delta_markdown,
-        format_fetch_markdown,
         format_lp_markdown,
         format_markdown,
         format_quant_markdown,
@@ -239,7 +237,6 @@ def main():
         products_scaling_table,
         quant_fetch_table,
         serve_table,
-        sharded_fetch_table,
         skew_table,
         tier_table,
     )
@@ -249,18 +246,6 @@ def main():
         step_s, steps_per_epoch_1chip=args.steps_per_epoch, bandwidths=bw
     )
     md = format_markdown(rows, step_s, bw)
-    # flat-vs-tiled shard-LOCAL fetch term at the products config on the
-    # 2-host sharded-topology mesh (collective bytes are layout-invariant;
-    # this per-chip HBM term is where the layouts differ)
-    fetch_mesh = ShapeMesh(
-        ("host", "dp", "ici"), {"host": 2, "dp": 2, "ici": 2}
-    )
-    fetch_rows = sharded_fetch_table(fetch_mesh, (15, 10, 5), 1024)
-    fetch_md = (
-        "## Sharded-topology shard-local fetch: flat vs tiled "
-        "(host=2,dp=2,ici=2, products config)\n\n"
-        + format_fetch_markdown(fetch_rows)
-    )
     # per-codec quantized feature-store rows (quiver_tpu.quant): hot-cache
     # capacity multiplier + gather/H2D byte reduction at the products config
     quant_rows = quant_fetch_table((15, 10, 5), 1024, 100)
@@ -677,7 +662,6 @@ def main():
         + format_lp_markdown(lp_rows)
     )
     print(md, file=sys.stderr)
-    print("\n" + fetch_md, file=sys.stderr)
     print("\n" + quant_md, file=sys.stderr)
     print("\n" + serve_md, file=sys.stderr)
     print("\n" + serve_dist_md, file=sys.stderr)
@@ -699,7 +683,7 @@ def main():
         )
         with open(args.out, "w") as fh:
             fh.write(
-                header + md + "\n\n" + fetch_md + "\n\n" + quant_md
+                header + md + "\n\n" + quant_md
                 + "\n\n" + serve_md + "\n\n" + serve_dist_md
                 + "\n\n" + skew_md + "\n\n" + tier_md + "\n\n"
                 + delta_md + "\n\n"
@@ -720,7 +704,6 @@ def main():
         "host_resolve_us": host_resolve_us,
         "host_submit_source": host_submit_source,
         "rows": [r._asdict() for r in rows],
-        "sharded_fetch": [r._asdict() for r in fetch_rows],
         "quant_fetch": [r._asdict() for r in quant_rows],
         "serve": [r._asdict() for r in serve_rows],
         "serve_one_vs_two_dispatch": [r._asdict() for r in serve_dispatch_rows],

@@ -21,7 +21,7 @@ jax.config.update("jax_platforms", "cpu")
 
 # Persistent XLA compilation cache, placed by the library's one helper
 # (JAX_COMPILATION_CACHE_DIR if set, else <checkout>/.jax_cache — the same
-# directory bench.py and chip_smoke.py use). The tier-1 suite is
+# directory chip_smoke.py uses). The tier-1 suite is
 # compile-dominated and runs close to its time limit; warm runs skip every
 # compile over JAX's 1 s threshold. Purely an optimization: cache misses
 # (fresh box, jax upgrade) just compile as before.

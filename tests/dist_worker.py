@@ -13,9 +13,9 @@ mode "train": ONE `make_sharded_train_step` step on the process-spanning
 (dp=1, ici=2) mesh — the loss is printed so the parent test can assert it
 matches a single-controller run of the identical step (same keys, same
 mesh shape, same arithmetic; only the process layout differs).
-mode "train_topo_tiled": same, through `make_sharded_topo_train_step`
-with the TILED row-sharded topology (`TiledShardedTopology`): each
-process ends up holding only its own 128-lane tile block of the CSR.
+mode "train_topo": same, through `make_sharded_topo_train_step` with the
+row-sharded topology (`ShardedTopology`): each process ends up holding
+only its own block of the CSR.
 mode "serve": the serve-shaped exchange (`TpuComm.exchange_serve`) across
 two REAL processes: each holds only its own seed-ownership shard
 (topology closure + owned feature rows), runs a local pipelined
@@ -30,7 +30,7 @@ import os
 import sys
 
 
-def train_main(pid: int, port: str, topo_tiled: bool = False) -> None:
+def train_main(pid: int, port: str, topo: bool = False) -> None:
     os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ.setdefault("XLA_FLAGS", "")
 
@@ -66,16 +66,16 @@ def train_main(pid: int, port: str, topo_tiled: bool = False) -> None:
 
     params = jax.tree_util.tree_map(lambda a: gput(a, P()), case["params_np"])
     opt_state = jax.tree_util.tree_map(lambda a: gput(a, P()), case["opt_np"])
-    if topo_tiled:
-        from quiver_tpu.parallel import TiledShardedTopology
+    if topo:
+        from quiver_tpu.parallel import ShardedTopology
 
-        bd_b, tiles_b, row_start = case["stopo_np"]
-        stopo = TiledShardedTopology(
-            bd=gput(bd_b, P(("ici",), None, None)),
-            tiles=gput(tiles_b, P(("ici",), None, None)),
+        ptr_b, idx_b, row_start = case["stopo_np"]
+        stopo = ShardedTopology(
+            indptr=gput(ptr_b, P(("ici",), None)),
+            indices=gput(idx_b, P(("ici",), None)),
             row_start=gput(row_start, P()),
         )
-        step = case["make_step_topo_tiled"](mesh)
+        step = case["make_step_topo"](mesh)
         args = (
             params, opt_state, jax.random.key(2), stopo,
             gput(case["feat_padded"], P(("ici",), None)),
@@ -218,8 +218,8 @@ def serve_main(pid: int, port: str) -> None:
 def main() -> None:
     pid = int(sys.argv[1])
     port = sys.argv[2]
-    if len(sys.argv) > 3 and sys.argv[3] in ("train", "train_topo_tiled"):
-        train_main(pid, port, topo_tiled=sys.argv[3] == "train_topo_tiled")
+    if len(sys.argv) > 3 and sys.argv[3] in ("train", "train_topo"):
+        train_main(pid, port, topo=sys.argv[3] == "train_topo")
         return
     if len(sys.argv) > 3 and sys.argv[3] == "serve":
         serve_main(pid, port)

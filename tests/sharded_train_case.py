@@ -20,12 +20,12 @@ def build_case():
     from quiver_tpu import CSRTopo
     from quiver_tpu.models import GraphSAGE
     from quiver_tpu.parallel import (
-        build_tiled_topology_shards,
         make_mesh,
         make_sharded_topo_train_step,
         make_sharded_train_step,
     )
     from quiver_tpu.parallel.collectives import pad_to_multiple
+    from quiver_tpu.parallel.topology import build_topology_shards
     from quiver_tpu.pyg.sage_sampler import sample_dense_pure
 
     edge_index, feat, labels, n = _community_graph()
@@ -39,15 +39,15 @@ def build_case():
     )
     x0 = jnp.zeros((ds0.n_id.shape[0], feat.shape[1]), jnp.float32)
     params = model.init(jax.random.key(1), x0, ds0.adjs)
-    # TILED row-sharded topology blocks for the 2-shard (ici=2) mesh — the
-    # round-6 layout; both runners place bd/tiles striped over ici
-    bd_b, tiles_b, row_start = build_tiled_topology_shards(
+    # row-sharded topology blocks for the 2-shard (ici=2) mesh; both runners
+    # place the indptr/indices blocks striped over ici
+    ptr_b, idx_b, row_start = build_topology_shards(
         topo.indptr.astype(np.int32), topo.indices.astype(np.int32), 2
     )
     return {
         "indptr": topo.indptr.astype(np.int32),
         "indices": topo.indices.astype(np.int32),
-        "stopo_np": (bd_b, tiles_b, np.asarray(row_start)),
+        "stopo_np": (ptr_b, idx_b, np.asarray(row_start)),
         # the exact padding shard_feature_rows applies on an ici=2 mesh
         "feat_padded": np.asarray(pad_to_multiple(feat, 2)),
         "labels": labels,
@@ -57,7 +57,7 @@ def build_case():
         "make_step": lambda mesh: make_sharded_train_step(
             mesh, model, tx, sizes=CASE_SIZES, pipeline="dedup"
         ),
-        "make_step_topo_tiled": lambda mesh: make_sharded_topo_train_step(
-            mesh, model, tx, sizes=CASE_SIZES, pipeline="dedup", layout="tiled"
+        "make_step_topo": lambda mesh: make_sharded_topo_train_step(
+            mesh, model, tx, sizes=CASE_SIZES, pipeline="dedup"
         ),
     }

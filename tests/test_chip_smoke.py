@@ -1,4 +1,4 @@
-"""chip_smoke.py and bench.py never pass on a CPU; the smoke's phases run at
+"""chip_smoke.py never passes on a CPU; the smoke's phases run at
 the rehearsal size; the compile cache goes where the one helper says."""
 
 import json
@@ -17,8 +17,8 @@ from quiver_tpu.utils import enable_compile_cache
 
 
 @pytest.mark.parametrize(
-    "argv", [["chip_smoke.py", "--small"], ["chip_smoke.py"], ["bench.py"]],
-    ids=["smoke-small", "smoke", "bench"],
+    "argv", [["chip_smoke.py", "--small"], ["chip_smoke.py"]],
+    ids=["smoke-small", "smoke"],
 )
 def test_no_silent_cpu_pass(argv):
     """No TPU -> a traceback naming the platform, a non-zero exit, and no
@@ -31,7 +31,7 @@ def test_no_silent_cpu_pass(argv):
     )
     assert out.returncode != 0, out.stdout
     assert "platform 'cpu'" in out.stderr, out.stderr[-2000:]
-    assert '"ok"' not in out.stdout and '"metric"' not in out.stdout, out.stdout
+    assert '"ok"' not in out.stdout, out.stdout
 
 
 def test_phases_run_at_rehearsal_size():

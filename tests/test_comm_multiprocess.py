@@ -112,30 +112,30 @@ def test_two_process_sharded_train_step_matches_single_controller():
         assert abs(got - expect) < 1e-5, (got, expect, out)
 
 
-def test_two_process_tiled_topo_train_step_matches_single_controller():
-    """`make_sharded_topo_train_step(layout="tiled")` end to end across two
-    OS processes: each process holds ONLY its own tile block of the
-    row-sharded CSR (the round-6 tiled shard layout), and one step must
-    produce the same loss as the identical single-controller run."""
+def test_two_process_topo_train_step_matches_single_controller():
+    """`make_sharded_topo_train_step` end to end across two OS processes:
+    each process holds ONLY its own block of the row-sharded CSR, and one
+    step must produce the same loss as the identical single-controller
+    run."""
     from sharded_train_case import CASE_SEEDS, build_case
 
     import jax
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from quiver_tpu.parallel import TiledShardedTopology
+    from quiver_tpu.parallel import ShardedTopology
 
     case = build_case()
     mesh = case["make_mesh"]()
-    step = case["make_step_topo_tiled"](mesh)
+    step = case["make_step_topo"](mesh)
 
     def put(x, spec=P()):
         return jax.device_put(jax.numpy.asarray(x), NamedSharding(mesh, spec))
 
-    bd_b, tiles_b, row_start = case["stopo_np"]
-    stopo = TiledShardedTopology(
-        bd=put(bd_b, P(("ici",), None, None)),
-        tiles=put(tiles_b, P(("ici",), None, None)),
+    ptr_b, idx_b, row_start = case["stopo_np"]
+    stopo = ShardedTopology(
+        indptr=put(ptr_b, P(("ici",), None)),
+        indices=put(idx_b, P(("ici",), None)),
         row_start=put(row_start),
     )
     params = jax.tree_util.tree_map(put, case["params_np"])
@@ -148,7 +148,7 @@ def test_two_process_tiled_topo_train_step_matches_single_controller():
     expect = float(loss)
     assert np.isfinite(expect)
 
-    outs = _run_workers(mode="train_topo_tiled")
+    outs = _run_workers(mode="train_topo")
     for pid, out in enumerate(outs):
         line = [l for l in out.splitlines() if l.startswith(f"worker {pid} loss")]
         assert line, out

@@ -111,25 +111,6 @@ def test_hot_cold_tier_cuts_dcn():
     assert hc.layout == "sharded_topology_hot_cold"
 
 
-def test_sharded_fetch_table_flat_vs_tiled():
-    """The round-6 layout comparison row: identical descriptor counts,
-    tiled fetches more bytes but prices CHEAPER in time under the measured
-    descriptor rates (both regimes are issue-rate-bound, PERF.md (earlier claims))."""
-    from quiver_tpu.parallel.scaling import sharded_fetch_table
-
-    mesh = ShapeMesh(("host", "dp", "ici"), {"host": 2, "dp": 2, "ici": 2})
-    flat, tiled = sharded_fetch_table(mesh, (15, 10, 5), 1024)
-    assert (flat.layout, tiled.layout) == ("flat", "tiled")
-    assert flat.hbm_descriptors == tiled.hbm_descriptors
-    assert tiled.hbm_fetch_bytes > flat.hbm_fetch_bytes
-    assert tiled.fetch_s < flat.fetch_s
-    # rates are overridable knobs: a slower tiled rate flips the verdict
-    flat2, tiled2 = sharded_fetch_table(
-        mesh, (15, 10, 5), 1024, rates={"tiled": 1e6}
-    )
-    assert tiled2.fetch_s > flat2.fetch_s
-
-
 def test_collective_payload_bytes_parses_tuples():
     txt = """
   %ar = (f32[16,8]{1,0}, f32[64,8]{1,0}) all-reduce(%a, %b), replica_groups={}

@@ -1,11 +1,10 @@
-"""Placement shard by shard, the layout resolved from the graph, and what a
-sharded step sampled (ISSUE 28): `shard_feature_rows` / `shard_topology_rows`
-never hand a device more than its own block and never build the stack on the
-host; ``layout=None`` picks the flat layout for a low-degree graph and the
-tile layout for a products-like one, with the same draws from the same key;
-`make_sharded_topo_sample` returns the samples and rows the train step
-trained on, held here against the host CSR, the host table and the
-one-device step."""
+"""Placement shard by shard and what a sharded step sampled (ISSUE 28):
+`shard_feature_rows` / `shard_topology_rows` never hand a device more than
+its own block and never build the stack on the host; four shards draw what
+one device draws from the same key, for a low-degree graph and a
+products-like one; `make_sharded_topo_sample` returns the samples and rows
+the train step trained on, held here against the host CSR, the host table
+and the one-device step."""
 
 import jax
 import jax.numpy as jnp
@@ -18,25 +17,18 @@ from quiver_tpu.models import GraphSAGE
 from quiver_tpu.ops.sample import LANE, flat_resolve
 from quiver_tpu.parallel import (
     ShardedTopology,
-    TiledShardedTopology,
     make_mesh,
     make_sharded_topo_sample,
     make_sharded_topo_train_step,
     pad_to_multiple,
     replicate,
-    resolve_topology_layout,
     sampling_comm_bytes,
     shard_feature_hot_cold,
     shard_feature_rows,
     shard_topology_rows,
     step_comm_bytes,
 )
-from quiver_tpu.parallel.topology import (
-    TILE_SLOTS_PER_EDGE_MAX,
-    build_tiled_topology_shards,
-    build_topology_shards,
-    tile_slots_per_edge,
-)
+from quiver_tpu.parallel.topology import build_topology_shards
 
 SIZES = (4, 3)
 
@@ -119,43 +111,20 @@ def test_hot_cold_twin_places_what_the_padded_formulation_placed():
     assert cold.addressable_shards[0].data.shape == (-(-702 // striped), 6)
 
 
-@pytest.mark.parametrize("layout,build", [("flat", build_topology_shards),
-                                          ("tiled", build_tiled_topology_shards)])
-def test_topology_blocks_go_up_one_shard_at_a_time(layout, build, transfers):
+def test_topology_blocks_go_up_one_shard_at_a_time(transfers):
     mesh = make_mesh(4, dp=1)
     topo = graph(3000, 6)
-    stopo = shard_topology_rows(mesh, topo, layout=layout)
-    first, second, row_start = build(topo.indptr, topo.indices.astype(np.int32), 4)
-    assert isinstance(stopo, TiledShardedTopology if layout == "tiled" else ShardedTopology)
-    for arr, want in zip(stopo[:2], (first, second)):
+    stopo = shard_topology_rows(mesh, topo)
+    ptr, idx, row_start = build_topology_shards(topo.indptr, topo.indices.astype(np.int32), 4)
+    assert isinstance(stopo, ShardedTopology)
+    for arr, want in zip(stopo[:2], (ptr, idx)):
         assert arr.shape == want.shape and arr.dtype == jnp.int32
         assert [s.data.shape for s in arr.addressable_shards] == [(1,) + want.shape[1:]] * 4
         np.testing.assert_array_equal(np.asarray(arr), want)
     np.testing.assert_array_equal(np.asarray(stopo.row_start), row_start)
     # the largest single transfer is one block, not the stack of four
-    assert max(transfers) == max(first[0].nbytes, second[0].nbytes)
-    assert stopo[1].shape[-1] % LANE == 0  # flat blocks are whole lane rows
-
-
-def test_layout_is_resolved_from_the_graph():
-    low, dense = graph(3000, 6), graph(800, 80, seed=3)
-    assert tile_slots_per_edge(low.indptr) > TILE_SLOTS_PER_EDGE_MAX
-    assert tile_slots_per_edge(dense.indptr) < TILE_SLOTS_PER_EDGE_MAX
-    assert resolve_topology_layout(None, low.indptr) == "flat"
-    assert resolve_topology_layout(None, dense.indptr) == "tiled"
-    # the slots are the tile table's own
-    _, tiles, _ = build_tiled_topology_shards(dense.indptr, dense.indices, 1, pad_multiple=1)
-    assert tile_slots_per_edge(dense.indptr) == tiles[0].size / dense.indices.shape[0]
-    # a named layout is kept whatever the graph; None needs the graph
-    assert resolve_topology_layout("tiled", low.indptr) == "tiled"
-    assert resolve_topology_layout("flat") == "flat"
-    with pytest.raises(ValueError, match="from the graph"):
-        resolve_topology_layout(None)
-    with pytest.raises(ValueError, match="unsupported"):
-        resolve_topology_layout("coo", low.indptr)
-    mesh = make_mesh(4, dp=1)
-    assert isinstance(shard_topology_rows(mesh, low), ShardedTopology)
-    assert isinstance(shard_topology_rows(mesh, dense), TiledShardedTopology)
+    assert max(transfers) == max(ptr[0].nbytes, idx[0].nbytes)
+    assert stopo.indices.shape[-1] % LANE == 0  # blocks are whole lane rows
 
 
 def test_flat_resolve_reads_what_an_element_gather_reads():
@@ -179,20 +148,18 @@ def _problem(topo, n_dev, dim=12, classes=5, batch=32):
     return mesh, feat, labels, model, seeds
 
 
-def _sampled(mesh, topo, feat, seeds, key, layout=None):
-    sample = make_sharded_topo_sample(mesh, SIZES, pipeline="fused", layout=layout)
-    ds, x = sample(key, shard_topology_rows(mesh, topo, layout=layout),
+def _sampled(mesh, topo, feat, seeds, key):
+    sample = make_sharded_topo_sample(mesh, SIZES, pipeline="fused")
+    ds, x = sample(key, shard_topology_rows(mesh, topo),
                    shard_feature_rows(mesh, feat), seeds)
     return jax.tree_util.tree_map(lambda a: np.asarray(a)[0], ds._replace(batch_size=None)), \
         np.asarray(x)[0], ds.batch_size
 
 
-@pytest.mark.parametrize("mean_degree,layout", [(6, "flat"), (80, "tiled")])
-def test_sampled_blocks_are_edges_of_the_host_csr_and_rows_of_the_host_table(
-        mean_degree, layout):
+@pytest.mark.parametrize("mean_degree", [6, 80])
+def test_sampled_blocks_are_edges_of_the_host_csr_and_rows_of_the_host_table(mean_degree):
     topo = graph(1500, mean_degree, seed=6)
     mesh, feat, _, _, seeds = _problem(topo, 4)
-    assert resolve_topology_layout(None, topo.indptr) == layout
     ds, x, batch = _sampled(mesh, topo, feat, seeds, jax.random.key(3))
     assert batch == seeds.shape[0] and ds.n_id.shape[0] == 32 * 5 * 4
     np.testing.assert_array_equal(ds.n_id[:32], seeds)
@@ -213,28 +180,24 @@ def test_sampled_blocks_are_edges_of_the_host_csr_and_rows_of_the_host_table(
 
 
 @pytest.mark.parametrize("mean_degree", [6, 80])
-def test_both_layouts_and_one_device_draw_the_same_from_the_same_key(mean_degree):
+def test_four_shards_and_one_device_draw_the_same_from_the_same_key(mean_degree):
     topo = graph(1500, mean_degree, seed=7)
     mesh, feat, _, _, seeds = _problem(topo, 4)
     key = jax.random.key(11)
-    got = {layout: _sampled(mesh, topo, feat, seeds, key, layout)
-           for layout in (None, "flat", "tiled")}
-    got["one device"] = _sampled(make_mesh(1), topo, feat, seeds, key)
-    want_ds, want_x, _ = got[None]
-    for label, (ds, x, _) in got.items():
-        np.testing.assert_array_equal(ds.n_id, want_ds.n_id, err_msg=str(label))
-        np.testing.assert_array_equal(x, want_x, err_msg=str(label))
-        for a, b in zip(ds.adjs, want_ds.adjs):
-            np.testing.assert_array_equal(a.mask, b.mask, err_msg=str(label))
+    ds, x, _ = _sampled(mesh, topo, feat, seeds, key)
+    want_ds, want_x, _ = _sampled(make_mesh(1), topo, feat, seeds, key)
+    np.testing.assert_array_equal(ds.n_id, want_ds.n_id)
+    np.testing.assert_array_equal(x, want_x)
+    for a, b in zip(ds.adjs, want_ds.adjs):
+        np.testing.assert_array_equal(a.mask, b.mask)
 
 
-def _train(mesh, topo, feat, labels, model, batches, keys, layout=None):
+def _train(mesh, topo, feat, labels, model, batches, keys):
     tx = optax.adam(1e-2)
-    step = make_sharded_topo_train_step(mesh, model, tx, SIZES, pipeline="fused",
-                                        layout=layout)
-    stopo, placed = shard_topology_rows(mesh, topo, layout=layout), shard_feature_rows(mesh, feat)
+    step = make_sharded_topo_train_step(mesh, model, tx, SIZES, pipeline="fused")
+    stopo, placed = shard_topology_rows(mesh, topo), shard_feature_rows(mesh, feat)
     x0 = jnp.zeros((batches[0].shape[0] * 5 * 4, feat.shape[1]), jnp.float32)
-    sample = make_sharded_topo_sample(mesh, SIZES, pipeline="fused", layout=layout)
+    sample = make_sharded_topo_sample(mesh, SIZES, pipeline="fused")
     ds0, _ = sample(keys[0], stopo, placed, batches[0])
     adjs0 = jax.tree_util.tree_map(lambda a: a[0], ds0.adjs)
     params = replicate(mesh, model.init(jax.random.key(9), x0, adjs0))
@@ -255,37 +218,43 @@ def test_four_shard_step_trains_as_the_one_device_step():
     keys = [jax.random.key(20 + i) for i in range(3)]
     four, params4 = _train(mesh, topo, feat, labels, model, batches, keys)
     one, params1 = _train(make_mesh(1), topo, feat, labels, model, batches, keys)
-    tiled, _ = _train(mesh, topo, feat, labels, model, batches, keys, layout="tiled")
     assert four[-1] < four[0]
     np.testing.assert_allclose(four, one, rtol=1e-5)
-    np.testing.assert_allclose(four, tiled, rtol=1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(params4), jax.tree_util.tree_leaves(params1)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
 
 
-def test_the_step_takes_whichever_layout_it_is_handed_and_a_named_one_only():
+def test_the_layout_keyword_takes_none_and_flat_and_refuses_the_rest():
+    """ROADMAP D13: the benchmark still passes ``layout=None`` to all three."""
     topo = graph(600, 6, seed=9)
     mesh, feat, labels, model, seeds = _problem(topo, 4)
     tx = optax.adam(1e-2)
-    step = make_sharded_topo_train_step(mesh, model, tx, SIZES, pipeline="fused")
-    tiled_only = make_sharded_topo_train_step(mesh, model, tx, SIZES, pipeline="fused",
-                                              layout="tiled")
     placed = shard_feature_rows(mesh, feat)
     x0 = jnp.zeros((32 * 5 * 4, feat.shape[1]), jnp.float32)
-    ds0, _ = make_sharded_topo_sample(mesh, SIZES, pipeline="fused")(
-        jax.random.key(0), shard_topology_rows(mesh, topo), placed, seeds)
-    params = replicate(mesh, model.init(
-        jax.random.key(9), x0, jax.tree_util.tree_map(lambda a: a[0], ds0.adjs)))
-    args = (params, replicate(mesh, tx.init(params)), jax.random.key(1))
-    tail = (placed, replicate(mesh, labels), seeds)
-    losses = [float(step(*args, shard_topology_rows(mesh, topo, layout=l), *tail)[2])
-              for l in ("flat", "tiled")]
-    assert losses[0] == pytest.approx(losses[1], rel=1e-6)
-    assert step._cache_size() == 2  # one jitted step, traced once per stopo type
-    assert "module @jit_sharded_topo_train_step " in step.lower(
-        *args, shard_topology_rows(mesh, topo), *tail).as_text()
-    with pytest.raises(ValueError, match="layout='tiled' but stopo is a ShardedTopology"):
-        tiled_only(*args, shard_topology_rows(mesh, topo, layout="flat"), *tail)
+    losses = []
+    for layout in (None, "flat"):
+        stopo = shard_topology_rows(mesh, topo, layout=layout)
+        assert isinstance(stopo, ShardedTopology)
+        ds0, _ = make_sharded_topo_sample(mesh, SIZES, pipeline="fused", layout=layout)(
+            jax.random.key(0), stopo, placed, seeds)
+        params = replicate(mesh, model.init(
+            jax.random.key(9), x0, jax.tree_util.tree_map(lambda a: a[0], ds0.adjs)))
+        step = make_sharded_topo_train_step(mesh, model, tx, SIZES, pipeline="fused",
+                                            layout=layout)
+        args = (params, replicate(mesh, tx.init(params)), jax.random.key(1), stopo,
+                placed, replicate(mesh, labels), seeds)
+        losses.append(float(step(*args)[2]))
+        assert "module @jit_sharded_topo_train_step " in step.lower(*args).as_text()
+    assert losses[0] == losses[1]
+    for layout in ("tiled", "coo"):
+        for build in (
+            lambda: shard_topology_rows(mesh, topo, layout=layout),
+            lambda: make_sharded_topo_sample(mesh, SIZES, pipeline="fused", layout=layout),
+            lambda: make_sharded_topo_train_step(mesh, model, tx, SIZES, pipeline="fused",
+                                                 layout=layout),
+        ):
+            with pytest.raises(ValueError, match="unsupported topology layout"):
+                build()
 
 
 def test_placement_spans_and_the_step_counter_record_while_tracing(monkeypatch):
@@ -309,3 +278,20 @@ def test_placement_spans_and_the_step_counter_record_while_tracing(monkeypatch):
     # the rows' all-reduces are most of it: 2 x 3/4 x rows x row bytes
     rows = 32 * 5 * 4
     assert total > 2 * 0.75 * rows * 12 * 4
+
+
+def test_the_four_chip_cells_comm_bytes_and_the_models_keys():
+    """What the ledger's ``comm_bytes_per_step`` reads in
+    papers100M-sage.train-sharded4: the cell's shapes, to the byte."""
+    mesh = make_mesh(4, dp=1)
+    assert step_comm_bytes(mesh, (15, 10, 5), 1024, 128) == 843_436_032.0
+    model = sampling_comm_bytes(mesh, (15, 10, 5), 1024, feature_dim=128)
+    assert set(model) == {"ici_bytes", "dcn_bytes", "total_bytes"}
+    assert model["dcn_bytes"] == 0.0 and model["total_bytes"] == 843_436_032.0
+
+
+def test_every_exported_name_of_parallel_is_there():
+    import quiver_tpu.parallel as parallel
+
+    assert len(set(parallel.__all__)) == len(parallel.__all__)
+    assert [n for n in parallel.__all__ if not hasattr(parallel, n)] == []

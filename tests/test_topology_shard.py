@@ -19,8 +19,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from quiver_tpu.models import GraphSAGE
 from quiver_tpu.ops.sample import LANE, build_tiled_host, sample_layer, tiled_sample_layer
 from quiver_tpu.parallel import (
-    TiledShardedTopology,
-    build_tiled_topology_shards,
     make_mesh,
     make_sharded_topo_train_step,
     mesh_axes,
@@ -30,8 +28,6 @@ from quiver_tpu.parallel import (
     shard_topology_rows,
     sharded_sample_layer,
     sharded_sample_layer_grouped,
-    tiled_sharded_sample_layer,
-    tiled_sharded_sample_layer_grouped,
 )
 from quiver_tpu.parallel.topology import build_topology_shards, partition_rows_by_edges
 from quiver_tpu.utils import CSRTopo, shard_map_compat
@@ -228,79 +224,27 @@ def test_sampling_comm_bytes_model():
     assert m3b["total_bytes"] < m3["total_bytes"]
 
 
-def test_sampling_comm_bytes_layout_rows():
-    # collective bytes are layout-INVARIANT (identical [W, k] return trip);
-    # the tile layout only reshapes the local HBM fetch: same descriptor
-    # count, 128x the fetched bytes per position descriptor
-    from quiver_tpu.ops.sample import LANE as lane
-
-    for mesh in (make_mesh(8), make_mesh(8, hosts=2)):
-        flat = sampling_comm_bytes(
-            mesh, (4, 4), batch_per_group=16, feature_dim=32, layout="flat"
-        )
-        tiled = sampling_comm_bytes(
-            mesh, (4, 4), batch_per_group=16, feature_dim=32, layout="tiled"
-        )
-        for key in ("ici_bytes", "dcn_bytes", "total_bytes"):
-            assert flat[key] == tiled[key], key
-        assert flat["hbm_descriptors"] == tiled["hbm_descriptors"]
-        assert tiled["hbm_fetch_bytes"] > flat["hbm_fetch_bytes"]
-        # position fetches dominate: the ratio approaches LANE from below
-        assert tiled["hbm_fetch_bytes"] < flat["hbm_fetch_bytes"] * lane
-    with pytest.raises(ValueError):
-        sampling_comm_bytes(make_mesh(8), (4,), 16, layout="bogus")
-
-
 # ---------------------------------------------------------------------------
-# TILED shard layout (round 6): the 128-lane tile layout per shard block.
 # The contract under test: same PRNG key -> same neighbor ids and valid mask
-# as BOTH the flat sharded path and the single-chip samplers, on every mesh
-# shape — the draw is layout-invariant, only the HBM fetch shape changes.
+# as the single-chip samplers (flat CSR and 128-lane tile table), on every
+# mesh shape, for lists shorter and longer than a 128-lane row.
 # ---------------------------------------------------------------------------
 
 
-def _graph_with_isolated_rows(n=500, seed=0):
+def _graph_with_isolated_rows(n=500, seed=0, mean_degree=12):
     """Power-law graph plus 5 guaranteed degree-0 tail nodes (num_nodes
     overhang), so frontier rows with no neighbors are always exercised."""
     from quiver_tpu.datasets import synthetic_powerlaw
 
-    edge_index, _, _, _ = synthetic_powerlaw(n - 5, (n - 5) * 12, seed=seed)
+    edge_index, _, _, _ = synthetic_powerlaw(n - 5, (n - 5) * mean_degree, seed=seed)
     return CSRTopo(edge_index=edge_index, num_nodes=n)
 
 
-def test_tiled_build_matches_flat_blocks():
-    # per shard and per local row, the tile table must hold exactly the
-    # edges of the flat block, in the same order
-    topo = _graph_with_isolated_rows()
-    indptr, indices = np.asarray(topo.indptr), np.asarray(topo.indices)
-    for shards in (1, 3, 4):
-        bd_b, tiles_b, rs = build_tiled_topology_shards(indptr, indices, shards)
-        _, _, rs_flat = build_topology_shards(indptr, indices, shards)
-        np.testing.assert_array_equal(rs, rs_flat)  # same edge-balanced split
-        assert tiles_b.shape[2] == LANE
-        for p in range(shards):
-            lo, hi = int(rs[p]), int(rs[p + 1])
-            for r in range(hi - lo):
-                base, deg = int(bd_b[p, r, 0]), int(bd_b[p, r, 1])
-                want = indices[indptr[lo + r] : indptr[lo + r + 1]]
-                assert deg == want.shape[0]
-                got = tiles_b[p].reshape(-1)[base * LANE : base * LANE + deg]
-                np.testing.assert_array_equal(got, want)
-            # padding rows past the shard's range read as degree 0
-            assert np.all(bd_b[p, hi - lo :, 1] == 0)
-
-
 def _run_sharded_sample(mesh, stopo, cur, valid_in, k, key):
-    """One collective draw through shard_map, either shard layout."""
+    """One collective draw through shard_map."""
     _, feat_axes, _ = mesh_axes(mesh)
-    tiled = isinstance(stopo, TiledShardedTopology)
 
     def f(stopo, cur, valid_in):
-        if tiled:
-            return tiled_sharded_sample_layer(
-                stopo.bd[0], stopo.tiles[0], stopo.row_start,
-                cur, valid_in, k, key, feat_axes,
-            )
         return sharded_sample_layer(
             stopo.indptr[0], stopo.indices[0], stopo.row_start,
             cur, valid_in, k, key, feat_axes,
@@ -316,11 +260,13 @@ def _run_sharded_sample(mesh, stopo, cur, valid_in, k, key):
     )(stopo, replicate(mesh, cur), replicate(mesh, valid_in))
 
 
+@pytest.mark.parametrize("mean_degree", [6, 80])
 @pytest.mark.parametrize("n_shards", [2, 4])
-def test_tiled_sharded_sample_parity(n_shards):
-    # tiled sharded == flat sharded == single-chip tiled == single-chip flat,
-    # on 2- and 4-shard meshes, with a degree-0 frontier row included
-    topo = _graph_with_isolated_rows()
+def test_sharded_sample_parity(n_shards, mean_degree):
+    # sharded == single-chip flat == single-chip tiled, on 2- and 4-shard
+    # meshes, with a degree-0 frontier row included; at mean degree 80 lists
+    # longer than 128 straddle lane rows of a shard's block
+    topo = _graph_with_isolated_rows(mean_degree=mean_degree)
     n = topo.indptr.shape[0] - 1
     mesh = make_mesh(n_shards, dp=1)
     indptr = jnp.asarray(np.asarray(topo.indptr), jnp.int32)
@@ -328,12 +274,15 @@ def test_tiled_sharded_sample_parity(n_shards):
     rng = np.random.default_rng(1)
     cur_np = rng.integers(0, n, 64)
     cur_np[:3] = [n - 1, n - 3, n - 5]  # guaranteed degree-0 rows
+    deg = np.diff(np.asarray(topo.indptr))
+    assert (deg[cur_np[:3]] == 0).all()
+    if mean_degree == 80:
+        cur_np[3:6] = np.argsort(deg)[-3:]
+        assert deg[cur_np[3:6]].min() > LANE
     cur = jnp.asarray(cur_np, jnp.int32)
     valid_in = jnp.asarray(rng.random(64) < 0.9)
     key = jax.random.key(11)
     k = 6
-    deg = np.diff(np.asarray(topo.indptr))
-    assert (deg[cur_np[:3]] == 0).all()
 
     ref_nbrs, ref_valid = sample_layer(indptr, indices, cur, valid_in, k, key)
     bd, tiles = build_tiled_host(
@@ -343,24 +292,21 @@ def test_tiled_sharded_sample_parity(n_shards):
         jnp.asarray(bd), jnp.asarray(tiles), cur, valid_in, k, key
     )
     flat_n, flat_v = _run_sharded_sample(
-        mesh, shard_topology_rows(mesh, topo, layout="flat"), cur, valid_in, k, key
-    )
-    tile_n, tile_v = _run_sharded_sample(
-        mesh, shard_topology_rows(mesh, topo, layout="tiled"), cur, valid_in, k, key
+        mesh, shard_topology_rows(mesh, topo), cur, valid_in, k, key
     )
 
     rv = np.asarray(ref_valid)
     assert not rv[:3].any()  # degree-0 frontier rows draw nothing
-    for got_v in (t1_valid, flat_v, tile_v):
+    for got_v in (t1_valid, flat_v):
         np.testing.assert_array_equal(np.asarray(got_v), rv)
     want = np.asarray(ref_nbrs)[rv]
-    for got_n in (t1_nbrs, flat_n, tile_n):
+    for got_n in (t1_nbrs, flat_n):
         np.testing.assert_array_equal(np.asarray(got_n)[rv], want)
 
 
-def test_tiled_sharded_empty_shard_range():
+def test_sharded_empty_shard_range():
     # one hub row owning ~90% of edges forces empty row ranges at 4 shards;
-    # both layouts must stay exact through them
+    # the draw must stay exact through them
     rng = np.random.default_rng(2)
     hub_dst = rng.integers(1, 40, 900)
     tail_src = rng.integers(1, 40, 100)
@@ -381,21 +327,19 @@ def test_tiled_sharded_empty_shard_range():
     key = jax.random.key(5)
     k = 4
     ref_nbrs, ref_valid = sample_layer(indptr, indices, cur, valid_in, k, key)
-    for layout in ("flat", "tiled"):
-        got_n, got_v = _run_sharded_sample(
-            mesh, shard_topology_rows(mesh, topo, layout=layout), cur, valid_in, k, key
-        )
-        rv = np.asarray(ref_valid)
-        np.testing.assert_array_equal(np.asarray(got_v), rv)
-        np.testing.assert_array_equal(np.asarray(got_n)[rv], np.asarray(ref_nbrs)[rv])
+    got_n, got_v = _run_sharded_sample(
+        mesh, shard_topology_rows(mesh, topo), cur, valid_in, k, key
+    )
+    rv = np.asarray(ref_valid)
+    np.testing.assert_array_equal(np.asarray(got_v), rv)
+    np.testing.assert_array_equal(np.asarray(got_n)[rv], np.asarray(ref_nbrs)[rv])
 
 
-@pytest.mark.parametrize("via", ["scatter", "psum"])
-def test_tiled_grouped_parity_both_vias(via):
-    # (host, dp, ici) mesh, hosts carry DISTINCT frontiers: grouped tiled ==
-    # grouped flat == single-chip draw on the host-concatenated frontier,
-    # under both return-trip spellings
-    topo = _graph_with_isolated_rows()
+@pytest.mark.parametrize("mean_degree", [6, 80])
+def test_grouped_sample_parity(mean_degree):
+    # (host, dp, ici) mesh, hosts carry DISTINCT frontiers: the grouped
+    # sampler == the single-chip draw on the host-concatenated frontier
+    topo = _graph_with_isolated_rows(mean_degree=mean_degree)
     n = topo.indptr.shape[0] - 1
     mesh = make_mesh(8, hosts=2)
     _, feat_axes, _ = mesh_axes(mesh)
@@ -413,127 +357,27 @@ def test_tiled_grouped_parity_both_vias(via):
         indptr, indices, jnp.asarray(all_cur_np, jnp.int32),
         jnp.asarray(all_valid_np), k, key,
     )
+    stopo = shard_topology_rows(mesh, topo)
 
-    outs = {}
-    for layout in ("flat", "tiled"):
-        stopo = shard_topology_rows(mesh, topo, layout=layout)
-        tiled = layout == "tiled"
-
-        def f(stopo, cur, valid_in):
-            args = (
-                (stopo.bd[0], stopo.tiles[0]) if tiled
-                else (stopo.indptr[0], stopo.indices[0])
-            )
-            fn = (
-                tiled_sharded_sample_layer_grouped if tiled
-                else sharded_sample_layer_grouped
-            )
-            return fn(
-                *args, stopo.row_start, cur, valid_in, k, key,
-                feat_axes, "host", via=via,
-            )
-
-        got_n, got_v = jax.jit(
-            shard_map_compat(
-                f, mesh=mesh,
-                in_specs=(stopo.specs(feat_axes), P(("host",)), P(("host",))),
-                out_specs=(P(("host",), None), P(("host",), None)),
-                check_vma=False,
-            )
-        )(
-            stopo,
-            jax.device_put(
-                jnp.asarray(all_cur_np, jnp.int32),
-                NamedSharding(mesh, P(("host",))),
-            ),
-            jax.device_put(
-                jnp.asarray(all_valid_np), NamedSharding(mesh, P(("host",)))
-            ),
+    def f(stopo, cur, valid_in):
+        return sharded_sample_layer_grouped(
+            stopo.indptr[0], stopo.indices[0], stopo.row_start, cur, valid_in,
+            k, key, feat_axes, "host",
         )
-        outs[layout] = (np.asarray(got_n), np.asarray(got_v))
 
+    by_host = NamedSharding(mesh, P(("host",)))
+    got_n, got_v = jax.jit(
+        shard_map_compat(
+            f, mesh=mesh,
+            in_specs=(stopo.specs(feat_axes), P(("host",)), P(("host",))),
+            out_specs=(P(("host",), None), P(("host",), None)),
+            check_vma=False,
+        )
+    )(
+        stopo,
+        jax.device_put(jnp.asarray(all_cur_np, jnp.int32), by_host),
+        jax.device_put(jnp.asarray(all_valid_np), by_host),
+    )
     rv = np.asarray(ref_valid)
-    for layout, (got_n, got_v) in outs.items():
-        np.testing.assert_array_equal(got_v, rv, err_msg=layout)
-        np.testing.assert_array_equal(
-            got_n[rv], np.asarray(ref_nbrs)[rv], err_msg=layout
-        )
-
-
-@pytest.mark.parametrize("pipeline", ["dedup", "fused"])
-def test_tiled_sharded_topo_train_step_learns(pipeline):
-    from quiver_tpu.pyg.sage_sampler import sample_dense_fused, sample_dense_pure
-
-    edge_index, feat_np, labels, n = make_community_graph(per_comm=40)
-    topo = CSRTopo(edge_index=edge_index)
-    mesh = make_mesh(8)
-    stopo = shard_topology_rows(mesh, topo, layout="tiled")
-    assert isinstance(stopo, TiledShardedTopology)
-    model = GraphSAGE(hidden_dim=16, out_dim=4, num_layers=2, dropout=0.0)
-    tx = optax.adam(1e-2)
-    step = make_sharded_topo_train_step(
-        mesh, model, tx, sizes=[4, 4], pipeline=pipeline, layout="tiled"
-    )
-
-    feat = shard_feature_rows(mesh, feat_np)
-    labels_d = replicate(mesh, labels.astype(np.int32))
-    dp = mesh.shape["dp"]
-    batch_global = 8 * dp
-    ip = jnp.asarray(topo.indptr.astype(np.int32))
-    ix = jnp.asarray(topo.indices.astype(np.int32))
-    seeds0 = jnp.arange(batch_global // dp, dtype=jnp.int32)
-    make0 = sample_dense_fused if pipeline == "fused" else sample_dense_pure
-    ds0 = make0(ip, ix, jax.random.key(0), seeds0, (4, 4))
-    x0 = jnp.zeros((ds0.n_id.shape[0], feat_np.shape[1]), jnp.float32)
-    params = replicate(mesh, model.init(jax.random.key(1), x0, ds0.adjs))
-    opt_state = jax.device_put(tx.init(params), NamedSharding(mesh, P()))
-
-    rng = np.random.default_rng(3)
-    losses = []
-    for i in range(30):
-        seeds = jax.device_put(
-            rng.choice(n, batch_global, replace=False).astype(np.int32),
-            NamedSharding(mesh, P("dp")),
-        )
-        params, opt_state, loss = step(
-            params, opt_state, jax.random.key(i), stopo, feat, labels_d, seeds
-        )
-        losses.append(float(loss))
-    assert losses[-1] < losses[0] * 0.7, losses
-
-
-def test_tiled_vs_flat_train_step_same_loss():
-    # layout changes the fetch path, not the math: one step from identical
-    # params/keys/seeds must produce the identical loss under both layouts
-    edge_index, feat_np, labels, n = make_community_graph(per_comm=40)
-    topo = CSRTopo(edge_index=edge_index)
-    mesh = make_mesh(8)
-    model = GraphSAGE(hidden_dim=16, out_dim=4, num_layers=2, dropout=0.0)
-    tx = optax.adam(1e-2)
-    feat = shard_feature_rows(mesh, feat_np)
-    labels_d = replicate(mesh, labels.astype(np.int32))
-    dp = mesh.shape["dp"]
-    from quiver_tpu.pyg.sage_sampler import sample_dense_fused
-
-    ip = jnp.asarray(topo.indptr.astype(np.int32))
-    ix = jnp.asarray(topo.indices.astype(np.int32))
-    seeds0 = jnp.arange(8, dtype=jnp.int32)
-    ds0 = sample_dense_fused(ip, ix, jax.random.key(0), seeds0, (4, 4))
-    x0 = jnp.zeros((ds0.n_id.shape[0], feat_np.shape[1]), jnp.float32)
-    params0 = model.init(jax.random.key(1), x0, ds0.adjs)
-    seeds = jax.device_put(
-        np.arange(8 * dp, dtype=np.int32), NamedSharding(mesh, P("dp"))
-    )
-    losses = {}
-    for layout in ("flat", "tiled"):
-        stopo = shard_topology_rows(mesh, topo, layout=layout)
-        step = make_sharded_topo_train_step(
-            mesh, model, tx, sizes=[4, 4], pipeline="fused", layout=layout
-        )
-        params = replicate(mesh, params0)
-        opt_state = jax.device_put(tx.init(params0), NamedSharding(mesh, P()))
-        _, _, loss = step(
-            params, opt_state, jax.random.key(2), stopo, feat, labels_d, seeds
-        )
-        losses[layout] = float(loss)
-    assert losses["flat"] == losses["tiled"], losses
+    np.testing.assert_array_equal(np.asarray(got_v), rv)
+    np.testing.assert_array_equal(np.asarray(got_n)[rv], np.asarray(ref_nbrs)[rv])

@@ -9,12 +9,12 @@ main path, at the shapes `chip_smoke.py` runs, are lowered for a described
   (b) dedup train step      (the same over the capped dedup sampler)
   (c) one sealed serve bucket from `inference.make_serve_step`, seed buffer
       donated as `BucketPrograms` donates it
-  (d) `make_sharded_topo_train_step(layout="tiled")` on the four described
-      devices (the layout is passed: `default_backend()` says cpu here)
+  (d) `make_sharded_topo_train_step` on the four described devices, at the
+      products shape (the shape PR 28 measured on four chips)
   (e) the two programs of `Feature.lookup_padded`, at the shapes of the
       benchmark's train cells: the row gather and nothing beside it
-  (f) `make_sharded_topo_train_step` over the FLAT layout at the shard sizes
-      of the benchmark's four-chip cell (half of ogbn-papers100M: 7.1 GB of
+  (f) `make_sharded_topo_train_step` at the shard sizes of the benchmark's
+      four-chip cell (half of ogbn-papers100M: 7.1 GB of
       feature rows and 0.86 GB of graph a chip): eleven seconds, where
       one-element gathers from a 1-D edge array of that size took minutes
   (g) the jitted optax step over `GraphSAGE` at the shapes of the benchmark's
@@ -50,7 +50,7 @@ from quiver_tpu.feature import _padded_gather, _padded_gather_ordered
 from quiver_tpu.inference import make_serve_step
 from quiver_tpu.ops.sample import LANE, tiled_sample_layer
 from quiver_tpu.parallel import make_sharded_topo_train_step, make_sharded_train_step
-from quiver_tpu.parallel.topology import ShardedTopology, TiledShardedTopology
+from quiver_tpu.parallel.topology import ShardedTopology
 from quiver_tpu.pyg.sage_sampler import sample_dense_fused, sample_dense_pure
 
 HBM_BYTES = 16e9                      # one v5e chip
@@ -58,8 +58,8 @@ N = PRODUCTS["nodes"]
 TILE_ROWS = 2_833_089                 # [M, 128] tile table of the seed-0 graph
 DEDUP_CAPS = (16384, 151552, 600064)  # at batch 1024, margin 1.2
 SERVE_BUCKET = 64
-# per-shard row / tile-row counts of the four-way edge-balanced split, with slack
-SHARD_ROWS, SHARD_TILE_ROWS = 700_000, 760_000
+# per-shard rows of the four-way edge-balanced split, with slack
+SHARD_ROWS = 700_000
 
 
 def _struct(tree, sharding):
@@ -167,23 +167,25 @@ def compile_serve_bucket(v5e, bucket=SERVE_BUCKET):
 def compile_sharded_topo_step(v5e, n_devices=4, batch=PRODUCTS["batch"]):
     """n_devices=1 is the one-device twin `chip_smoke.py --chips 4` compares
     the sharded steps against (script only)."""
+    from quiver_tpu.parallel.topology import _padded
+
     model, tx = make_model(PRODUCTS["classes"], dropout=0.0), optax.adam(3e-3)
     params, opt_state, key = _model_structs(model, tx)
     mesh = Mesh(np.array(v5e.devices[:n_devices]).reshape(1, n_devices), ("dp", "ici"))
     rep = NamedSharding(mesh, P())
     rows = NamedSharding(mesh, P("ici", None))
-    blocks = NamedSharding(mesh, P("ici", None, None))
-    shard_rows, shard_tiles = (
-        (SHARD_ROWS, SHARD_TILE_ROWS) if n_devices == 4 else (N, TILE_ROWS))
-    stopo = TiledShardedTopology(
-        bd=jax.ShapeDtypeStruct((n_devices, shard_rows, 2), jnp.int32, sharding=blocks),
-        tiles=jax.ShapeDtypeStruct((n_devices, shard_tiles, LANE), jnp.int32,
-                                   sharding=blocks),
+    # blocks of whole (8, 128) tiles, as `shard_topology_rows` cuts them
+    shard_rows = SHARD_ROWS if n_devices == 4 else N
+    stopo = ShardedTopology(
+        indptr=jax.ShapeDtypeStruct(
+            (n_devices, _padded(shard_rows + 1, 8 * LANE)), jnp.int32, sharding=rows),
+        indices=jax.ShapeDtypeStruct(
+            (n_devices, _padded(PRODUCTS["edges"] // n_devices, 8 * LANE)), jnp.int32,
+            sharding=rows),
         row_start=jax.ShapeDtypeStruct((n_devices + 1,), jnp.int32, sharding=rep),
     )
     n_pad = -(-N // n_devices) * n_devices
-    step = make_sharded_topo_train_step(
-        mesh, model, tx, SIZES, pipeline="fused", layout="tiled")
+    step = make_sharded_topo_train_step(mesh, model, tx, SIZES, pipeline="fused")
     compiled = step.lower(
         *_struct((params, opt_state, key), rep), stopo,
         jax.ShapeDtypeStruct((n_pad, PRODUCTS["dim"]), jnp.float32, sharding=rows),
@@ -194,7 +196,7 @@ def compile_sharded_topo_step(v5e, n_devices=4, batch=PRODUCTS["batch"]):
     if n_devices > 1:
         assert "all-reduce" in compiled.as_text(), (
             "the sharded step compiled without a collective")
-    return _fits(compiled, f"tiled sharded-topology step on {n_devices} device(s)")
+    return _fits(compiled, f"sharded-topology step on {n_devices} device(s)")
 
 
 # papers100M-sage.train-sharded4 (qbench/configs/papers100M-sage.json): nodes,
@@ -204,9 +206,8 @@ PAPERS = dict(nodes=55_529_978, edges=807_842_936, dim=128, classes=172,
 
 
 def compile_flat_sharded_topo_step_at_papers_size(v5e, batch=1024):
-    """What the four-chip cell runs, for four described chips: the layout is
-    the one `shard_topology_rows` resolves for that graph, handed over as
-    shapes (``layout=None`` takes whichever the `stopo` is)."""
+    """What the four-chip cell runs, for four described chips: the blocks
+    `shard_topology_rows` places for that graph, handed over as shapes."""
     from quiver_tpu.models import GraphSAGE
 
     model = GraphSAGE(hidden_dim=256, out_dim=PAPERS["classes"], num_layers=3, dropout=0.0)
@@ -356,7 +357,7 @@ def test_serve_bucket_compiles_for_v5e(v5e):
     compile_serve_bucket(v5e, 8)
 
 
-def test_tiled_sharded_topo_step_compiles_for_four_v5e(v5e):
+def test_sharded_topo_step_compiles_for_four_v5e_at_products_size(v5e):
     compile_sharded_topo_step(v5e)
 
 
@@ -436,9 +437,9 @@ if __name__ == "__main__":
          lambda: compile_train_step(desc, DEDUP_CAPS, PRODUCTS["batch"])),
         ("serve bucket 64", lambda: compile_serve_bucket(desc)),
         ("serve bucket 1", lambda: compile_serve_bucket(desc, 1)),
-        ("tiled sharded-topology step, 4 devices",
+        ("sharded-topology step at the products size, 4 devices",
          lambda: compile_sharded_topo_step(desc)),
-        ("tiled sharded-topology step, 1 device (the twin)",
+        ("sharded-topology step at the products size, 1 device (the twin)",
          lambda: compile_sharded_topo_step(desc, 1)),
         ("sharded-feature step, 4 devices",
          lambda: compile_sharded_feature_step(desc, 4)),
