@@ -16,7 +16,12 @@ papers100M.npz from scripts/export_ogb.py):
   native engine samples (HOST mode = the UVA analog, SURVEY.md section
   7.3); features run the tiered hot-HBM/cold-host(/mmap-disk) prefetch
   pipeline (`TrainPipeline`), so neither graph nor features need to fit
-  HBM.
+  HBM. The benchmark's cell ``papers100M-sage-tiered.train-hot6g`` (PR 32,
+  ``qbench/TIERED.md``) measures the same tiered `Feature` +
+  `TrainPipeline` + `make_tiered_train_step` with the sampler on the
+  DEVICE (``GraphSageSampler(mode="TPU", layout="flat")``: the flat graph
+  of half of ogbn-papers100M is 3.45 GB of HBM); ``mode="HOST"`` sampling,
+  which this script's host layout runs, is the other, unmeasured way.
 
 Run hermetically: QUIVER_VIRTUAL_DEVICES=8 python benchmarks/papers100M_workflow.py
 """

@@ -25,24 +25,27 @@ tier holds whole is stored in the caller's row order: ``feature_order`` stays
 
 from __future__ import annotations
 
+import collections
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Union
+from typing import Dict, List, NamedTuple, Optional, Sequence, Union
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-from .ops import gather_sum
+from .ops import cpu_kernels, gather_sum
 from .shard_tensor import (
     CPU_DEVICE,
+    HostRows,
     ShardTensor,
     ShardTensorConfig,
     _device_of,
+    host_gather,
     normalize_dtype,
 )
-from .trace import trace_scope
-from .utils import CSRTopo, IciTopo, parse_size, reindex_feature
+from .trace import observe, trace_scope
+from .utils import CSRTopo, IciTopo, degree_order, parse_size, reindex_feature
 
 
 @dataclass
@@ -155,6 +158,38 @@ def _padded_gather_ordered(table: jax.Array, order: jax.Array, ids: jax.Array) -
     return jnp.take(table, jnp.take(order, ids, mode="clip"), axis=0, mode="clip")
 
 
+class TieredStage(NamedTuple):
+    """The host half of a tiered lookup (`Feature.stage_tiered`), awaiting
+    its copy to the device and `tiered_gather`."""
+
+    mapped: np.ndarray     # [W] int32 row of the view [hot table; cold block]; -1: a zero row
+    cold_rows: np.ndarray  # [C, D] the cold block; rows past ``n_cold`` are stale
+    n_cold: int            # valid rows of the block
+
+
+def tiered_gather(hot_table: jax.Array, mapped: jax.Array, cold_rows: jax.Array) -> jax.Array:
+    """The device half of a tiered lookup, jit-safe: row ``mapped[i]`` of
+    the view ``[hot_table; cold_rows]`` without the concatenation (two row
+    gathers and a select; a scatter of the cold block into the hot gather
+    costs more, PERF.md section 6, PR 32), a zero row where ``mapped`` is
+    negative. No shape depends on how many rows of the block are valid."""
+    hot_n = hot_table.shape[0]
+    if hot_n == 0:  # nothing hot: the block is the table's answer
+        x = jnp.take(cold_rows, mapped, axis=0, mode="clip")
+    else:
+        x = jnp.take(hot_table, mapped, axis=0, mode="clip")
+        if cold_rows.shape[0]:
+            cold = jnp.take(cold_rows, mapped - hot_n, axis=0, mode="clip")
+            x = jnp.where((mapped >= hot_n)[:, None], cold, x)
+    return jnp.where((mapped >= 0)[:, None], x, jnp.zeros((), x.dtype))
+
+
+@jax.jit
+def _padded_gather_tiered(hot_table: jax.Array, mapped: jax.Array,
+                          cold_rows: jax.Array) -> jax.Array:
+    return tiered_gather(hot_table, mapped, cold_rows)
+
+
 class Feature:
     """Tiered [N, D] float feature store (reference feature.py:17).
 
@@ -244,6 +279,12 @@ class Feature:
         self.read_pool = read_pool
         self.tier_store = None  # tiers.TierStore when adaptive
         self._inv_order: Optional[np.ndarray] = None
+        # width of a tiered lookup's cold block, in rows (`calibrate_cold_cap`,
+        # or set it as `GraphSageSampler.caps` is set); None: the width of
+        # the lookup itself, which every batch fits
+        self.cold_cap: Optional[int] = None
+        self.cold_overflow = 0  # tiered lookups whose cold rows passed cold_cap
+        self._free_blocks: collections.deque = collections.deque()
         # observe-only workload tap (round 13): when a tier-aware
         # HitRateCounter is attached, every eager gather attributes its
         # rows per tier (attribute_gather_tiers) — placement telemetry,
@@ -281,6 +322,7 @@ class Feature:
             # aggregation kernel's, its imports start beside the upload
             gather_sum.prefetch_pallas(self.dtype, self._dim)
 
+        prev_order = None  # stored row -> caller's row, when reordered
         if (self.csr_topo is not None and not self._local_order_applied
                 and not wholly_hot):
             # degree-descending reorder so the cache prefix is hot
@@ -288,7 +330,10 @@ class Feature:
             # prefix to choose: the reorder would only permute the table and
             # make each lookup gather through `feature_order` to undo it.
             ratio = hot_total / max(self._n, 1)
-            arr, order = reindex_feature(self.csr_topo, arr, ratio)
+            if self.disk_path is not None:
+                arr, order = reindex_feature(self.csr_topo, arr, ratio)
+            else:
+                prev_order, order = degree_order(self.csr_topo, ratio)
             self.feature_order = order
             self.csr_topo.feature_order = order
             self._inv_order = None
@@ -298,15 +343,29 @@ class Feature:
             return
 
         st = ShardTensor(self.rank, ShardTensorConfig({}), dtype=self.dtype)
-        if self.cache_policy == "device_replicate":
+        if not wholly_hot:
+            # a table with a host tier: the stored order is never made on
+            # the host (it would be a second table). Each device shard goes
+            # up in pieces gathered from the caller's array, and the host
+            # tier is that array, read through the permutation
+            rows_of = (lambda lo, hi: slice(lo, hi)) if prev_order is None else (
+                lambda lo, hi: prev_order[lo:hi])
+            per = cache_rows if self.cache_policy == "device_replicate" else (
+                hot_total // max(len(clique), 1))
+            cursor = 0
+            for dev in clique:
+                rows = min(per, hot_total - cursor)
+                if rows <= 0:
+                    break
+                st.append_rows(arr, rows_of(cursor, cursor + rows), dev)
+                cursor += rows
+            st.append_rows(arr, rows_of(cursor, self._n), CPU_DEVICE)
+        elif self.cache_policy == "device_replicate":
             # hot prefix replicated per chip: each rank's Feature handle is
             # built with its own `rank` and stores its own replica, so this
             # handle's shard book holds one device shard + the shared host
             # tail (reference feature.py:219-223,268-274)
-            if cache_rows > 0:
-                st.append(arr[:cache_rows], self.rank)
-            if cache_rows < self._n:
-                st.append(arr[cache_rows:], CPU_DEVICE)
+            st.append(arr, self.rank)
         else:
             # hot set striped across the ICI clique (reference feature.py:225-265)
             per = hot_total // max(len(clique), 1)
@@ -317,7 +376,7 @@ class Feature:
                     break
                 st.append(arr[cursor : cursor + rows], dev)
                 cursor += rows
-            if cursor < self._n:
+            if cursor < self._n:  # what the stripes' equal shares left over
                 st.append(arr[cursor:], CPU_DEVICE)
         self.shard_tensor = st
 
@@ -540,25 +599,161 @@ class Feature:
             out[mem_mask] = np.asarray(self.shard_tensor[disk_index[mem_mask]])
         return jnp.asarray(out)
 
-    def lookup_padded(self, node_idx: jax.Array, valid: Optional[jax.Array] = None) -> jax.Array:
+    # ---------------------------------------------------- tiered padded lookup
+    def tiered_tables(self):
+        """``(hot_table, hot_rows, host_shard)`` of a table this chip's HBM
+        and the host's DRAM hold between them (one device shard at most, a
+        host shard, no disk tier, a static shard book): what the tiered
+        padded lookup serves. Anything else raises."""
+        st = self.shard_tensor
+        if (st is None or st.cpu_tensor is None or st.disk_shard is not None
+                or len(st.device_shards) > 1 or self._local_order_applied
+                or any(dev != self.rank for dev, _, _ in st.device_shards)):
+            raise ValueError(
+                "the tiered padded lookup serves one hot shard on this chip over "
+                "a host tail; use __getitem__ or the mesh-sharded gather"
+            )
+        if not st.device_shards:
+            return jnp.zeros((0, self.dim), self.dtype, device=_device_of(self.rank)), 0, st.cpu_tensor
+        _, table, off = st.device_shards[0]
+        return table, off.end - off.start, st.cpu_tensor
+
+    def stage_tiered(self, node_idx, count: Optional[int] = None,
+                     clip: bool = True) -> TieredStage:
+        """The HOST half of the tiered padded lookup, no device call: remap
+        through ``feature_order``, split hot from cold at the cache
+        boundary, gather the cold rows (native, threaded) into a block of
+        ``cold_cap`` rows. Lanes from ``count`` on (a dedup sample's padding
+        tail) ask for nothing. Out-of-range ids are clipped into the table
+        as `lookup_padded` clips them, or with ``clip=False`` answered with
+        zero rows as the eager lookups answer them.
+
+        A batch with more cold rows than ``cold_cap`` is counted
+        (``quiver.feature.cold_overflow``) and answered all the same, in a
+        block of the next multiple of ``cold_cap`` rows: a shape the device
+        half has not seen, so a program is built for it. The block comes
+        from a free list; `release_block` hands it back once copied."""
+        _, hot_rows, host = self.tiered_tables()
+        order = self.feature_order
+        with trace_scope("quiver.feature.lookup", ordered=int(order is not None)) as span:
+            ids = np.asarray(node_idx).reshape(-1)
+            width = ids.shape[0]
+            live = width if count is None else min(int(count), width)
+            ids = ids[:live]
+            mapped = np.full(width, -1, np.int32)
+            if clip:
+                ids, stored = self._clipped_stored(ids)
+            else:
+                ids = ids.astype(np.int64)
+                bad = (ids < 0) | (ids >= self._n)
+                ids[bad] = 0
+                stored = np.where(bad, -1, ids if order is None else order[ids])
+            mapped[:live] = stored
+            (cold_at,) = np.nonzero(stored >= hot_rows)
+            n_cold = int(cold_at.shape[0])
+            mapped[cold_at] = hot_rows + np.arange(n_cold, dtype=np.int32)
+            cap = width if self.cold_cap is None else int(self.cold_cap)
+            if n_cold > cap:
+                self.cold_overflow += 1
+                observe("quiver.feature.cold_overflow", n_cold - cap)
+                cap *= -(-n_cold // cap)
+            span.set(cold=n_cold)
+            block = self._take_block(cap)
+            with trace_scope("quiver.feature.cold_gather"):
+                if isinstance(host, HostRows):
+                    # the caller's own rows: no detour through the stored order
+                    cpu_kernels.gather_rows(host.base, ids[cold_at], out=block)
+                else:
+                    host_gather(host, stored[cold_at] - hot_rows, out=block)
+            observe("quiver.feature.cold_rows", n_cold)
+        return TieredStage(mapped, block, n_cold)
+
+    def _clipped_stored(self, ids):
+        """``(ids, stored rows)`` of lookup ids clipped into the table, as
+        the padded gathers clip them."""
+        ids = np.clip(np.asarray(ids).reshape(-1), 0, self._n - 1).astype(np.int64)
+        return ids, ids if self.feature_order is None else self.feature_order[ids]
+
+    def _take_block(self, rows: int) -> np.ndarray:
+        while self._free_blocks:
+            block = self._free_blocks.pop()
+            if block.shape[0] == rows:
+                return block
+        return np.zeros((rows, self.dim), self.dtype)
+
+    def release_block(self, stage: TieredStage) -> None:
+        """``stage``'s cold block may be written again: its copy is on the
+        device (`jax.device_put` reads the host's memory until then)."""
+        self._free_blocks.append(stage.cold_rows)
+
+    def calibrate_cold_cap(self, probe_ids, counts=None, margin: float = 1.2,
+                           granule: int = 4096, set_cap: bool = True) -> int:
+        """Probe-batch calibration of ``cold_cap``, as
+        `GraphSageSampler.calibrate_caps` calibrates the sampler's caps:
+        the most cold rows any probe batch asks for (``probe_ids``: the
+        ``n_id`` of >= 8 representative samples, ``counts`` their valid
+        lengths), times ``margin``, rounded up to ``granule``."""
+        _, hot_rows, _ = self.tiered_tables()
+        worst = 0
+        for i, ids in enumerate(probe_ids):
+            ids = np.asarray(ids).reshape(-1)
+            _, stored = self._clipped_stored(ids if counts is None else ids[: int(counts[i])])
+            worst = max(worst, int((stored >= hot_rows).sum()))
+        cap = int(-(-worst * margin // granule) * granule)
+        if set_cap:
+            self.cold_cap = cap
+        return cap
+
+    def upload_tiered(self, stage: TieredStage):
+        """``(mapped, cold_rows)`` of ``stage`` on this chip. The span
+        ``quiver.feature.h2d`` runs from the first copy's start until both
+        arrays are READY: a wait, so that the span is the link's time and
+        the block can go back to the free list. On `TrainPipeline`'s upload
+        thread it delays nothing."""
+        device = _device_of(self.rank)
+        with trace_scope("quiver.feature.h2d", bytes=int(stage.cold_rows.nbytes)):
+            placed = jax.block_until_ready((jax.device_put(stage.mapped, device),
+                                            jax.device_put(stage.cold_rows, device)))
+        if device.platform != "cpu":  # the CPU backend may alias the host's block
+            self.release_block(stage)
+        return placed
+
+    def _lookup_padded_tiered(self, node_idx, valid, count) -> jax.Array:
+        hot, _, _ = self.tiered_tables()
+        mapped, cold = self.upload_tiered(self.stage_tiered(node_idx, count))
+        rows = _padded_gather_tiered(hot, mapped, cold)
+        if valid is not None:
+            rows = rows * valid[:, None].astype(rows.dtype)
+        return rows
+
+    def lookup_padded(self, node_idx: jax.Array, valid: Optional[jax.Array] = None,
+                      count: Optional[int] = None) -> jax.Array:
         """Jit-friendly gather for padded id arrays; already jitted
         internally (the table is passed as an ARGUMENT to the jitted
         program — never ``jax.jit`` a bound method of this class, or the
         table becomes a baked-in compile-time constant).
 
-        Requires the feature to be fully device-resident (single hot shard on
-        this chip covering all rows); multi-tier padded lookup goes through
+        A table this chip's HBM holds whole is one gather program. A table
+        with a host tier (one hot shard here, the rest in host DRAM) is the
+        synchronous composition of the tiered lookup's two halves:
+        `stage_tiered` on the host (it reads ``node_idx`` back), the copy
+        (`upload_tiered`) and `tiered_gather` on the chip as the program
+        `_padded_gather_tiered`; `pipeline.TrainPipeline` runs the same
+        halves on its threads. ``count`` (tiered tables only) is the length
+        of the valid prefix: lanes past it fetch nothing and read zero.
+        Striped and disk-backed tables go through ``__getitem__`` or
         `quiver_tpu.parallel.collectives.sharded_gather` on a mesh.
 
         Out-of-range ids are CLIPPED into the table (negative -> id 0,
         ``>= N`` -> id N-1), never filled: see `validate_lookup_ids`. The
         program is `_padded_gather`, or `_padded_gather_ordered` when a
-        ``feature_order`` (degree reorder of a tiered table,
-        `set_local_order`) stands between ids and stored rows; the span
-        carries which as ``ordered``.
+        ``feature_order`` (`set_local_order`) stands between ids and stored
+        rows; the span carries which as ``ordered``.
         """
         st = self.shard_tensor
-        if st is None or st.cpu_tensor is not None or len(st.device_shards) != 1:
+        if st is not None and st.cpu_tensor is not None:
+            return self._lookup_padded_tiered(node_idx, valid, count)
+        if st is None or len(st.device_shards) != 1:
             raise ValueError(
                 "lookup_padded needs a fully HBM-resident feature; "
                 "use __getitem__ (tiered) or the mesh-sharded gather"

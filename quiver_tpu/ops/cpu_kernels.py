@@ -296,8 +296,12 @@ class HostSampler:
         return gather_rows(table, ids)
 
 
-def gather_rows(table: np.ndarray, ids: np.ndarray) -> np.ndarray:
+def gather_rows(table: np.ndarray, ids: np.ndarray, out: np.ndarray = None) -> np.ndarray:
     """Module-level host gather using the native lib when possible.
+    ``out`` (C-contiguous, the table's dtype and width, at least
+    ``len(ids)`` rows) takes the rows in its first ``len(ids)`` rows and is
+    returned: a staging block a caller keeps is then not allocated anew,
+    and faulted in anew, on every batch.
 
     Dtype-agnostic: any C-contiguous 2-D table goes through the native
     byte-row engine (`qt_gather_rows_bytes`) — bf16 cold tiers included
@@ -314,8 +318,16 @@ def gather_rows(table: np.ndarray, ids: np.ndarray) -> np.ndarray:
         and not table.dtype.hasobject  # object rows are PyObject* — memcpy
         #                                would skip refcounting (crash at GC)
     )
+    if out is not None:
+        if not (out.flags.c_contiguous and out.dtype == table.dtype
+                and out.shape[1:] == table.shape[1:] and out.shape[0] >= ids.shape[0]):
+            raise ValueError("out must be C-contiguous [>= len(ids), D] of the table's dtype")
+        if lib is None or not plain:
+            out[: ids.shape[0]] = gather_rows(table, ids)
+            return out
     if lib is not None and plain:
-        out = np.empty((ids.shape[0], table.shape[1]), table.dtype)
+        if out is None:
+            out = np.empty((ids.shape[0], table.shape[1]), table.dtype)
         lib.qt_gather_rows_bytes(
             table.ctypes.data,
             table.shape[0],

@@ -244,20 +244,33 @@ def _tiled_resolve(tiles, base, pos, k):
     return _select_lanes(tiles, rows, jnp.bitwise_and(pos, LANE - 1), k)
 
 
+def lane_rows(indices):
+    """A flat edge array as 128-lane rows. ``[R, 128]`` (what
+    `CSRTopo.to_device_lane_rows` places) is taken as it is and ``[E]`` with
+    E a multiple of 128 reshapes for free; any other ``[E]`` is padded, a
+    COPY of the array inside the program: fine at a test's size, and the
+    reason the library places a big graph as rows."""
+    if indices.ndim == 2:
+        return indices
+    if indices.shape[0] % LANE:
+        indices = jnp.pad(indices, (0, -indices.shape[0] % LANE))
+    return indices.reshape(-1, LANE)
+
+
 def flat_resolve(indices, ptr, pos, k):
     """Resolve drawn positions through a FLAT edge array seen as 128-lane
-    rows (``indices`` [E], E a multiple of 128: the reshape is free):
-    position ``p`` of a node whose list starts at ``ptr`` sits at row
-    ``(ptr + p) // 128``, lane ``(ptr + p) % 128``. The same row-gather
-    fetch as the tile layout with no per-node padding — a list may straddle
-    two rows, which costs nothing: every position is its own descriptor
-    either way. (One-element gathers from a 1-D array of 1e8 entries take
-    the TPU compiler minutes and run at half the row rate.)"""
-    off = jnp.clip(ptr[:, None] + pos.astype(ptr.dtype), 0, indices.shape[0] - 1)
+    rows (`lane_rows`): position ``p`` of a node whose list starts at
+    ``ptr`` sits at row ``(ptr + p) // 128``, lane ``(ptr + p) % 128``. The
+    same row-gather fetch as the tile layout with no per-node padding — a
+    list may straddle two rows, which costs nothing: every position is its
+    own descriptor either way. (One-element gathers from a 1-D array of 1e8
+    entries take the TPU compiler minutes and run at half the row rate.)"""
+    tiles = lane_rows(indices)
+    off = jnp.clip(ptr[:, None] + pos.astype(ptr.dtype), 0, tiles.size - 1)
     shift = LANE.bit_length() - 1
     rows = lax.shift_right_logical(off, jnp.asarray(shift, off.dtype))
     lane = jnp.bitwise_and(off, LANE - 1).astype(jnp.int32)
-    return _select_lanes(indices.reshape(-1, LANE), rows.astype(jnp.int32), lane, k)
+    return _select_lanes(tiles, rows.astype(jnp.int32), lane, k)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "max_deg"))
@@ -323,7 +336,10 @@ def sample_layer(
     Parameters
     ----------
     indptr : [N+1] int array in HBM
-    indices : [E] int array in HBM
+    indices : [E] int array in HBM, or the same edges as ``[R, 128]``
+        lane rows (`lane_rows`): positions are fetched as row gathers and
+        one-hot lane selects (`flat_resolve`), as the tiled layout fetches
+        them, so both layouts draw the same neighbours from the same key
     seeds : [B] int array (garbage allowed where ``~seed_valid``)
     seed_valid : [B] bool
     k : static fanout
@@ -338,10 +354,7 @@ def sample_layer(
     ptr, deg = row_windows(indptr, s)
     deg = jnp.where(seed_valid, deg, 0)
     pos, valid = fisher_yates_positions(key, deg, k)
-    flat = ptr[:, None] + pos.astype(ptr.dtype)
-    flat = jnp.clip(flat, 0, jnp.asarray(indices.shape[0] - 1, ptr.dtype))
-    nbrs = jnp.take(indices, flat)
-    return nbrs, valid
+    return flat_resolve(indices, ptr, pos, k), valid
 
 
 def build_tiled_host(

@@ -18,10 +18,15 @@ replacement (SURVEY.md section 7.3 item 5) is an explicit software pipeline:
   single prefetch worker delivered (round-3 bench: 11% of non-link latency
   hidden).
 
-The merge is in-jit: ``x = hot_gather(mapped) * is_hot`` then scatter the
-prefetched cold rows into their slots (`mode="drop"` makes the padding
-self-discarding). Cold batch length is bucketed to powers of two so the step
-program is reused across batches (bounded recompiles).
+The merge is in-jit. A table of one hot shard over a host tail (the layout
+`Feature.lookup_padded` serves too) goes through the feature's own two
+halves: `Feature.stage_tiered` on the gather thread, `Feature.upload_tiered`
+on the upload thread, `feature.tiered_gather` in the step: a cold block of the
+fixed width ``Feature.cold_cap``, so the step has ONE shape whatever a
+batch's cold count. The disk-backed, adaptive and quantized stores keep the
+older staging: ``x = hot_gather(mapped) * is_hot`` then a scatter of the cold
+rows into their slots (`mode="drop"` makes the padding self-discarding), the
+cold batch length bucketed to powers of two (bounded recompiles).
 """
 
 from __future__ import annotations
@@ -36,9 +41,23 @@ import jax
 import jax.numpy as jnp
 
 from .comm import round_up_pow2
-from .feature import Feature
+from .feature import Feature, TieredStage, tiered_gather
 from .pyg.sage_sampler import DenseSample, GraphSageSampler
 from .trace import SpanRecorder, trace_scope
+
+KEY_BLOCK = 4096  # steps whose dropout keys `_key_chain` makes in one launch
+
+
+@jax.jit
+def _key_chain(key):
+    """``KEY_BLOCK`` turns of ``key, sub = jax.random.split(key)`` in one
+    program: the key to go on from and the subkeys, in order. Split eagerly,
+    each turn is a program of its own between two steps."""
+    def turn(k, _):
+        both = jax.random.split(k)
+        return both[0], both[1]
+
+    return jax.lax.scan(turn, key, None, length=KEY_BLOCK)
 
 
 class AsyncReadPool:
@@ -163,7 +182,9 @@ class TieredBatch(NamedTuple):
     ds: DenseSample        # padded sample (adjs consumed by the model)
     mapped: jax.Array      # [W] int32 row ids in reordered (cache) space; -1 invalid
     cold_rows: jax.Array   # [C_b, D] prefetched host-tier rows (padded bucket)
-    cold_pos: jax.Array    # [C_b] int32 slot in [0, W) for each cold row; W pads
+    # [C_b] int32 slot in [0, W) for each cold row, W pads; None: ``mapped``
+    # indexes the view [hot table; cold_rows] (`feature.tiered_gather`)
+    cold_pos: Optional[jax.Array]
     seeds: jax.Array       # [B] the batch's seed node ids (for labels)
 
 
@@ -179,12 +200,16 @@ def tiered_lookup(
     hot_table: jax.Array,
     mapped: jax.Array,
     cold_rows: jax.Array,
-    cold_pos: jax.Array,
+    cold_pos: Optional[jax.Array],
 ) -> jax.Array:
     """Jit-safe tiered feature assembly: HBM gather for hot rows + scatter of
     prefetched cold rows. The in-jit half of the reference's multi-pointer
     gather kernel (shard_tensor.cu.hpp:16-58) — the host-pointer branch
-    arrives as ``cold_rows`` instead of being read through UVA."""
+    arrives as ``cold_rows`` instead of being read through UVA. Without
+    ``cold_pos`` the batch was staged by `Feature.stage_tiered`: the
+    feature's own device half answers it."""
+    if cold_pos is None:
+        return tiered_gather(hot_table, mapped, cold_rows)
     hot_n = hot_table.shape[0]
     is_hot = (mapped >= 0) & (mapped < hot_n)
     x = jnp.take(hot_table, jnp.clip(mapped, 0, hot_n - 1), axis=0)
@@ -239,9 +264,9 @@ class TieredFeaturePipeline:
         self.device = device or jax.local_devices()[0]
         self.dtype = getattr(feature, "dtype", np.dtype(np.float32))
         self._order = feature.feature_order  # old id -> stored row (or None)
-        from .ops import cpu_kernels
+        from .shard_tensor import host_gather
 
-        self._gather = cpu_kernels.gather_rows
+        self._gather = host_gather
         # true tier traffic (padding excluded), accumulated across prepare()
         self.cold_rows_seen = 0
         self.rows_seen = 0
@@ -311,6 +336,11 @@ class TieredFeaturePipeline:
                     feature.disk_staged = self._prefetch.staged_mask
         else:
             self.mode = "dram"
+            # one hot shard over a host tail of a plain `Feature`: the
+            # feature's own halves stage the batch, in one fixed shape
+            self._own_halves = isinstance(feature, Feature) and self.cold_np is not None
+
+    _own_halves = False
 
     def prepare_host(
         self, ids: np.ndarray, valid_count: Optional[int] = None
@@ -324,7 +354,12 @@ class TieredFeaturePipeline:
         fetching them wastes cold-tier H2D — at products scale ~15% of the
         capped width.
         """
-        with trace_scope("pipeline.prepare_host"):
+        if self._own_halves:
+            stage = self.feature.stage_tiered(ids, valid_count, clip=False)
+            self.rows_seen += stage.mapped.shape[0]
+            self.cold_rows_seen += stage.n_cold
+            return stage
+        with trace_scope("quiver.feature.lookup"):
             ids = np.asarray(ids).astype(np.int64).reshape(-1)
             W = ids.shape[0]
             n_total = self.feature.shape[0]
@@ -351,7 +386,7 @@ class TieredFeaturePipeline:
             pos[: cold_sel.shape[0]] = cold_sel
             rows = np.zeros((b, self.feature.dim), self.dtype)
             cold_ids = mapped[cold_sel].astype(np.int64)
-            with trace_scope("pipeline.cold_gather"):
+            with trace_scope("quiver.feature.cold_gather"):
                 if self.mode == "disk":
                     host_sel = np.nonzero(cold_ids < self._disk_start)[0]
                     if host_sel.size and self.cold_np is not None:
@@ -404,7 +439,7 @@ class TieredFeaturePipeline:
         rows = np.zeros((b, self.feature.dim), self.dtype)
         cold_ids = stored[cold_sel]
         cold_tiers = tiers[cold_sel]
-        with trace_scope("pipeline.cold_gather"):
+        with trace_scope("quiver.feature.cold_gather"):
             host_sel = np.nonzero(cold_tiers == self._tier_host)[0]
             if host_sel.size and self._host_cache is not None:
                 rows[host_sel] = self._gather(
@@ -470,7 +505,9 @@ class TieredFeaturePipeline:
         """Device half of staging: the H2D copies. Runs in the upload thread
         so a 10-100 MB cold transfer overlaps the NEXT batch's host gather
         and the CURRENT batch's device step."""
-        with trace_scope("pipeline.h2d"):
+        if isinstance(staged, TieredStage):
+            return self.feature.upload_tiered(staged) + (None,)
+        with trace_scope("quiver.feature.h2d"):
             mapped_dev = jax.device_put(staged.mapped, self.device)
             if staged.rows is None:
                 cold_rows = jnp.zeros(
@@ -585,6 +622,7 @@ class TrainPipeline:
         # instances over one Feature would drift apart on stats
         self.tiered = tiered if tiered is not None else TieredFeaturePipeline(feature)
         self.step_fn = step_fn
+        self._chain = None  # (key, key after its first block, the block's subkeys)
         self.depth = max(depth, 1)
         self.stats = PipelineStats()
         # measure_overlap=True: sync each step's loss so the recorded
@@ -649,7 +687,9 @@ class TrainPipeline:
             mapped=mapped,
             cold_rows=cold_rows,
             cold_pos=cold_pos,
-            seeds=jnp.asarray(np.asarray(seeds), jnp.int32),
+            # a copy, not a program: the cast happens on the host
+            seeds=jax.device_put(np.asarray(seeds).astype(np.int32, copy=False),
+                                 self.tiered.device),
         )
 
     def _stage_ds(self, ds: DenseSample, seeds=None) -> TieredBatch:
@@ -782,7 +822,17 @@ class TrainPipeline:
             self.stats.record("upload", t0, _time.monotonic())
             return out
 
+        # the keys ``key, sub = split(key)`` would hand out step by step,
+        # made KEY_BLOCK at a time and wrapped from their words on the host
+        typed = jnp.issubdtype(key.dtype, jax.dtypes.prng_key)
+        base, subs, used = key, None, KEY_BLOCK
+        # the state a step returns is committed to the chip; state handed in
+        # fresh (an init's output) is not, and the step would compile once
+        # for each. No copy: the arrays are where they are put
+        params, opt_state = jax.device_put((params, opt_state), self.tiered.device)
+
         q = collections.deque()
+        failed = False
         try:
 
             def launch():
@@ -797,7 +847,20 @@ class TrainPipeline:
                 if batch is None:
                     break
                 launch()
-                key, sub = jax.random.split(key)
+                if used == KEY_BLOCK:
+                    # a run on the key object of the run before (an epoch
+                    # after its warm-up) starts on the block already made
+                    if key is base and self._chain is not None and self._chain[0] is key:
+                        _, key, subs = self._chain
+                    else:
+                        first, (key, block) = key is base, _key_chain(key)
+                        subs = np.asarray(jax.random.key_data(block) if typed else block)
+                        if first:
+                            self._chain = (base, key, subs)
+                    used = 0
+                sub = (jax.random.wrap_key_data(subs[used], impl=jax.random.key_impl(key))
+                       if typed else subs[used])
+                used += 1
                 t0 = _time.monotonic()
                 params, opt_state, loss = self.step_fn(params, opt_state, sub, batch)
                 if self.measure_overlap:
@@ -841,11 +904,16 @@ class TrainPipeline:
             # cancel + observe them so the unwind leaves no pool zombies
             # (the r7/r14 error contract extended to the prefetch leg)
             self.tiered.cancel_prefetch()
+            failed = True
             raise
         finally:
             spool.shutdown(wait=True)
             gpool.shutdown(wait=True)
             upool.shutdown(wait=True)
+            if failed:
+                # a sample task that was RUNNING when the queue was cancelled
+                # may have issued its batch's reads after the cancel above
+                self.tiered.cancel_prefetch()
             if self.checkpoint is not None:
                 self.checkpoint.flush()
         return params, opt_state, [float(l) for l in losses]
@@ -853,16 +921,18 @@ class TrainPipeline:
 
 def make_tiered_train_step(model, tx, labels: jax.Array, hot_table: jax.Array):
     """Jitted ``step(params, opt_state, key, batch)`` fusing the hot gather
-    into fwd/bwd. ``labels``/``hot_table`` enter the jitted program as
-    ARGUMENTS (closure capture would embed a million-row table as an XLA
-    constant — minutes of compile)."""
+    and the merge of the batch's cold block into fwd/bwd: ONE program a step,
+    XLA module ``jit_tiered_train_step`` (`trace.TIERED_PROGRAM_NAMES`).
+    ``labels``/``hot_table`` enter the jitted program as ARGUMENTS (closure
+    capture would embed a million-row table as an XLA constant — minutes of
+    compile)."""
     import optax
 
     hot_table = jnp.asarray(hot_table)
     labels = jnp.asarray(labels)
 
     @jax.jit
-    def step(params, opt_state, key, hot, lab, batch: TieredBatch):
+    def tiered_train_step(params, opt_state, key, hot, lab, batch: TieredBatch):
         x = tiered_lookup(hot, batch.mapped, batch.cold_rows, batch.cold_pos)
         y = jnp.take(lab, jnp.clip(batch.seeds, 0, lab.shape[0] - 1))
 
@@ -880,6 +950,7 @@ def make_tiered_train_step(model, tx, labels: jax.Array, hot_table: jax.Array):
         return params, opt_state, loss
 
     def bound(params, opt_state, key, batch: TieredBatch):
-        return step(params, opt_state, key, hot_table, labels, batch)
+        return tiered_train_step(params, opt_state, key, hot_table, labels, batch)
 
+    bound.program = tiered_train_step  # to lower it, or to hold its name
     return bound

@@ -554,7 +554,9 @@ class GraphSageSampler:
         stores edges 128-lane-aligned (`CSRTopo.to_device_tiled`) so the
         neighbor fetch rides 2-D row gathers (~1.4x the element-gather
         rate, measured) at ~2-3x flat-CSR HBM bytes; "flat" keeps the
-        plain CSR (use when HBM is tight). Draw-identical on the same
+        plain CSR's bytes (use when HBM is tight) and fetches positions
+        through its edges seen as 128-lane rows, the same row gathers
+        (`ops.sample.flat_resolve`). Draw-identical on the same
         seed (weighted: when max_deg is a multiple of 128). Weighted
         tiled additionally tiles the edge weights
         (`to_device_tiled_weights`) so the [B, max_deg] weight window
@@ -774,8 +776,10 @@ class GraphSageSampler:
         ``(bd, tiles)`` pair under the default tiled layout (weighted
         samplers included — their weight tiles bind separately via
         ``to_device_tiled_weights``), the flat ``(indptr, indices)`` pair
-        under ``layout='flat'``. Callers needing the flat pair regardless
-        of layout should use ``self.csr_topo.to_device()``."""
+        under ``layout='flat'`` with the edges as ``[R, 128]`` lane rows
+        (`CSRTopo.to_device_lane_rows`; ``[E]`` for a weighted sampler).
+        Callers needing the ``[E]`` pair regardless of layout should use
+        ``self.csr_topo.to_device()``."""
         if self.layout == "tiled":
             if self._stream is not None:
                 return self._stream.graph()
@@ -783,7 +787,11 @@ class GraphSageSampler:
                 self._dev_tiled = self.csr_topo.to_device_tiled(self._device_obj())
             return self._dev_tiled
         if self._dev_arrays is None:
-            self._dev_arrays = self.csr_topo.to_device(self._device_obj())
+            # weighted draws read the weights by edge position beside the
+            # [E] array; uniform ones fetch through 128-lane rows
+            place = (self.csr_topo.to_device if self.weighted
+                     else self.csr_topo.to_device_lane_rows)
+            self._dev_arrays = place(self._device_obj())
         return self._dev_arrays
 
     def _host(self):

@@ -24,6 +24,7 @@ TPU because every chip in a slice reaches every other over ICI.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Union
 
@@ -97,6 +98,67 @@ def _scatter_rows(out: jax.Array, pos: jax.Array, rows: jax.Array) -> jax.Array:
     return out.at[pos].set(rows, mode="drop")
 
 
+PIECE_BYTES = 256 << 20  # of one host-to-device copy of `place_rows`
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_rows(table: jax.Array, piece: jax.Array, at) -> jax.Array:
+    return jax.lax.dynamic_update_slice(table, piece, (at, jnp.zeros((), at.dtype)))
+
+
+class HostRows:
+    """A host shard held as ROWS OF AN ARRAY THE CALLER KEEPS: local row
+    ``i`` is ``base[rows[i]]``, never materialised. A degree-ordered table's
+    cold tail is most of the table; permuting it would be a second table on
+    the host (28 GB at half of ogbn-papers100M), and a lookup that knows the
+    caller's ids reads ``base`` without the detour (`Feature.stage_tiered`)."""
+
+    def __init__(self, base: np.ndarray, rows: np.ndarray):
+        self.base, self.rows = base, rows
+        self.shape = (int(rows.shape[0]), int(base.shape[1]))
+        self.dtype = base.dtype
+
+    def gather(self, local_ids, out=None) -> np.ndarray:
+        return cpu_kernels.gather_rows(self.base, self.rows[np.asarray(local_ids)], out=out)
+
+
+def host_gather(shard, local_ids, out=None) -> np.ndarray:
+    """Rows ``local_ids`` of a host shard: an array or `HostRows`."""
+    if isinstance(shard, HostRows):
+        return shard.gather(local_ids, out=out)
+    return cpu_kernels.gather_rows(shard, local_ids, out=out)
+
+
+def place_pieces(n: int, dim: int, dtype, device, piece_of) -> jax.Array:
+    """An ``[n, dim]`` array on ``device`` from host pieces of `PIECE_BYTES`:
+    a zeroed array on the device, each ``piece_of(lo, hi)`` (``[hi - lo,
+    dim]``, made on the host as it is asked for) copied and written in
+    place. No second copy of the whole on the host, no one copy of gigabytes
+    through the default device. The last piece starts early enough to be a
+    whole one, so one write program serves all."""
+    dtype = np.dtype(dtype)
+    table = jnp.zeros((n, dim), dtype, device=device)
+    per = max(min(PIECE_BYTES // max(dim * dtype.itemsize, 1), n), 1)
+    for lo in range(0, n, per):
+        lo = min(lo, n - per)
+        piece = np.ascontiguousarray(piece_of(lo, lo + per).astype(dtype, copy=False))
+        table = _write_rows(table, jax.device_put(piece, device), np.int32(lo))
+    return table
+
+
+def place_rows(base: np.ndarray, rows, device, dtype) -> jax.Array:
+    """``base[rows]`` on ``device`` (``rows``: an index array, or a slice of
+    leading rows) through `place_pieces`: each piece gathered on the host
+    (native, threaded) as it goes up."""
+    if isinstance(rows, slice):
+        base, rows = base[rows], None
+    n = int(base.shape[0] if rows is None else rows.shape[0])
+    return place_pieces(
+        n, int(base.shape[1]), dtype, device,
+        (lambda lo, hi: base[lo:hi]) if rows is None
+        else (lambda lo, hi: cpu_kernels.gather_rows(base, rows[lo:hi])))
+
+
 def _bucket(n: int, floor: int = 256) -> int:
     """Pad id-batch lengths to power-of-two buckets so the jitted gather and
     scatter programs are reused across calls (XLA recompiles per shape; an
@@ -141,6 +203,8 @@ class ShardTensor:
     def append(self, tensor, device: int) -> None:
         """Place ``tensor`` as the next row range on ``device``
         (-1 = host DRAM). Mirrors reference shard_tensor.py:75-95."""
+        if isinstance(tensor, HostRows):
+            return self.append_rows(tensor.base, tensor.rows, device)
         arr = np.asarray(tensor)
         if arr.ndim != 2:
             raise ValueError("ShardTensor shards must be 2-D")
@@ -163,6 +227,31 @@ class ShardTensor:
                 jnp.asarray(arr).astype(self.dtype), _device_of(device)
             )
             self.device_shards.append((device, dev_arr, off))
+        self._n_rows = off.end
+
+    def append_rows(self, base, rows, device: int) -> None:
+        """`append` of ``base[rows]`` without making it on the host: a
+        device shard goes up in pieces (`place_rows`), the host shard stays
+        `HostRows` (an index into ``base``, which the caller keeps).
+        ``rows`` is an index array or a slice of leading rows."""
+        base = np.asarray(base)
+        if base.ndim != 2 or base.dtype != self.dtype:
+            raise ValueError(f"append_rows takes a 2-D {self.dtype} array")
+        if self.disk_shard is not None or self.cpu_tensor is not None:
+            raise ValueError("shards must precede the host shard and the disk shard")
+        if self._dim is None:
+            self._dim = base.shape[1]
+        elif base.shape[1] != self._dim:
+            raise ValueError("shard dim mismatch")
+        n = (len(range(*rows.indices(base.shape[0]))) if isinstance(rows, slice)
+             else int(rows.shape[0]))
+        off = Offset(self._n_rows, self._n_rows + n)
+        if device == CPU_DEVICE:
+            self.cpu_tensor = base[rows] if isinstance(rows, slice) else HostRows(base, rows)
+            self.cpu_offset = off
+        else:
+            self.device_shards.append(
+                (device, place_rows(base, rows, _device_of(device), self.dtype), off))
         self._n_rows = off.end
 
     def append_disk(self, tensor, path: str, read_pool=None) -> None:
@@ -290,9 +379,7 @@ class ShardTensor:
                 pos = np.full(b, n, np.int32)
                 pos[: sel.shape[0]] = sel
                 rows_np = np.zeros((b, self._dim), self.dtype)
-                rows_np[: sel.size] = cpu_kernels.gather_rows(
-                    self.cpu_tensor, ids_np[sel] - off.start
-                )
+                host_gather(self.cpu_tensor, ids_np[sel] - off.start, out=rows_np)
                 rows = jax.device_put(jnp.asarray(rows_np), target)
                 out = _scatter_rows(out, jnp.asarray(pos), rows)
         if self.disk_shard is not None:

@@ -52,6 +52,19 @@ STEP_PROGRAM_NAMES = (
     "sharded_topo_train_step",  # parallel/train.make_sharded_topo_train_step
 )
 
+# The programs of a table with a host tier (PR 32). The step of
+# `pipeline.make_tiered_train_step` (hot gather, merge of the cold block,
+# model and optimizer in one program) is named so that the benchmark's
+# patterns read it as "the step" and the sampler's metrics do not count it;
+# `Feature.lookup_padded`'s device half on such a table is named as the
+# gather's programs are ("padded_gather"). `pipeline.TrainPipeline` launches
+# the step, `sample_dense_program` and, once per `pipeline.KEY_BLOCK` steps,
+# `_key_chain`. Held by tests/test_tiered_names.py.
+TIERED_PROGRAM_NAMES = (
+    "tiered_train_step",        # pipeline.make_tiered_train_step
+    "_padded_gather_tiered",    # feature.py
+)
+
 # `GraphSageSampler.sample_dense`'s TPU path: the whole k-hop sample in ONE
 # program a call (PR 31; before it ~38 eager ones). Named so that the
 # benchmark's patterns for the gather's programs ("padded_gather") and for

@@ -135,6 +135,7 @@ class CSRTopo:
         self._feature_order: Optional[np.ndarray] = None
         self._device_cache = None
         self._tiled_cache = None
+        self._lanes_cache = None
 
     @property
     def feature_order(self) -> Optional[np.ndarray]:
@@ -155,7 +156,18 @@ class CSRTopo:
 
     @property
     def edge_count(self) -> int:
-        return self.indices.shape[0]
+        return int(self.indptr[-1]) if self.indices is None else self.indices.shape[0]
+
+    def drop_host_edges(self) -> None:
+        """Free the host's edge array (6.5 GB at half of ogbn-papers100M)
+        once a device layout holds the edges: a TPU-mode sampler reads its
+        cached device arrays and `Feature` reads the degrees, which `indptr`
+        gives. ``indices`` is None from here on: whatever still wants the
+        host's edges (a HOST-mode sampler, a layout not yet built) fails
+        loudly instead of reading something else."""
+        if not any((self._device_cache, self._tiled_cache, self._lanes_cache)):
+            raise ValueError("no device layout holds the edges yet: place one first")
+        self.indices = None
 
     def __getstate__(self):
         # device arrays don't cross process boundaries; children re-bind
@@ -164,6 +176,7 @@ class CSRTopo:
         state = self.__dict__.copy()
         state["_device_cache"] = None
         state["_tiled_cache"] = None
+        state["_lanes_cache"] = None
         state["_wtiled_cache"] = None
         return state
 
@@ -207,6 +220,43 @@ class CSRTopo:
             indices = jax.device_put(indices, device)
         self._device_cache = (key, (indptr, indices))
         return self._device_cache[1]
+
+    def to_device_lane_rows(self, device=None):
+        """``(indptr, rows)`` in HBM with the edge array as ``[R, 128]``
+        rows, the last one zero-padded: the flat layout as
+        `ops.sample.flat_resolve` fetches from it (row gathers at the tile
+        layout's rate, for the flat CSR's bytes). What
+        ``GraphSageSampler(layout="flat")`` binds; `to_device` keeps the
+        ``[E]`` array for everything that walks the edges."""
+        import jax
+
+        from .ops.sample import LANE
+
+        key = ("lanes", str(device))
+        if getattr(self, "_lanes_cache", None) is not None and self._lanes_cache[0] == key:
+            return self._lanes_cache[1]
+        id_dtype = _best_id_dtype(max(self.edge_count, self.node_count + 1))
+        if np.dtype(id_dtype) == np.int64 and not jax.config.jax_enable_x64:
+            raise ValueError(
+                "graph needs int64 ids on device but jax x64 is disabled — "
+                "see CSRTopo.to_device"
+            )
+        from .shard_tensor import place_pieces
+
+        # in pieces, each cast as it is cut: no second copy of the edges on
+        # the host and no copy of gigabytes in one piece
+        e = self.edge_count
+
+        def piece_of(lo: int, hi: int) -> np.ndarray:
+            piece = np.zeros((hi - lo) * LANE, id_dtype)
+            cut = self.indices[lo * LANE: hi * LANE]
+            piece[: cut.shape[0]] = cut
+            return piece.reshape(hi - lo, LANE)
+
+        rows = place_pieces(max(-(-e // LANE), 1), LANE, id_dtype, device, piece_of)
+        placed = (jax.device_put(self.indptr.astype(id_dtype), device), rows)
+        self._lanes_cache = (key, placed)
+        return placed
 
     def to_device_tiled(self, device=None, id_dtype=None):
         """Materialise the 128-lane-aligned tile layout in HBM:
@@ -355,20 +405,28 @@ def reindex_by_config(adj_csr: CSRTopo, graph_feature, gpu_portion: float, seed:
     any performance comparison across runs — is reproducible; pass a
     different ``seed`` to resample the striping.
     """
+    prev_order, new_order = degree_order(adj_csr, gpu_portion, seed)
+    if graph_feature is not None:
+        graph_feature = np.asarray(graph_feature)[prev_order]
+    return graph_feature, new_order
+
+
+def degree_order(adj_csr: CSRTopo, gpu_portion: float, seed: int = 0):
+    """The permutation of `reindex_by_config` without a table to permute:
+    ``(prev_order, new_order)``, stored row -> old node id and its inverse
+    ("feature_order"). `Feature.from_cpu_tensor` places a tiered table from
+    ``prev_order`` without making the permuted copy."""
     if not 0.0 <= gpu_portion <= 1.0:
         raise ValueError("gpu_portion must be in [0, 1]")
     node_count = adj_csr.node_count
     split = int(node_count * gpu_portion)
     perm_range = np.random.default_rng(seed).permutation(split)
-    degree = adj_csr.degree
     # descending degree order; stable for determinism on ties
-    prev_order = np.argsort(-degree, kind="stable")
+    prev_order = np.argsort(-adj_csr.degree, kind="stable")
     prev_order[:split] = prev_order[perm_range]
     new_order = np.empty(node_count, dtype=np.int64)
     new_order[prev_order] = np.arange(node_count, dtype=np.int64)
-    if graph_feature is not None:
-        graph_feature = np.asarray(graph_feature)[prev_order]
-    return graph_feature, new_order
+    return prev_order, new_order
 
 
 def reindex_feature(graph: CSRTopo, feature, ratio: float, seed: int = 0):

@@ -100,6 +100,11 @@ def make_chain_graph(n_layers=4, width=5):
 # out only the literal kind list and the "last seven" position. The two
 # themselves are expected to fail, strictly: the first run in which one passes
 # again (a `benchmark` PR has rewritten it) fails here, and this hook goes.
+# PR 32 met the same in PR 28's own file: two of its tests want the four-chip
+# cell's five metrics to be the LAST of per_layer, and what a PR adds goes at
+# the end of the list (the driver reads an entry put in the middle as a change
+# to the one it displaced). tests/qbench/test_qbench_tiered_manifest.py holds
+# every other assertion of the two, PR by PR.
 OUTGROWN = {
     "test_qbench_manifest.py::test_every_cell_loads_by_name[benchmark]":
         'asserts kind in ("train", "serve"); papers100M-sage.train-sharded4 is of kind '
@@ -108,6 +113,14 @@ OUTGROWN = {
     "test_new_metrics_load_for_the_cells_that_report_what_they_move":
         "asserts that PR 26's seven metrics are the last seven of per_layer and list every "
         "train cell; PR 28 appended five metrics and a train cell without their spans",
+    "test_qbench_sharded_manifest.py::"
+    "test_pr26s_metrics_load_for_the_cells_that_report_what_they_move":
+        "asserts that everything after PR 26's seven lists the four-chip cell alone; PR 32 "
+        "appended the tiered cell's five (test_qbench_tiered_manifest.py holds the rest)",
+    "test_qbench_sharded_manifest.py::"
+    "test_the_metrics_this_cell_added_come_last_and_are_its_own":
+        "asserts that the four-chip cell's five are the last five of per_layer; PR 32's five "
+        "are appended after them (test_qbench_tiered_manifest.py holds them PR by PR)",
 }
 
 

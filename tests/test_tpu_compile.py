@@ -443,6 +443,98 @@ def test_sample_dense_program_compiles_for_v5e_at_the_train_cells_shapes(v5e, ce
     assert memory.temp_size_in_bytes < 0.5 * 2**30, memory.temp_size_in_bytes / 2**30
 
 
+# papers100M-sage-tiered.train-hot6g (qbench/workloads): half of
+# ogbn-papers100M on ONE chip, 6 GiB of hot rows, the flat graph as lane rows
+TIERED = dict(caps=(12288, 94208, 499712), cold_cap=143360, hot_rows=6 * 2**30 // 512,
+              edge_rows=-(-807_842_936 // LANE))
+
+
+def compile_flat_dedup_sampler_at_papers_size(v5e, caps=TIERED["caps"], batch=1024):
+    """`sample_dense`'s program over the FLAT layout at the tiered cell's
+    size: (optimized HLO text, `memory_analysis`, seconds, the sample's
+    shapes)."""
+    from quiver_tpu.pyg.sage_sampler import sample_dense_program
+
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    graph = (_sds((PAPERS["nodes"] + 1,), jnp.int32), _sds((TIERED["edge_rows"], LANE), jnp.int32))
+    key0 = jax.eval_shape(lambda: jax.random.key(0))
+    args = _struct((key0, _sds((), jnp.uint32), _sds((batch,), jnp.int32), graph), one_chip)
+    static = dict(sizes=SIZES, caps=tuple(caps), dedup=True, hop=("flat", False, 512))
+    t0 = time.time()
+    compiled = sample_dense_program.lower(*args, **static).compile()
+    seconds = time.time() - t0
+    _fits(compiled, f"flat sample_dense_program caps={caps}")
+    ds = jax.eval_shape(lambda *a: sample_dense_program(*a, **static), *args)
+    return compiled.as_text(), compiled.memory_analysis(), seconds, ds
+
+
+def compile_tiered_train_step_at_papers_size(v5e, ds, batch=1024):
+    """`make_tiered_train_step`'s program at the tiered cell's shapes, the
+    hot table and the labels handed over as shapes."""
+    from quiver_tpu import trace
+    from quiver_tpu.models import GraphSAGE
+    from quiver_tpu.pipeline import TieredBatch, make_tiered_train_step
+
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    model = GraphSAGE(hidden_dim=256, out_dim=PAPERS["classes"],
+                      num_layers=len(SIZES), dropout=0.0)
+    tx = optax.adam(1e-3)
+    width = ds.n_id.shape[0]
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    params = jax.eval_shape(
+        lambda k, adjs: model.init(k, jnp.zeros((width, PAPERS["dim"]), jnp.float32), adjs),
+        key, ds.adjs)
+    batch_s = TieredBatch(ds=ds._replace(batch_size=None), mapped=_sds((width,), jnp.int32),
+                          cold_rows=_sds((TIERED["cold_cap"], PAPERS["dim"]), jnp.float32),
+                          cold_pos=None, seeds=_sds((batch,), jnp.int32))
+    step = make_tiered_train_step(model, tx, np.zeros(1, np.int32),
+                                  np.zeros((1, PAPERS["dim"]), np.float32)).program
+    args = (params, jax.eval_shape(tx.init, params), key,
+            _sds((TIERED["hot_rows"], PAPERS["dim"]), jnp.float32),
+            _sds((PAPERS["nodes"],), jnp.int32), batch_s)
+    t0 = time.time()
+    compiled = step.lower(*_struct(args, one_chip)).compile()
+    seconds = time.time() - t0
+    text = compiled.as_text()
+    assert f"HloModule jit_{trace.TIERED_PROGRAM_NAMES[0]}" in text
+    return text, _fits(compiled, "tiered_train_step"), seconds, _entry_operations(compiled)
+
+
+def test_tiered_step_and_flat_sampler_compile_for_v5e_at_papers_size(v5e):
+    """The two programs a step of papers100M-sage-tiered.train-hot6g launches:
+    the three-hop dedup sampler over the flat graph seen as 128-lane rows
+    (no one-element gather from the 8e8-entry edge array) and the tiered
+    step (hot gather, merge of the cold block, model, Adam)."""
+    text, memory, seconds, ds = compile_flat_dedup_sampler_at_papers_size(v5e)
+    print(f"flat jit_sample_dense_program at papers size compiled in {seconds:.1f}s, "
+          f"temporaries {memory.temp_size_in_bytes / 2**30:.3f} GiB")
+    rows = f"s32[{TIERED['edge_rows']},{LANE}]"
+    entry = text[text.index("ENTRY"):]
+    assert re.search(rf"= {re.escape(rows)}\S* parameter\(", entry)
+    # every fetch from the edges is a row gather: [W, 128] out of [R, 128]
+    fetches = re.findall(rf"\(param_\S+: {re.escape(rows)}, param_\S+: s32\[(\d+)\]\) -> (\S+) ", text)
+    assert len(fetches) == sum(SIZES), fetches  # one row gather a drawn position
+    assert all(out == f"s32[{width},{LANE}]" for width, out in fetches), fetches
+    assert memory.temp_size_in_bytes < 1.5 * 2**30
+
+    text, fit, seconds, operations = compile_tiered_train_step_at_papers_size(v5e, ds)
+    print(f"jit_tiered_train_step compiled in {seconds:.1f}s: {fit}")
+    assert 6.4 < fit["arguments_gb"] < 7.2  # the hot table, the labels, one batch
+    import json
+
+    with open(os.path.join(REPO, "qbench", "metrics", "cold_merge_ms.train.json")) as f:
+        params = json.load(f)["params"]
+    merge = [op for op in operations if any(re.search(p, op) for p in params["include"])]
+    print("\n".join(merge))
+    assert merge, "cold_merge_ms.train's patterns match no operation of the tiered step"
+    # the gather out of the cold block and the select over the two gathers
+    assert len(merge) == 2 and all(
+        re.match(rf"%\S+ = f32\[{ds.n_id.shape[0]},{PAPERS['dim']}\]", op) for op in merge)
+    assert f"f32[{TIERED['cold_cap']},{PAPERS['dim']}]" in merge[0] and "pred[" in merge[1]
+    hot = f"f32[{TIERED['hot_rows']},{PAPERS['dim']}]"
+    assert not any(hot in op for op in merge)  # the hot gather is the gather's, not the merge's
+
+
 def compile_feature_gather(v5e, program, rows, dim, positions):
     """The entry computation of a `lookup_padded` program as
     ``[(shape, opcode)]``."""
