@@ -55,18 +55,29 @@ def pad_widths(batch: int, sizes, caps=None):
     return widths
 
 
-def row_windows(indptr: jax.Array, s: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """(row start, degree) for CLIPPED node ids ``s`` — as ONE dim-2 gather
-    instead of two element gathers. TPU gathers are descriptor-rate bound
-    and width-invariant up to ~128 lanes (PERF.md (earlier claims)), so pairing
-    (indptr[i], indptr[i+1]) into an [N, 2] table halves the degree-lookup
-    descriptors (measured 43.6 -> 41.5 ms on the products e2e step). The
-    stack is loop-invariant: CSE'd across hops and hoisted out of epoch
-    scans. The ONE implementation — every sampler (uniform, weighted,
-    sharded) goes through it."""
-    pp = jnp.stack([indptr[:-1], indptr[1:]], axis=1)
-    both = jnp.take(pp, s, axis=0)
-    return both[:, 0], (both[:, 1] - both[:, 0]).astype(jnp.int32)
+def row_windows(
+    table: jax.Array, seeds: jax.Array, seed_valid: jax.Array
+) -> Tuple[jax.Array, jax.Array]:
+    """``(base, degree)`` of every seed, the degree 0 where ``~seed_valid``:
+    ONE ``[W, 2]`` row gather from the ``[N, 2]`` table that is PLACED with
+    the graph. The tile layout's ``bd`` holds (first tile row, degree)
+    (`CSRTopo.to_device_tiled`), the flat layout's windows (first edge,
+    degree) (`CSRTopo.to_device_lane_rows`; a shard's block of it,
+    `parallel.topology.shard_topology_rows`): both are built once, on the
+    host, and reach every program as an argument. TPU gathers are
+    descriptor-rate bound and width-invariant up to ~128 lanes (PERF.md
+    section 6), so the pair costs one descriptor a seed where two element
+    gathers from ``indptr`` cost two. A 1-D ``indptr`` is stacked into that
+    table INSIDE the program, in every launch (6.2 ms of ``[N]``-sized
+    operations at 55.5M nodes: PERF.md section 6, PR 33): fine at a test's
+    size and for the weighted flat sampler, and the reason the library
+    places a big graph's table. The ONE lookup of every sampler; seeds are
+    clipped to the table (garbage is allowed where ``~seed_valid``)."""
+    if table.ndim == 1:
+        table = jnp.stack([table[:-1], table[1:] - table[:-1]], axis=1)
+    s = jnp.clip(seeds, 0, table.shape[0] - 1).astype(table.dtype)
+    both = jnp.take(table, s, axis=0)
+    return both[:, 0], jnp.where(seed_valid, both[:, 1], 0).astype(jnp.int32)
 
 
 def fisher_yates_positions(key: jax.Array, deg: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
@@ -182,17 +193,18 @@ def weighted_sample_layer(
     """One-hop WEIGHTED neighbor sample (reference quiver.cu.hpp:61-82
     bucketed weights + cuda_random.cu.hpp:177-221 weight_sample).
 
-    ``weights`` [E] edge weights aligned with ``indices``. Static-shape
+    ``weights`` [E] edge weights aligned with ``indices``. The weighted
+    flat sampler keeps the 1-D ``indptr`` (`CSRTopo.to_device`), so this
+    program stacks its window table in every launch (`row_windows`); no
+    cell runs it. Static-shape
     tradeoff: each row considers its first ``min(deg, max_deg)`` neighbors
     (one ``[B, max_deg]`` lane window instead of the reference's dynamic
     bucket machinery) — set ``max_deg`` >= the graph's max degree for exact
     semantics; heavier-degree tails are truncated and a row's sample then
     comes from its first ``max_deg`` edges.
     """
-    n = indptr.shape[0] - 1
-    s = jnp.clip(seeds, 0, n - 1).astype(indptr.dtype)
-    ptr, deg = row_windows(indptr, s)
-    deg = jnp.where(seed_valid, jnp.minimum(deg, max_deg), 0)
+    ptr, deg = row_windows(indptr, seeds, seed_valid)
+    deg = jnp.minimum(deg, max_deg)
     lanes = ptr[:, None] + jnp.arange(max_deg, dtype=ptr.dtype)[None, :]
     lanes = jnp.clip(lanes, 0, indices.shape[0] - 1)
     w_rows = jnp.take(weights, lanes)
@@ -209,14 +221,6 @@ def weighted_sample_layer(
     )
     nbrs = jnp.take(indices, flat)
     return nbrs, valid
-
-
-def _tiled_bd_lookup(bd, seeds, seed_valid):
-    """(base, deg) rows for clipped seeds; deg zeroed where invalid."""
-    n = bd.shape[0]
-    s = jnp.clip(seeds, 0, n - 1).astype(jnp.int32)
-    both = jnp.take(bd, s, axis=0)
-    return both[:, 0], jnp.where(seed_valid, both[:, 1], 0)
 
 
 def _select_lanes(tiles, rows, lane, k):
@@ -296,7 +300,7 @@ def tiled_weighted_sample_layer(
     same scores, same top-k). Same truncation semantics: each row
     considers its first ``min(deg, max_deg)`` edges.
     """
-    base, deg = _tiled_bd_lookup(bd, seeds, seed_valid)
+    base, deg = row_windows(bd, seeds, seed_valid)
     deg = jnp.minimum(deg, max_deg)
     w_rows = _tiled_payload_window(base, wtiles, max_deg)
     pos, valid = gumbel_topk_positions(key, deg, k, w_rows)
@@ -319,6 +323,21 @@ def _tiled_payload_window(base, ptiles, max_deg: int):
     return jnp.concatenate(parts, axis=1)  # [B, T*128] >= max_deg
 
 
+def flat_windows_host(indptr, dtype, rows: Optional[int] = None) -> "np.ndarray":
+    """Host build of the flat layout's ``[N, 2]`` (first edge, degree) table
+    that `row_windows` reads, of ``dtype``, with no ``[N]`` temporary of
+    ``indptr``'s own width. ``rows`` > N appends rows of degree 0 (a
+    shard's block, padded to the shards' common length)."""
+    import numpy as np
+
+    n = indptr.shape[0] - 1
+    out = np.empty((n if rows is None else rows, 2), dtype)
+    out[:n, 0] = indptr[:-1]
+    np.subtract(indptr[1:], indptr[:-1], out=out[:n, 1], casting="unsafe")
+    out[n:] = (indptr[-1], 0)
+    return out
+
+
 @functools.partial(jax.jit, static_argnames=("k",))
 def sample_layer(
     indptr: jax.Array,
@@ -335,7 +354,9 @@ def sample_layer(
 
     Parameters
     ----------
-    indptr : [N+1] int array in HBM
+    indptr : the placed ``[N, 2]`` (first edge, degree) windows
+        (`CSRTopo.to_device_lane_rows`), taken as they are, or an ``[N+1]``
+        indptr, stacked into them inside the program (`row_windows`)
     indices : [E] int array in HBM, or the same edges as ``[R, 128]``
         lane rows (`lane_rows`): positions are fetched as row gathers and
         one-hot lane selects (`flat_resolve`), as the tiled layout fetches
@@ -349,10 +370,7 @@ def sample_layer(
     nbrs : [B, k] same dtype as ``indices``; garbage where invalid
     valid : [B, k] bool
     """
-    n = indptr.shape[0] - 1
-    s = jnp.clip(seeds, 0, n - 1).astype(indptr.dtype)
-    ptr, deg = row_windows(indptr, s)
-    deg = jnp.where(seed_valid, deg, 0)
+    ptr, deg = row_windows(indptr, seeds, seed_valid)
     pos, valid = fisher_yates_positions(key, deg, k)
     return flat_resolve(indices, ptr, pos, k), valid
 
@@ -547,7 +565,7 @@ def tiled_temporal_sample_layer(
     ``temporal_edge_weights(ttiles, recency)`` on the same key — the
     frozen-graph parity pin. Rows whose valid-edge count is below k
     return all their valid edges (copy-all, like every sampler here)."""
-    base, deg = _tiled_bd_lookup(bd, seeds, seed_valid)
+    base, deg = row_windows(bd, seeds, seed_valid)
     deg = jnp.minimum(deg, max_deg)
     ts_rows = _tiled_payload_window(base, ttiles, max_deg)
     w_rows = temporal_weight_rows(ts_rows, t.astype(jnp.float32), recency,
@@ -572,7 +590,7 @@ def tiled_sample_layer(
     resolved via k 2-D row gathers + one-hot lane selects. Measured at
     products hop-3 shape: fetch 6.5 vs 9.0 ms.
     """
-    base, deg = _tiled_bd_lookup(bd, seeds, seed_valid)
+    base, deg = row_windows(bd, seeds, seed_valid)
     pos, valid = fisher_yates_positions(key, deg, k)
     return _tiled_resolve(tiles, base, pos, k), valid
 

@@ -24,9 +24,11 @@ degree-ordered power-law graphs is the full frontier width (the same
 analysis as the grouped feature gather, see NEXT.md round-2 note), so the
 lane count matches the all_gather/psum formulation while adding sorts.
 
-Each shard keeps its contiguous CSR block as a local indptr + flat indices
-array (`ShardedTopology`; bytes follow the EDGES: 4 B an edge + 4 B a node).
-A drawn position is read from the indices seen as 128-lane rows
+Each shard keeps its contiguous CSR block as a local window table + flat
+indices array (`ShardedTopology`; bytes follow the EDGES: 4 B an edge + 8 B a
+node). A frontier row's (first edge, degree) is one row of the table, built on
+the host and placed with the block (`ops.sample.row_windows`), and a drawn
+position is read from the indices seen as 128-lane rows
 (`ops.sample.flat_resolve`): a 2-D ROW gather + one-hot lane select
 (`ops.sample._select_lanes`), the fetch the single-chip tile sampler uses too,
 so the same key draws the same neighbours as on one device.
@@ -49,6 +51,7 @@ from ..ops.sample import (
     LANE,
     fisher_yates_positions,
     flat_resolve,
+    flat_windows_host,
     pad_widths,
     row_windows,
 )
@@ -57,21 +60,21 @@ from ..ops.sample import (
 class ShardedTopology(NamedTuple):
     """Device-resident row-sharded CSR (see `shard_topology_rows`).
 
-    ``indptr``  [P, R_max+1] — per-shard LOCAL indptr (offsets into the
-                shard's own indices block), edge-padded so padding rows read
-                as degree 0;
+    ``windows`` [P, R_max, 2] — per-shard (first edge, degree) of every
+                local row, offsets into the shard's own indices block
+                (`ops.sample.flat_windows_host`); padding rows have degree 0;
     ``indices`` [P, E_pad]   — per-shard neighbor block, zero-padded;
     ``row_start`` [P+1]      — global row boundaries (replicated; shard p
                 owns rows ``row_start[p]:row_start[p+1]``).
     """
 
-    indptr: jax.Array
+    windows: jax.Array
     indices: jax.Array
     row_start: jax.Array
 
     @property
     def n_shards(self) -> int:
-        return self.indptr.shape[0]
+        return self.windows.shape[0]
 
     def specs(self, feat_axes) -> "ShardedTopology":
         """shard_map in_specs pytree for this topology striped over
@@ -83,7 +86,7 @@ def topology_specs(feat_axes) -> "ShardedTopology":
     """The ONE place the ShardedTopology shard_map spec layout lives: CSR
     blocks striped over ``feat_axes``, row boundaries replicated."""
     return ShardedTopology(
-        indptr=P(feat_axes, None), indices=P(feat_axes, None), row_start=P()
+        windows=P(feat_axes, None, None), indices=P(feat_axes, None), row_start=P()
     )
 
 
@@ -131,7 +134,7 @@ def _flat_plan(indptr: np.ndarray, n_shards: int, pad_multiple: int):
     pad_multiple = int(np.lcm(pad_multiple, 8 * LANE))
     row_start = partition_rows_by_edges(indptr, n_shards)
     r_max = max(int(np.max(np.diff(row_start))) if n_shards else 0, 1)
-    r_max = _padded(r_max + 1, 8 * LANE) - 1  # the indptr block: r_max + 1
+    r_max = _padded(r_max, 8 * LANE)
     e_pad = max(
         (int(indptr[row_start[p + 1]] - indptr[row_start[p]])
          for p in range(n_shards)),
@@ -142,18 +145,15 @@ def _flat_plan(indptr: np.ndarray, n_shards: int, pad_multiple: int):
 
 def _flat_block(indptr, indices, row_start, p: int, r_max: int, e_pad: int,
                 id_dtype) -> Tuple[np.ndarray, np.ndarray]:
-    """Shard ``p``'s (local indptr [r_max+1], indices [e_pad]) alone."""
+    """Shard ``p``'s (local windows [r_max, 2], indices [e_pad]) alone."""
     lo, hi = int(row_start[p]), int(row_start[p + 1])
     ptr_dt = np.int32 if e_pad < 2**31 else np.int64
-    local = (indptr[lo : hi + 1] - indptr[lo]).astype(ptr_dt)
-    ptr = np.empty(r_max + 1, ptr_dt)
-    ptr[: hi - lo + 1] = local
-    # edge-pad: rows past this shard's range read as degree 0
-    ptr[hi - lo + 1 :] = local[-1] if local.size else 0
+    # rows past this shard's range read as degree 0
+    windows = flat_windows_host(indptr[lo : hi + 1] - indptr[lo], ptr_dt, rows=r_max)
     idx = np.zeros(e_pad, id_dtype)
     blk = indices[int(indptr[lo]) : int(indptr[hi])]
     idx[: blk.shape[0]] = blk
-    return ptr, idx
+    return windows, idx
 
 
 def _row_start_dtype(row_start: np.ndarray):
@@ -166,7 +166,7 @@ def build_topology_shards(
     n_shards: int,
     pad_multiple: int = 512,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Host-side shard construction: (indptr_blocks, indices_blocks,
+    """Host-side shard construction: (window_blocks, indices_blocks,
     row_start) as stacked numpy arrays (see `ShardedTopology`). The stack is
     a second copy of the graph: `shard_topology_rows` places block by block
     and never builds it."""
@@ -224,10 +224,10 @@ def shard_topology_rows(
             "see CSRTopo.to_device"
         )
     row_start, r_max, e_pad = _flat_plan(indptr, n_shards, 512)
-    shapes = ((n_shards, r_max + 1), (n_shards, e_pad))
-    chip_bytes = (r_max + 1 + e_pad) * 4
+    shapes = ((n_shards, r_max, 2), (n_shards, e_pad))
+    chip_bytes = (2 * r_max + e_pad) * 4
     with trace_scope("quiver.shard.topology", chip_bytes=chip_bytes) as span:
-        ptr, idx = place_shards(
+        win, idx = place_shards(
             mesh, axes, shapes,
             lambda p: tuple(
                 b[None] for b in _flat_block(indptr, indices, row_start, p,
@@ -238,8 +238,8 @@ def shard_topology_rows(
             row_start.astype(_row_start_dtype(row_start)),
             NamedSharding(mesh, P()),
         )
-        span.sync = (ptr, idx, rs)
-    return ShardedTopology(ptr, idx, rs)
+        span.sync = (win, idx, rs)
+    return ShardedTopology(win, idx, rs)
 
 
 def _flat_axis_index(axes: Tuple[str, ...]):
@@ -287,7 +287,7 @@ def _grouped_collective_sample(partial_fn, cur, cur_valid, k, axes, group_axis):
 
 
 def sharded_sample_layer(
-    indptr_blk: jax.Array,
+    windows_blk: jax.Array,
     indices_blk: jax.Array,
     row_start: jax.Array,
     cur: jax.Array,
@@ -309,13 +309,13 @@ def sharded_sample_layer(
     """
     axes = (axes,) if isinstance(axes, str) else tuple(axes)
     nbrs, valid = _sample_layer_partial(
-        indptr_blk, indices_blk, row_start, cur, cur_valid, k, key, axes
+        windows_blk, indices_blk, row_start, cur, cur_valid, k, key, axes
     )
     return _psum_assemble(nbrs, valid, axes)
 
 
 def _sample_layer_partial(
-    indptr_blk, indices_blk, row_start, cur, cur_valid, k, key, axes
+    windows_blk, indices_blk, row_start, cur, cur_valid, k, key, axes
 ):
     """This shard's un-reduced contribution to a one-hop sample: neighbors
     for the frontier rows it owns, zeros elsewhere. Callers choose the
@@ -323,12 +323,9 @@ def _sample_layer_partial(
     idx = _flat_axis_index(axes)
     start = jnp.take(row_start, idx)
     end = jnp.take(row_start, idx + 1)
-    r_max = indptr_blk.shape[0] - 1
     local = (cur - start).astype(jnp.int32)
     mine = cur_valid & (cur >= start) & (cur < end)
-    s = jnp.clip(local, 0, r_max - 1)
-    ptr, deg = row_windows(indptr_blk, s)
-    deg = jnp.where(mine, deg, 0)
+    ptr, deg = row_windows(windows_blk, local, mine)
     pos, valid = fisher_yates_positions(key, deg, k)
     nbrs = flat_resolve(indices_blk, ptr, pos, k)
     nbrs = jnp.where(valid, nbrs, 0)
@@ -336,7 +333,7 @@ def _sample_layer_partial(
 
 
 def sharded_sample_layer_grouped(
-    indptr_blk: jax.Array,
+    windows_blk: jax.Array,
     indices_blk: jax.Array,
     row_start: jax.Array,
     cur: jax.Array,
@@ -354,7 +351,7 @@ def sharded_sample_layer_grouped(
 
     def partial_fn(all_cur, all_valid):
         return _sample_layer_partial(
-            indptr_blk, indices_blk, row_start, all_cur, all_valid, k, key, axes
+            windows_blk, indices_blk, row_start, all_cur, all_valid, k, key, axes
         )
 
     return _grouped_collective_sample(

@@ -304,11 +304,11 @@ def _topo_sample_local(pipeline, sizes, caps, has_host, hot_cold, feat_axes,
         has_host, hot_cold, feat_axes, hot_rows, cold_budget, overflow_acc
     )
     row_start = stopo.row_start     # [P+1] replicated boundaries
-    # this shard's blocks, the [R_max+1] local indptr and the [E_pad] edge
+    # this shard's blocks, the [R_max, 2] local windows and the [E_pad] edge
     # block, the leading shard axis of length 1 dropped by a reshape:
     # ``block[0]`` compiles to a COPY of the block every step (3.7 ms for the
     # 808 MB edge block of the papers100M cell; PERF.md)
-    blocks = tuple(b.reshape(b.shape[1:]) for b in (stopo.indptr, stopo.indices))
+    blocks = tuple(b.reshape(b.shape[1:]) for b in (stopo.windows, stopo.indices))
 
     def sample_fn(cur, cur_valid, k, sub):
         if not has_host:

@@ -481,9 +481,13 @@ def caps_from_counts(
 def one_hop_binder(layout: str, weighted: bool, max_deg: int):
     """``bind(graph) -> sample_fn``: the one-hop op of a TPU-mode sampler
     over the device-array pytree `GraphSageSampler.fused_sample_spec` hands
-    out beside it (``(bd, tiles[, wtiles])`` tiled, ``(indptr, indices[,
-    weights])`` flat). The one source of that closure: every program that
-    samples in-jit calls ``bind`` on its TRACED graph argument."""
+    out beside it: ``(bd, tiles[, wtiles])`` tiled, ``(windows, rows)``
+    flat (the placed ``[N, 2]`` (first edge, degree) table and the
+    ``[R, 128]`` lane rows of `CSRTopo.to_device_lane_rows`), ``(indptr,
+    indices, weights)`` weighted flat (1-D arrays: that one stacks its
+    table in the program, `ops.sample.row_windows`). The one source of
+    that closure: every program that samples in-jit calls ``bind`` on its
+    TRACED graph argument, and hands the pair through as it is."""
 
     def bind(g):
         if layout == "tiled" and weighted:
@@ -506,10 +510,10 @@ def one_hop_binder(layout: str, weighted: bool, max_deg: int):
                     indptr, indices, w, cur, cur_valid, k, key, max_deg
                 )
         else:
-            indptr, indices = g
+            windows, rows = g
 
             def sample_fn(cur, cur_valid, k, key):
-                return _sample_layer_op(indptr, indices, cur, cur_valid, k, key)
+                return _sample_layer_op(windows, rows, cur, cur_valid, k, key)
 
         return sample_fn
 
@@ -775,11 +779,14 @@ class GraphSageSampler:
         """Bind the graph to the device and return the binding: the
         ``(bd, tiles)`` pair under the default tiled layout (weighted
         samplers included — their weight tiles bind separately via
-        ``to_device_tiled_weights``), the flat ``(indptr, indices)`` pair
-        under ``layout='flat'`` with the edges as ``[R, 128]`` lane rows
-        (`CSRTopo.to_device_lane_rows`; ``[E]`` for a weighted sampler).
-        Callers needing the ``[E]`` pair regardless of layout should use
-        ``self.csr_topo.to_device()``."""
+        ``to_device_tiled_weights``); under ``layout='flat'`` the
+        ``(windows, rows)`` pair of `CSRTopo.to_device_lane_rows`, the
+        ``[N, 2]`` (first edge, degree) table and the edges as ``[R, 128]``
+        lane rows (a weighted sampler: `CSRTopo.to_device`'s 1-D
+        ``(indptr [N+1], indices [E])``). Either way the first array is
+        what `ops.sample.row_windows` looks a seed up in and the second
+        holds the edges. Callers needing the ``[E]`` pair regardless of
+        layout should use ``self.csr_topo.to_device()``."""
         if self.layout == "tiled":
             if self._stream is not None:
                 return self._stream.graph()
@@ -788,7 +795,8 @@ class GraphSageSampler:
             return self._dev_tiled
         if self._dev_arrays is None:
             # weighted draws read the weights by edge position beside the
-            # [E] array; uniform ones fetch through 128-lane rows
+            # [E] array; uniform ones fetch through 128-lane rows and the
+            # placed window table
             place = (self.csr_topo.to_device if self.weighted
                      else self.csr_topo.to_device_lane_rows)
             self._dev_arrays = place(self._device_obj())
@@ -860,14 +868,14 @@ class GraphSageSampler:
                 wtiles = self.csr_topo.to_device_tiled_weights(self._device_obj())
                 return (bd, tiles, wtiles), bind, tiles.dtype
             return (bd, tiles), bind, tiles.dtype
-        indptr, indices = self.lazy_init_quiver()
+        windows, edges = self.lazy_init_quiver()
         if self.weighted:
             if self._w_dev is None:
                 self._w_dev = jnp.asarray(
                     np.asarray(self.csr_topo.edge_weights, np.float32)
                 )
-            return (indptr, indices, self._w_dev), bind, indices.dtype
-        return (indptr, indices), bind, indices.dtype
+            return (windows, edges, self._w_dev), bind, edges.dtype
+        return (windows, edges), bind, edges.dtype
 
     def _hop(self):
         return self.layout, self.weighted, self.max_deg

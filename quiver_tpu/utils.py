@@ -222,15 +222,20 @@ class CSRTopo:
         return self._device_cache[1]
 
     def to_device_lane_rows(self, device=None):
-        """``(indptr, rows)`` in HBM with the edge array as ``[R, 128]``
-        rows, the last one zero-padded: the flat layout as
+        """``(windows, rows)`` in HBM: the flat layout as
+        ``GraphSageSampler(layout="flat")`` binds it. ``rows`` is the edge
+        array as ``[R, 128]`` rows, the last one zero-padded, as
         `ops.sample.flat_resolve` fetches from it (row gathers at the tile
-        layout's rate, for the flat CSR's bytes). What
-        ``GraphSageSampler(layout="flat")`` binds; `to_device` keeps the
-        ``[E]`` array for everything that walks the edges."""
+        layout's rate, for the flat CSR's bytes); ``windows`` is the
+        ``[N, 2]`` (first edge, degree) table `ops.sample.row_windows`
+        reads, built here ONCE on the host (`ops.sample.flat_windows_host`)
+        and placed beside the rows as ``bd`` is beside the tiles: no 1-D
+        ``indptr`` goes to the chip and no program stacks one. `to_device`
+        keeps ``(indptr [N+1], indices [E])`` for everything that walks
+        the edges."""
         import jax
 
-        from .ops.sample import LANE
+        from .ops.sample import LANE, flat_windows_host
 
         key = ("lanes", str(device))
         if getattr(self, "_lanes_cache", None) is not None and self._lanes_cache[0] == key:
@@ -254,7 +259,8 @@ class CSRTopo:
             return piece.reshape(hi - lo, LANE)
 
         rows = place_pieces(max(-(-e // LANE), 1), LANE, id_dtype, device, piece_of)
-        placed = (jax.device_put(self.indptr.astype(id_dtype), device), rows)
+        windows = jax.device_put(flat_windows_host(self.indptr, id_dtype), device)
+        placed = (windows, rows)
         self._lanes_cache = (key, placed)
         return placed
 

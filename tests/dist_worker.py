@@ -69,9 +69,9 @@ def train_main(pid: int, port: str, topo: bool = False) -> None:
     if topo:
         from quiver_tpu.parallel import ShardedTopology
 
-        ptr_b, idx_b, row_start = case["stopo_np"]
+        win_b, idx_b, row_start = case["stopo_np"]
         stopo = ShardedTopology(
-            indptr=gput(ptr_b, P(("ici",), None)),
+            windows=gput(win_b, P(("ici",), None, None)),
             indices=gput(idx_b, P(("ici",), None)),
             row_start=gput(row_start, P()),
         )

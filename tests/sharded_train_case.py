@@ -40,14 +40,14 @@ def build_case():
     x0 = jnp.zeros((ds0.n_id.shape[0], feat.shape[1]), jnp.float32)
     params = model.init(jax.random.key(1), x0, ds0.adjs)
     # row-sharded topology blocks for the 2-shard (ici=2) mesh; both runners
-    # place the indptr/indices blocks striped over ici
-    ptr_b, idx_b, row_start = build_topology_shards(
+    # place the window/indices blocks striped over ici
+    win_b, idx_b, row_start = build_topology_shards(
         topo.indptr.astype(np.int32), topo.indices.astype(np.int32), 2
     )
     return {
         "indptr": topo.indptr.astype(np.int32),
         "indices": topo.indices.astype(np.int32),
-        "stopo_np": (ptr_b, idx_b, np.asarray(row_start)),
+        "stopo_np": (win_b, idx_b, np.asarray(row_start)),
         # the exact padding shard_feature_rows applies on an ici=2 mesh
         "feat_padded": np.asarray(pad_to_multiple(feat, 2)),
         "labels": labels,

@@ -132,9 +132,9 @@ def test_two_process_topo_train_step_matches_single_controller():
     def put(x, spec=P()):
         return jax.device_put(jax.numpy.asarray(x), NamedSharding(mesh, spec))
 
-    ptr_b, idx_b, row_start = case["stopo_np"]
+    win_b, idx_b, row_start = case["stopo_np"]
     stopo = ShardedTopology(
-        indptr=put(ptr_b, P(("ici",), None)),
+        windows=put(win_b, P(("ici",), None, None)),
         indices=put(idx_b, P(("ici",), None)),
         row_start=put(row_start),
     )

@@ -405,10 +405,8 @@ def test_weighted_flat_window_select_draw_parity_with_take_along_axis(graph):
 
     def reference_take_along_axis(ip, ix, w, s, sv, k, key, max_deg):
         # the pre-round-10 formulation, verbatim
-        n = ip.shape[0] - 1
-        s = jnp.clip(s, 0, n - 1).astype(ip.dtype)
-        ptr, deg = row_windows(ip, s)
-        deg = jnp.where(sv, jnp.minimum(deg, max_deg), 0)
+        ptr, deg = row_windows(ip, s, sv)
+        deg = jnp.minimum(deg, max_deg)
         lanes = ptr[:, None] + jnp.arange(max_deg, dtype=ip.dtype)[None, :]
         lanes = jnp.clip(lanes, 0, ix.shape[0] - 1)
         w_rows = jnp.take(w, lanes)

@@ -11,24 +11,28 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from quiver_tpu import CSRTopo, trace as qtrace
 from quiver_tpu.models import GraphSAGE
-from quiver_tpu.ops.sample import LANE, flat_resolve
+from quiver_tpu.ops.sample import LANE, flat_resolve, sample_layer
 from quiver_tpu.parallel import (
     ShardedTopology,
     make_mesh,
     make_sharded_topo_sample,
     make_sharded_topo_train_step,
+    mesh_axes,
     pad_to_multiple,
     replicate,
     sampling_comm_bytes,
     shard_feature_hot_cold,
     shard_feature_rows,
     shard_topology_rows,
+    sharded_sample_layer,
     step_comm_bytes,
 )
 from quiver_tpu.parallel.topology import build_topology_shards
+from quiver_tpu.utils import shard_map_compat
 
 SIZES = (4, 3)
 
@@ -115,15 +119,16 @@ def test_topology_blocks_go_up_one_shard_at_a_time(transfers):
     mesh = make_mesh(4, dp=1)
     topo = graph(3000, 6)
     stopo = shard_topology_rows(mesh, topo)
-    ptr, idx, row_start = build_topology_shards(topo.indptr, topo.indices.astype(np.int32), 4)
+    win, idx, row_start = build_topology_shards(topo.indptr, topo.indices.astype(np.int32), 4)
+    assert win.ndim == 3 and win.shape[2] == 2 and win.shape[1] % (8 * LANE) == 0
     assert isinstance(stopo, ShardedTopology)
-    for arr, want in zip(stopo[:2], (ptr, idx)):
+    for arr, want in zip(stopo[:2], (win, idx)):
         assert arr.shape == want.shape and arr.dtype == jnp.int32
         assert [s.data.shape for s in arr.addressable_shards] == [(1,) + want.shape[1:]] * 4
         np.testing.assert_array_equal(np.asarray(arr), want)
     np.testing.assert_array_equal(np.asarray(stopo.row_start), row_start)
     # the largest single transfer is one block, not the stack of four
-    assert max(transfers) == max(ptr[0].nbytes, idx[0].nbytes)
+    assert max(transfers) == max(win[0].nbytes, idx[0].nbytes)
     assert stopo.indices.shape[-1] % LANE == 0  # blocks are whole lane rows
 
 
@@ -190,6 +195,55 @@ def test_four_shards_and_one_device_draw_the_same_from_the_same_key(mean_degree)
     np.testing.assert_array_equal(x, want_x)
     for a, b in zip(ds.adjs, want_ds.adjs):
         np.testing.assert_array_equal(a.mask, b.mask)
+
+
+@pytest.mark.parametrize("mean_degree", [6, 80])
+def test_the_hop_over_placed_window_blocks_draws_what_the_one_device_hop_draws(mean_degree):
+    """`sharded_sample_layer` over the ``[P, R_max, 2]`` window blocks that
+    `shard_topology_rows` places, on four devices, against `sample_layer`
+    over the pair `CSRTopo.to_device_lane_rows` places, same key: the same
+    neighbours and validity (the collective zeroes what is not valid)."""
+    topo = graph(1500, mean_degree, seed=13)
+    mesh = make_mesh(4, dp=1)
+    _, feat_axes, _ = mesh_axes(mesh)
+    stopo = shard_topology_rows(mesh, topo)
+    r_max = stopo.windows.shape[1]
+    assert stopo.windows.shape == (4, r_max, 2) and r_max % (8 * LANE) == 0
+    assert [s.data.shape for s in stopo.windows.addressable_shards] == [(1, r_max, 2)] * 4
+    specs = stopo.specs(feat_axes)
+    assert specs.windows == P(feat_axes, None, None) and specs.indices == P(feat_axes, None)
+    # a shard's block: its rows' (first LOCAL edge, degree), then degree 0
+    row_start, blocks = np.asarray(stopo.row_start), np.asarray(stopo.windows)
+    for p in range(4):
+        lo, hi = row_start[p], row_start[p + 1]
+        np.testing.assert_array_equal(blocks[p, : hi - lo, 0], topo.indptr[lo:hi] - topo.indptr[lo])
+        np.testing.assert_array_equal(blocks[p, : hi - lo, 1], topo.degree[lo:hi])
+        assert not blocks[p, hi - lo:, 1].any()
+
+    rng = np.random.default_rng(2)
+    cur = jnp.asarray(np.concatenate([rng.integers(0, 1500, 252), [0, 1499, 1500, 1507]])
+                      .astype(np.int32))
+    cur_valid = jnp.asarray(rng.random(256) < 0.9)
+    key, k = jax.random.key(17), 5
+    windows, rows = topo.to_device_lane_rows()
+    want_n, want_v = sample_layer(windows, rows, cur, cur_valid, k, key)
+
+    def hop(stopo, cur, cur_valid):
+        blocks = (b.reshape(b.shape[1:]) for b in (stopo.windows, stopo.indices))
+        return sharded_sample_layer(*blocks, stopo.row_start, cur, cur_valid, k, key, feat_axes)
+
+    got_n, got_v = jax.jit(shard_map_compat(
+        hop, mesh=mesh, in_specs=(specs, P(), P()), out_specs=(P(), P()), check_vma=False,
+    ))(stopo, replicate(mesh, cur), replicate(mesh, cur_valid))
+    want_v = np.asarray(want_v)
+    # a seed past the last node is nobody's row: the one-device hop clips it
+    # to the last node, the collective draws nothing for it
+    owned = np.asarray(cur) < 1500
+    np.testing.assert_array_equal(np.asarray(got_v)[owned], want_v[owned])
+    assert not np.asarray(got_v)[~owned].any()
+    keep = want_v & owned[:, None]
+    np.testing.assert_array_equal(np.asarray(got_n)[keep], np.asarray(want_n)[keep])
+    assert not np.asarray(got_n)[~np.asarray(got_v)].any()
 
 
 def _train(mesh, topo, feat, labels, model, batches, keys):

@@ -200,7 +200,10 @@ def test_three_pipeline_steps_equal_the_all_hot_loop_and_follow_the_reference(ta
 def test_flat_layout_fetches_through_lane_rows_and_draws_what_tiled_draws(topo):
     tiled = GraphSageSampler(topo, [5, 4, 3], mode="TPU", seed=5, dedup=True)
     flat = GraphSageSampler(topo, [5, 4, 3], mode="TPU", seed=5, dedup=True, layout="flat")
-    indptr, rows = flat.lazy_init_quiver()
+    windows, rows = flat.lazy_init_quiver()
+    # the placed (first edge, degree) table, and no 1-D indptr beside it
+    np.testing.assert_array_equal(
+        np.asarray(windows), np.stack([topo.indptr[:-1], np.diff(topo.indptr)], axis=1))
     assert rows.ndim == 2 and rows.shape[1] == sample_ops.LANE
     assert rows.shape[0] == -(-topo.edge_count // sample_ops.LANE)
     np.testing.assert_array_equal(np.asarray(rows).reshape(-1)[: topo.edge_count], topo.indices)
@@ -217,10 +220,10 @@ def test_flat_layout_fetches_through_lane_rows_and_draws_what_tiled_draws(topo):
     assert ix.shape[0] % sample_ops.LANE  # so the [E] form pads inside the program
     cur, valid, key = jnp.arange(200, dtype=ip.dtype), jnp.ones(200, bool), jax.random.key(9)
     n1, v1 = sample_ops.sample_layer(ip, ix, cur, valid, 6, key)
-    n2, v2 = sample_ops.sample_layer(indptr, rows, cur, valid, 6, key)
+    n2, v2 = sample_ops.sample_layer(windows, rows, cur, valid, 6, key)
     np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
     np.testing.assert_array_equal(np.asarray(n1)[np.asarray(v1)], np.asarray(n2)[np.asarray(v2)])
     deg = np.diff(topo.indptr)[:200]
     assert (np.asarray(v1).sum(axis=1) == np.minimum(deg, 6)).all()
-    text = sample_ops.sample_layer.lower(indptr, rows, cur, valid, 6, key).as_text()
+    text = sample_ops.sample_layer.lower(windows, rows, cur, valid, 6, key).as_text()
     assert f"tensor<{rows.shape[0]}x128x" in text  # gathers read [R, 128], never a 1-D edge array

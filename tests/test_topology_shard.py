@@ -45,18 +45,22 @@ def test_partition_reconstructs_csr():
     topo = _powerlaw_graph()
     indptr, indices = np.asarray(topo.indptr), np.asarray(topo.indices)
     for shards in (1, 3, 8):
-        ib, xb, rs = build_topology_shards(indptr, indices, shards)
+        wb, xb, rs = build_topology_shards(indptr, indices, shards)
         assert rs[0] == 0 and rs[-1] == indptr.shape[0] - 1
-        got_indptr, got_indices = [0], []
+        assert wb.shape[0] == shards and wb.shape[2] == 2
+        got_start, got_deg, got_indices, edges_before = [], [], [], 0
         for p in range(shards):
             lo, hi = int(rs[p]), int(rs[p + 1])
-            local = ib[p, : hi - lo + 1]
-            got_indices.append(xb[p, : local[-1]])
-            got_indptr.extend((local[1:] + got_indptr[-1] - local[0]).tolist())
-        np.testing.assert_array_equal(np.asarray(got_indptr), indptr)
+            start, deg = wb[p, : hi - lo, 0], wb[p, : hi - lo, 1]
+            got_indices.append(xb[p, : int(deg.sum())])
+            got_start.append(start + edges_before)
+            got_deg.append(deg)
+            edges_before += int(deg.sum())
+            # padding rows in each window block must read as degree 0
+            assert not wb[p, hi - lo :, 1].any()
+        np.testing.assert_array_equal(np.concatenate(got_start), indptr[:-1])
+        np.testing.assert_array_equal(np.concatenate(got_deg), np.diff(indptr))
         np.testing.assert_array_equal(np.concatenate(got_indices), indices)
-        # padding rows in each indptr block must read as degree 0
-        assert np.all(np.diff(ib, axis=1) >= 0)
 
 
 def test_partition_edge_balance_on_powerlaw():
@@ -107,7 +111,7 @@ def test_sharded_sample_layer_bit_matches_local():
 
     def f(stopo, cur, valid_in):
         return sharded_sample_layer(
-            stopo.indptr[0], stopo.indices[0], stopo.row_start,
+            stopo.windows[0], stopo.indices[0], stopo.row_start,
             cur, valid_in, k, key, feat_axes,
         )
 
@@ -180,7 +184,7 @@ def test_multihost_sharded_topo_step(pipeline):
     mesh = make_mesh(8, hosts=2)
     stopo = shard_topology_rows(mesh, topo)
     # topology must stripe over BOTH host and ici
-    assert stopo.indptr.sharding.spec[0] == ("host", "ici")
+    assert stopo.windows.sharding.spec[0] == ("host", "ici")
     model = GraphSAGE(hidden_dim=16, out_dim=4, num_layers=2, dropout=0.0)
     tx = optax.adam(1e-2)
     step = make_sharded_topo_train_step(mesh, model, tx, sizes=[4, 4], pipeline=pipeline)
@@ -246,7 +250,7 @@ def _run_sharded_sample(mesh, stopo, cur, valid_in, k, key):
 
     def f(stopo, cur, valid_in):
         return sharded_sample_layer(
-            stopo.indptr[0], stopo.indices[0], stopo.row_start,
+            stopo.windows[0], stopo.indices[0], stopo.row_start,
             cur, valid_in, k, key, feat_axes,
         )
 
@@ -361,7 +365,7 @@ def test_grouped_sample_parity(mean_degree):
 
     def f(stopo, cur, valid_in):
         return sharded_sample_layer_grouped(
-            stopo.indptr[0], stopo.indices[0], stopo.row_start, cur, valid_in,
+            stopo.windows[0], stopo.indices[0], stopo.row_start, cur, valid_in,
             k, key, feat_axes, "host",
         )
 

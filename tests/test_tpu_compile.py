@@ -181,8 +181,9 @@ def compile_sharded_topo_step(v5e, n_devices=4, batch=PRODUCTS["batch"]):
     # blocks of whole (8, 128) tiles, as `shard_topology_rows` cuts them
     shard_rows = SHARD_ROWS if n_devices == 4 else N
     stopo = ShardedTopology(
-        indptr=jax.ShapeDtypeStruct(
-            (n_devices, _padded(shard_rows + 1, 8 * LANE)), jnp.int32, sharding=rows),
+        windows=jax.ShapeDtypeStruct(
+            (n_devices, _padded(shard_rows, 8 * LANE), 2), jnp.int32,
+            sharding=NamedSharding(mesh, P("ici", None, None))),
         indices=jax.ShapeDtypeStruct(
             (n_devices, _padded(PRODUCTS["edges"] // n_devices, 8 * LANE)), jnp.int32,
             sharding=rows),
@@ -206,7 +207,7 @@ def compile_sharded_topo_step(v5e, n_devices=4, batch=PRODUCTS["batch"]):
 # papers100M-sage.train-sharded4 (qbench/configs/papers100M-sage.json): nodes,
 # edges, lanes, classes; the per-shard block sizes `shard_topology_rows` gives every seed
 PAPERS = dict(nodes=55_529_978, edges=807_842_936, dim=128, classes=172,
-              shard_rows=14_155_775, shard_edges=209_715_200)
+              shard_rows=14_155_776, shard_edges=209_715_200)
 
 
 def compile_flat_sharded_topo_step_at_papers_size(v5e, batch=1024):
@@ -224,7 +225,8 @@ def compile_flat_sharded_topo_step_at_papers_size(v5e, batch=1024):
     rep = NamedSharding(mesh, P())
     blocks = NamedSharding(mesh, P("ici", None))
     stopo = ShardedTopology(
-        indptr=jax.ShapeDtypeStruct((4, PAPERS["shard_rows"] + 1), jnp.int32, sharding=blocks),
+        windows=jax.ShapeDtypeStruct((4, PAPERS["shard_rows"], 2), jnp.int32,
+                                     sharding=NamedSharding(mesh, P("ici", None, None))),
         indices=jax.ShapeDtypeStruct((4, PAPERS["shard_edges"]), jnp.int32, sharding=blocks),
         row_start=jax.ShapeDtypeStruct((5,), jnp.int32, sharding=rep))
     step = make_sharded_topo_train_step(mesh, model, tx, SIZES, pipeline="fused")
@@ -245,8 +247,24 @@ def compile_flat_sharded_topo_step_at_papers_size(v5e, batch=1024):
     # nor of either block where the program drops its shard axis of length 1
     # (blocks are padded to whole (8, 128) tiles for this: `_flat_plan`)
     assert not re.search(r" reduce\(%param", text[text.index("ENTRY"):])
+    # the window block is what was placed: the program builds nothing of a
+    # shard's row count (PR 33: the parent stacked it from the local indptr,
+    # a slice, two pads and an add of [R_max] a step); the compiler moves the
+    # block to its faster memory space by an asynchronous copy, that is all
+    assert _results_of_size(text, PAPERS["shard_rows"]) <= {
+        "parameter", "bitcast", "copy-start", "copy-done"}
+    assert re.search(rf"= s32\[1,{PAPERS['shard_rows']},2\]\S* parameter\(", text)
     return (_fits(compiled, "flat sharded-topology step at the papers100M cell's size"),
             _entry_operations(compiled))
+
+
+def _results_of_size(text, size):
+    """``{opcode}`` of every instruction of an optimized HLO text, fused
+    bodies included, whose result (a tuple's elements too) has a dimension
+    of ``size``."""
+    has_size = re.compile(rf"\[(?:\d+,)*{size}(?:,\d+)*\]")
+    lines = re.findall(r"^\s*(?:ROOT )?%\S+ = (.*?) ([\w-]+)\(", text, re.M)
+    return {opcode for result, opcode in lines if has_size.search(result)}
 
 
 def _entry_operations(compiled):
@@ -456,7 +474,7 @@ def compile_flat_dedup_sampler_at_papers_size(v5e, caps=TIERED["caps"], batch=10
     from quiver_tpu.pyg.sage_sampler import sample_dense_program
 
     one_chip = SingleDeviceSharding(v5e.devices[0])
-    graph = (_sds((PAPERS["nodes"] + 1,), jnp.int32), _sds((TIERED["edge_rows"], LANE), jnp.int32))
+    graph = (_sds((PAPERS["nodes"], 2), jnp.int32), _sds((TIERED["edge_rows"], LANE), jnp.int32))
     key0 = jax.eval_shape(lambda: jax.random.key(0))
     args = _struct((key0, _sds((), jnp.uint32), _sds((batch,), jnp.int32), graph), one_chip)
     static = dict(sizes=SIZES, caps=tuple(caps), dedup=True, hop=("flat", False, 512))
@@ -515,7 +533,13 @@ def test_tiered_step_and_flat_sampler_compile_for_v5e_at_papers_size(v5e):
     fetches = re.findall(rf"\(param_\S+: {re.escape(rows)}, param_\S+: s32\[(\d+)\]\) -> (\S+) ", text)
     assert len(fetches) == sum(SIZES), fetches  # one row gather a drawn position
     assert all(out == f"s32[{width},{LANE}]" for width, out in fetches), fetches
-    assert memory.temp_size_in_bytes < 1.5 * 2**30
+    # the (first edge, degree) table is the placed argument and nothing of
+    # the node count's size is built in the launch (PR 33: the parent's
+    # in-program stack was four such operations and 1.04 GiB of temporaries)
+    assert re.search(rf"= s32\[{PAPERS['nodes']},2\]\S* parameter\(", entry)
+    assert _results_of_size(text, PAPERS["nodes"]) == {"parameter"}
+    assert not _results_of_size(text, PAPERS["nodes"] + 1)
+    assert memory.temp_size_in_bytes < 0.1 * 2**30
 
     text, fit, seconds, operations = compile_tiered_train_step_at_papers_size(v5e, ds)
     print(f"jit_tiered_train_step compiled in {seconds:.1f}s: {fit}")
