@@ -190,3 +190,118 @@ def _bwd(saved, g):
 
 
 gather_masked_sum.defvjp(_fwd, _bwd)
+
+
+# --- attention (models/gat.py) ---------------------------------------------
+# `gather_attention_sum` is the sum of attention: a weight per slot AND head that
+# is a softmax of scores of the gathered rows themselves. Plain `jax.numpy`,
+# `ATTENTION_BLOCK` targets at a time, so that one block's ``[k, block, H * D]``
+# gather is the largest temporary; its backward gathers each block again
+# instead of keeping the k-fold rows, and adds every slot's gradient (the
+# weighted sum's and the score's) to its source row in ONE scatter-add: a TPU
+# scatter costs 30-70 ns a row whatever the row's width, so a second one for the
+# scores' ``[W * k, H]`` cost more than half as much again (PERF.md section 6,
+# PR 34).
+
+ATTENTION_BLOCK = 8192  # targets a block: 252 MB of 2 KB rows at k = 15
+
+
+def _target_blocks(w: int, *arrays):
+    """``[W, ...]`` arrays cut into equal blocks of at most `ATTENTION_BLOCK`
+    targets, a whole number of 8-row tiles each (zero-padded: a padded
+    target's slots are all masked), stacked for a scan."""
+    n = -(-w // ATTENTION_BLOCK)
+    block = -(-w // (8 * n)) * 8
+    return [jnp.pad(a, ((0, n * block - w),) + ((0, 0),) * (a.ndim - 1))
+            .reshape((n, block) + a.shape[1:]) for a in arrays]
+
+
+def attention_block(rows, x_dst, valid, att, t, slope):
+    """Attention of a set of targets over their k slots and themselves,
+    slot-major: ``rows [k, *T, D]`` the slots' source rows, ``x_dst [*T, D]``
+    the targets' own, ``t [*T]`` the targets' half of the score; ``valid``
+    (which slots are real) broadcasts against ``[k, *T]`` and ``att`` (the
+    source half's vector) against ``[*T, D]``, heads being some of the axes
+    ``T``. A pair scores ``leaky_relu(row . att + t)``; the softmax runs in
+    float32 over the valid slots and the target itself (a masked slot's share
+    underflows to exactly 0); returns ``sum_j alpha_j row_j + alpha_self
+    x_dst`` as ``[*T, D]``, in the rows' dtype."""
+    att = att.astype(rows.dtype)
+    pre = (rows * att).sum(axis=-1) + t
+    pre_self = (x_dst * att).sum(axis=-1) + t
+    e = jnp.where(valid, jax.nn.leaky_relu(pre, slope), jnp.asarray(-1e9, pre.dtype))
+    e = jnp.concatenate([e, jax.nn.leaky_relu(pre_self, slope)[None]], axis=0)
+    alpha = jax.nn.softmax(e.astype(jnp.float32), axis=0).astype(rows.dtype)
+    return (alpha[:-1, ..., None] * rows).sum(axis=0) + alpha[-1, ..., None] * x_dst
+
+
+def _tiles(a, h):
+    """``[..., B, H * D]`` rows as ``[..., B / 8, H, 8, D]``: on a TPU the
+    ``(8, 128)`` tiles of 2-D rows in the order memory holds them, so that for
+    D = 128 the view is a bitcast and a head is an axis all the same (a
+    reshape to ``[..., B, H, D]`` relays every byte: 7 ms a pass of the IGB
+    cell's 2.26 GB)."""
+    return a.reshape(a.shape[:-2] + (a.shape[-2] // 8, 8, h, -1)).swapaxes(-3, -2)
+
+
+def _rows(a):
+    """`_tiles` undone: ``[..., B / 8, H, 8, D]`` as ``[..., B, H * D]``."""
+    a = a.swapaxes(-3, -2)
+    return a.reshape(a.shape[:-4] + (a.shape[-4] * 8, -1))
+
+
+def _tiled_block(x, c, m, x_dst, t_b, att):
+    """`attention_block`'s arguments for one block of targets, in `_tiles`'
+    arrangement: ids ``c [B, k]`` into ``x``, mask ``m [B, k]``, the targets'
+    rows and score halves. Returns (clipped ids ``[k, B]``, arguments)."""
+    h = att.shape[0]
+    ids = jnp.clip(c.T, 0, x.shape[0] - 1)
+    return ids, (_tiles(jnp.take(x, ids, axis=0), h), _tiles(x_dst, h),
+                 m.T.reshape(m.shape[1], -1, 1, 8), att[:, None, :],
+                 t_b.reshape(-1, 8, h).swapaxes(-2, -1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def gather_attention_sum(x, cols, mask, att, t, slope):
+    """`attention_block` of every target over ``x[clip(cols)]``: ``[W, k]`` ids
+    into ``x[N, H * D]`` (heads side by side in a row, the targets its first W
+    rows; ``att [H, D]`` says how a row splits) and the targets' score halves
+    ``t [W, H]`` give ``[W, H * D]``. The ``[W, k, H, D]`` rows are never laid
+    out whole, forward or backward. Rows are gathered and their gradients
+    scattered as 2-D rows: a TPU scatter-add into ``[N, H * D]`` costs 45 ns a
+    row, into ``[N, H, D]`` 73."""
+    w = cols.shape[0]
+
+    def block(args):
+        _, tiled = _tiled_block(x, *args, att)
+        return _rows(attention_block(*tiled, slope))
+
+    out = jax.lax.map(block, tuple(_target_blocks(w, cols, mask, x[:w], t)))
+    return out.reshape(-1, x.shape[1])[:w]
+
+
+def _attention_fwd(x, cols, mask, att, t, slope):
+    return gather_attention_sum(x, cols, mask, att, t, slope), (x, cols, mask, att, t)
+
+
+def _attention_bwd(slope, saved, g):
+    x, cols, mask, att, t = saved
+    w = cols.shape[0]
+
+    def block(carry, args):
+        dx, datt = carry
+        ids, (rows, x_dst, valid, att_t, t_b) = _tiled_block(x, *args[:-1], att)
+        _, vjp = jax.vjp(lambda rows, xd, a, tb: attention_block(rows, xd, valid, a, tb, slope),
+                         rows, x_dst, att_t, t_b)
+        drows, dx_dst, datt_b, dt_b = vjp(_tiles(args[-1], att.shape[0]))
+        return ((dx.at[ids].add(_rows(drows)), datt + datt_b[:, 0]),
+                (_rows(dx_dst), dt_b.swapaxes(-2, -1).reshape(-1, att.shape[0])))
+
+    (dx, datt), (dx_dst, dt) = jax.lax.scan(
+        block, (jnp.zeros_like(x), jnp.zeros_like(att)),
+        tuple(_target_blocks(w, cols, mask, x[:w], t, g)))
+    dx = dx.at[:w].add(dx_dst.reshape(-1, x.shape[1])[:w])
+    return dx, None, None, datt, dt.reshape((-1,) + t.shape[1:])[:w]
+
+
+gather_attention_sum.defvjp(_attention_fwd, _attention_bwd)

@@ -88,10 +88,10 @@ class DenseAdj(NamedTuple):
         """Neighbor features ``[W_dst, k, ...]`` from the hop-source array,
         honoring the layout: a slice+reshape for the structural (fused)
         layout, a gather for explicit cols. For consumers that need every
-        neighbor row (GCN's weights, GAT's attention, the DGL shim); a plain
-        masked sum over the k slots should not lay them out: see
-        `quiver_tpu.ops.gather_sum.gather_masked_sum`, which
-        `models.masked_mean_aggregate` uses for explicit cols."""
+        neighbor row (GCN's weights, the DGL shim); a sum over the k slots
+        should not lay them out: see `quiver_tpu.ops.gather_sum`
+        (`gather_masked_sum` behind `models.masked_mean_aggregate`,
+        `gather_attention_sum` behind `models.GATConv`, for explicit cols)."""
         w, k = self.mask.shape
         if self.cols is None:
             s = x_src[w : w * (1 + k)]

@@ -105,6 +105,11 @@ def make_chain_graph(n_layers=4, width=5):
 # the end of the list (the driver reads an entry put in the middle as a change
 # to the one it displaced). tests/qbench/test_qbench_tiered_manifest.py holds
 # every other assertion of the two, PR by PR.
+# PR 34 met the same in PR 32's file: one test wants PR 32's five metrics to be
+# the LAST of per_layer, and PR 34's five (the attention cell's) are appended
+# after them. tests/qbench/test_qbench_gat_manifest.py holds every other
+# assertion of it (what each PR appended stands together, in order) and says
+# nothing of what follows, so the next appended metric keeps it.
 OUTGROWN = {
     "test_qbench_manifest.py::test_every_cell_loads_by_name[benchmark]":
         'asserts kind in ("train", "serve"); papers100M-sage.train-sharded4 is of kind '
@@ -121,6 +126,10 @@ OUTGROWN = {
     "test_the_metrics_this_cell_added_come_last_and_are_its_own":
         "asserts that the four-chip cell's five are the last five of per_layer; PR 32's five "
         "are appended after them (test_qbench_tiered_manifest.py holds them PR by PR)",
+    "test_qbench_tiered_manifest.py::"
+    "test_what_each_pr_appended_stands_together_in_order_and_this_prs_comes_last":
+        "asserts that PR 32's five are the last five of per_layer; PR 34's five are appended "
+        "after them (test_qbench_gat_manifest.py holds the order PR by PR, with no last place)",
 }
 
 

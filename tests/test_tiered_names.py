@@ -69,6 +69,9 @@ def test_every_module_pattern_reads_the_tiered_programs_as_what_they_are(
         assert reads(include, exclude, "jit_sample_dense_program(2)")
     elif name == "gather_roofline":
         assert reads(include, exclude, gather) and not reads(include, exclude, step)
+    elif name == "model_device_ms.train":  # PR 34: "the step", whichever program is it
+        assert reads(include, exclude, step) and not reads(include, exclude, gather)
+        assert not reads(include, exclude, "jit_sample_dense_program(2)")
     else:  # the serve step's metrics: no program of this path
         assert not reads(include, exclude, step) and not reads(include, exclude, gather)
 

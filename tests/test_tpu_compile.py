@@ -24,6 +24,11 @@ main path, at the shapes `chip_smoke.py` runs, are lowered for a described
       `jit_sample_dense_program`) at the shapes of the benchmark's two
       one-chip train cells: the graph arrays are parameters, no constant of
       their size is in the program, temporaries stay under 0.5 GiB
+  (i) the jitted optax step over `GAT` (4 heads x 128, 4 output heads) at the
+      shapes of the benchmark's attention cell: no ``[W_dst, k, H, D]`` array
+      in any buffer, temporaries under 4.5 GiB, five matrix products and no
+      more (scores, softmax and the weighted sum are not products), and every
+      pattern of the cell's two by-shape metrics meets an operation
 
 A compile that passes is not a chip run. To stay inside the suite's time
 limit the tests compile (b) at batch 64 and (c) at bucket 8 (the graph and
@@ -312,15 +317,11 @@ IGB_CAPS = (73728, 417792)           # `calibrate_caps(margin=1.1)`, as the cell
 IGB_CAPS_DEFAULT = (81920, 458752)   # the same probes at its default margin 1.2
 
 
-def compile_igb_model_step(v5e, caps):
-    """The step of `examples/reddit_sage.py` over explicit-``cols`` hops at the
-    igb cell's widths: (entry operations as `(shape, opcode)`, temporaries)."""
-    from quiver_tpu.models import GraphSAGE
+def _igb_step_args(model, tx, caps):
+    """(params, opt_state, key, x, adjs, labels) shapes of a step over
+    explicit-``cols`` hops at the igb cells' widths."""
     from quiver_tpu.pyg.sage_sampler import DenseAdj
 
-    model = GraphSAGE(hidden_dim=IGB["hidden"], out_dim=IGB["classes"],
-                      num_layers=len(IGB["fanout"]), dropout=0.0)
-    tx = optax.adam(0.01)
     widths = (IGB["batch"],) + tuple(caps)
     scalar = _sds((), jnp.int32)
     # outermost hop first: hop i aggregates widths[i + 1] source rows into widths[i]
@@ -331,8 +332,19 @@ def compile_igb_model_step(v5e, caps):
     x = _sds((widths[-1], IGB["dim"]), jnp.float32)
     key = jax.eval_shape(lambda: jax.random.key(0))
     params = jax.eval_shape(model.init, key, x, adjs)
-    args = (params, jax.eval_shape(tx.init, params), key, x, adjs,
+    return (params, jax.eval_shape(tx.init, params), key, x, adjs,
             _sds((IGB["batch"],), jnp.int32))
+
+
+def compile_igb_model_step(v5e, caps):
+    """The step of `examples/reddit_sage.py` over explicit-``cols`` hops at the
+    igb cell's widths: (entry operations as `(shape, opcode)`, temporaries)."""
+    from quiver_tpu.models import GraphSAGE
+
+    model = GraphSAGE(hidden_dim=IGB["hidden"], out_dim=IGB["classes"],
+                      num_layers=len(IGB["fanout"]), dropout=0.0)
+    tx = optax.adam(0.01)
+    args = _igb_step_args(model, tx, caps)
     one_chip = SingleDeviceSharding(v5e.devices[0])
     compiled = make_train_step(model, tx).lower(*_struct(args, one_chip)).compile()
     _fits(compiled, f"igb model step at caps {caps}")
@@ -363,6 +375,57 @@ def test_igb_model_step_holds_no_k_fold_gather_on_v5e(v5e, caps, temp_gib):
     # the relaid x (1.6 GiB at the cell's caps) and the kernel's output, no more:
     # 1.88 and 2.06 GiB, where the `take -> sum` form needed 8.72 GiB in one block
     assert temp_bytes < temp_gib * 2**30, temp_bytes / 2**30
+
+
+def compile_igb_gat_step(v5e, caps=IGB_CAPS):
+    """The benchmark's attention step (`qbench.kinds.train.make_train_step` over
+    `GAT` as `qbench/configs/igb-small-gat.json` states it) over explicit-``cols``
+    hops at the cell's widths: (compiled, the entry's operations as a device
+    trace names them; a loop is one of them, its body is not listed)."""
+    from qbench.kinds import train
+    from quiver_tpu.models import GAT
+
+    model = GAT(hidden_dim=IGB["hidden"], out_dim=IGB["classes"], heads=4, out_heads=4,
+                num_layers=len(IGB["fanout"]), dropout=0.0, activation=jax.nn.relu)
+    tx = optax.adam(0.01)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    compiled = train.make_train_step(model, tx, None).lower(
+        *_struct(_igb_step_args(model, tx, caps), one_chip)).compile()
+    return compiled, _entry_operations(compiled)
+
+
+def test_igb_gat_step_fits_a_v5e_and_its_metrics_patterns_meet_operations(v5e):
+    """The attention cell's step: it fits beside the cell's 6.8 GB resident,
+    lays no k-fold rows out, multiplies matrices in the projections alone, and
+    every pattern of `gat_project_ms.train` and `gat_edge_ms.train` names at
+    least one operation of the optimized program (a later restructuring fails
+    here instead of emptying a metric)."""
+    import json
+
+    compiled, operations = compile_igb_gat_step(v5e)
+    _fits(compiled, "igb GAT step")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"igb GAT step: temporaries {temp / 2**30:.2f} GiB")
+    assert temp < 4.5 * 2**30 and temp + 6.8e9 < 0.95 * 16.9e9, temp / 2**30
+    text = compiled.as_text()
+    w_dst, k = IGB_CAPS[0], IGB["fanout"][-1]
+    for shape in (f"[{w_dst},{k},4,128]", f"[{k},{w_dst},4,128]", f"[{w_dst * k},512]",
+                  f"[{k},{w_dst},512]", f"[{w_dst},{k},512]", f"[{w_dst * k},4,128]"):
+        assert f"f32{shape}" not in text, shape
+    # projection forward x2, weight gradient x2, layer 2's input gradient: no other product
+    assert len(re.findall(r" convolution\(", text)) == 5 and " dot(" not in text
+    matched = {}
+    for metric in ("gat_project_ms.train", "gat_edge_ms.train"):
+        with open(os.path.join(REPO, "qbench", "metrics", f"{metric}.json")) as f:
+            params = json.load(f)["params"]
+        assert params["line"] == "ops" and not params.get("exclude")
+        for pattern in params["include"]:
+            assert any(re.search(pattern, op) for op in operations), (metric, pattern)
+        matched[metric] = [op for op in operations
+                           if any(re.search(p, op) for p in params["include"])]
+    # layer 1's forward product and its weight gradient; its two loops, forward and backward
+    assert len(matched["gat_project_ms.train"]) == 2, matched["gat_project_ms.train"]
+    assert sum(" while(" in op for op in matched["gat_edge_ms.train"]) == 2
 
 
 def test_fused_train_step_compiles_for_v5e(v5e):
@@ -620,6 +683,8 @@ if __name__ == "__main__":
          lambda: compile_igb_model_step(desc, IGB_CAPS)[1] / 2**30),
         ("igb model step, caps at margin 1.2: temporaries GiB",
          lambda: compile_igb_model_step(desc, IGB_CAPS_DEFAULT)[1] / 2**30),
+        ("igb GAT step (the attention cell): temporaries GiB",
+         lambda: compile_igb_gat_step(desc)[0].memory_analysis().temp_size_in_bytes / 2**30),
         *((f"sample_dense program, {cell}: temporaries GiB, compile seconds",
            lambda shape=shape: [(m.temp_size_in_bytes / 2**30, s) for _, m, s in
                                 [compile_sample_dense_program(desc, **shape)]][0])
