@@ -227,9 +227,11 @@ def _select_lanes(tiles, rows, lane, k):
     """``tiles[rows[b, j], lane[b, j]]`` (rows in range) as k-split row gathers + one-hot
     lane selects (k separate [B]-row gathers measured faster than one
     [B*k]: 6.2 vs 7.1 ms; one-hot instead of take_along_axis — the
-    descriptor trap). The ONE
-    position fetch: the tiled layers and the flat sharded layer all ride
-    it, so the fetch pattern is tuned in one place."""
+    descriptor trap). One ``[B, 128]`` row fetched per DRAWN POSITION: k
+    descriptors a seed, each moving 512 B to deliver one int32 (9.3 ns a
+    row; PERF.md section 6). The flat layer (`flat_resolve`) and the tile
+    layout's k-fetch (`_tiled_k_fetch`: its far seeds, its small hops and
+    its fallback) ride it, so the k-split pattern is tuned in one place."""
     ar = jnp.arange(LANE, dtype=jnp.int32)
     cols = []
     for j in range(k):
@@ -239,13 +241,102 @@ def _select_lanes(tiles, rows, lane, k):
     return jnp.stack(cols, axis=1).astype(tiles.dtype)
 
 
-def _tiled_resolve(tiles, base, pos, k):
-    """Resolve drawn positions to neighbor ids through the tile table:
-    position ``p`` of a node sits at tile row ``base + p // 128``, lane
-    ``p % 128``. Shared by the uniform and weighted tiled layers."""
+FAR_SHARE = 8     # the far list holds B // 8 seeds
+FAR_MIN = 1024    # hops whose far list would be shorter keep the k-fetch
+
+
+def far_width(batch: int, k: int) -> int:
+    """Static width ``H`` of `_tiled_resolve`'s compacted list of far seeds
+    at a hop of ``batch`` seeds and fan-out ``k``; 0 where the hop keeps
+    the k-fetch. A function of the hop's shape alone: ``H = B // 8`` where
+    ``k > 2`` (one fetch a seed costs ``2B + (k + 1)H`` descriptors against
+    ``kB``: nothing to win at ``k <= 2``) and ``H >= 1024``, i.e.
+    ``B >= 8192``. Engaged: igb's hops 1 and 2 (10240 x 10, 73728 x 15),
+    products' hops 2 and 3 (16384 x 10, 180224 x 5); never a serve bucket
+    (``B <= 704``), whose programs stay the k-fetch's text for text.
+    Readings that put the line there (ms a hop on a v5e over igb's
+    frontier, k-fetch -> one fetch; PERF.md section 6, PR 35): 73728 seeds
+    10.79 -> 3.68 at k=15, 3.62 -> 1.94 at k=5, 2.19 -> 1.58 at k=3;
+    8192 seeds 1.24 -> 0.48 at k=15, 0.44 -> 0.26 at k=5; 4096 seeds
+    0.64 -> 0.24 at k=15; at 2048 and below both read the 0.2 ms of a
+    launch. The line could sit at 4096: no cell has a hop between 1024
+    and 8192 seeds to show it end to end."""
+    far = batch // FAR_SHARE
+    return far if k > 2 and far >= FAR_MIN else 0
+
+
+def _tiled_k_fetch(tiles, base, pos, k):
+    """Position ``p`` of a node sits at tile row ``base + p // 128``, lane
+    ``p % 128``: every drawn position through a row fetch of its own
+    (`_select_lanes`)."""
     rows = base[:, None] + lax.shift_right_logical(pos, LANE.bit_length() - 1)
     rows = jnp.clip(rows, 0, tiles.shape[0] - 1)
     return _select_lanes(tiles, rows, jnp.bitwise_and(pos, LANE - 1), k)
+
+
+def _tiled_one_fetch(tiles, base, pos, k, far, width):
+    """The same ids as `_tiled_k_fetch` where at most ``width`` seeds are
+    ``far`` (some drawn position past their first tile row): ONE
+    ``[B, 128]`` fetch of every seed's first row, from which all its draws
+    below 128 are one-hot lane selects, and the far seeds, compacted by a
+    sort of ``[B]`` keys into a ``[width]`` list, through the k-fetch; a
+    far seed reads its ``k`` ids back by rank (one ``[B]``-row gather of
+    k-int rows)."""
+    B = base.shape[0]
+    row0 = jnp.take(tiles, jnp.clip(base, 0, tiles.shape[0] - 1), axis=0)
+    lane = jnp.bitwise_and(pos, LANE - 1)
+    ar = jnp.arange(LANE, dtype=jnp.int32)
+    near = jnp.stack(
+        [jnp.where(lane[:, j][:, None] == ar[None, :], row0, 0).sum(axis=1)
+         for j in range(k)], axis=1).astype(tiles.dtype)
+    idx = jnp.arange(B, dtype=jnp.int32)
+    # a sort, not a scatter: this chip sorts 1.18M keys in 2.2 ms and
+    # scatters at 45 ns a row (PERF.md section 6); slots past the far
+    # seeds hold B, clipped to a seed whose ids nobody reads back
+    slots = jnp.minimum(jnp.sort(jnp.where(far, idx, B))[:width], B - 1)
+    packed = jnp.concatenate([base[:, None], pos], axis=1)  # int32, as `bd` is
+    picked = jnp.take(packed, slots, axis=0)  # [width, 1 + k]: one descriptor a slot
+    far_ids = _tiled_k_fetch(tiles, picked[:, 0], picked[:, 1:], k)
+    rank = jnp.clip(jnp.cumsum(far.astype(jnp.int32)) - 1, 0, width - 1)
+    return jnp.where(far[:, None], jnp.take(far_ids, rank, axis=0), near)
+
+
+def _tiled_resolve(tiles, base, pos, k):
+    """Resolve drawn positions ``pos [B, k]`` to neighbour ids through the
+    tile table: ``tiles[base + pos // 128, pos % 128]``, bit for bit,
+    whatever the graph. Returns ``(ids [B, k], one_fetch)``, the second an
+    int32 scalar: 1 where the hop went through one fetch a seed. Shared by
+    the uniform, weighted and temporal tiled layers (and `stream.py`'s
+    relocated rows, through ``base``): the ONE position fetch of the tile
+    layout.
+
+    A node's list starts at a tile-row boundary (`build_tiled_host`), so
+    every draw of a node of degree <= 128 reads the SAME row: the k-fetch
+    moves that row k times. Where `far_width` engages the hop, seeds are
+    split by what was drawn, not by a degree table: a seed is *far* when
+    some position of its row of ``pos`` is >= 128 (only a seed of degree
+    > 128 can be; a masked draw of the weighted layers may be, and is then
+    fetched like any other, so masked lanes keep the k-fetch's bits too).
+    If the hop holds at most ``H = far_width(B, k)`` far seeds it takes
+    `_tiled_one_fetch`, else `_tiled_k_fetch`: both under one `lax.cond`
+    on the device-side count, no host read, no cap that can overflow.
+    Readings (v5e; PERF.md section 6, PR 35): igb's second hop (73,728
+    seeds x 15; 5,326 nodes of 1M have a degree over 128, ~3,100 seeds of
+    a frontier are far) 15 gathers of ``[73728, 128]`` at 0.686 ms each ->
+    one, plus a ``[9216]``-wide far list: 10.79 -> 3.86 ms; products'
+    frontiers are ~44% far and take the k-fetch branch on every hop, at
+    the k-fetch's time + 0.02-0.05 ms for the count and the branch."""
+    width = far_width(base.shape[0], k)
+    if not width:
+        return _tiled_k_fetch(tiles, base, pos, k), jnp.zeros((), jnp.int32)
+    far = (pos >= LANE).any(axis=1)
+    fits = far.sum() <= width
+    ids = lax.cond(
+        fits,
+        lambda: _tiled_one_fetch(tiles, base, pos, k, far, width),
+        lambda: _tiled_k_fetch(tiles, base, pos, k),
+    )
+    return ids, fits.astype(jnp.int32)
 
 
 def lane_rows(indices):
@@ -278,6 +369,28 @@ def flat_resolve(indices, ptr, pos, k):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "max_deg"))
+def tiled_weighted_sample_hop(
+    bd: jax.Array,
+    tiles: jax.Array,
+    wtiles: jax.Array,
+    seeds: jax.Array,
+    seed_valid: jax.Array,
+    k: int,
+    key: jax.Array,
+    max_deg: int = 512,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """`tiled_weighted_sample_layer`'s ``(nbrs, valid)`` and, third,
+    `_tiled_resolve`'s ``one_fetch`` scalar, for the caller that counts
+    (`pyg.sage_sampler.sample_dense_program`)."""
+    base, deg = row_windows(bd, seeds, seed_valid)
+    deg = jnp.minimum(deg, max_deg)
+    w_rows = _tiled_payload_window(base, wtiles, max_deg)
+    pos, valid = gumbel_topk_positions(key, deg, k, w_rows)
+    nbrs, one_fetch = _tiled_resolve(tiles, base, pos, k)
+    return nbrs, valid, one_fetch
+
+
+@functools.partial(jax.jit, static_argnames=("k", "max_deg"))
 def tiled_weighted_sample_layer(
     bd: jax.Array,
     tiles: jax.Array,
@@ -300,17 +413,14 @@ def tiled_weighted_sample_layer(
     same scores, same top-k). Same truncation semantics: each row
     considers its first ``min(deg, max_deg)`` edges.
     """
-    base, deg = row_windows(bd, seeds, seed_valid)
-    deg = jnp.minimum(deg, max_deg)
-    w_rows = _tiled_payload_window(base, wtiles, max_deg)
-    pos, valid = gumbel_topk_positions(key, deg, k, w_rows)
-    return _tiled_resolve(tiles, base, pos, k), valid
+    return tiled_weighted_sample_hop(
+        bd, tiles, wtiles, seeds, seed_valid, k, key, max_deg)[:2]
 
 
 def _tiled_payload_window(base, ptiles, max_deg: int):
     """Each row's first ``ceil(max_deg/128)`` PAYLOAD tiles as one
     ``[B, T*128]`` window: T per-row tile fetches, k-split style — a
-    [B, T] 3-D gather compiles pathologically, see `_tiled_resolve`.
+    [B, T] 3-D gather compiles pathologically, see `_select_lanes`.
     The ONE payload-window fetch (weights and timestamps both ride it;
     the temporal-vs-weighted bit-parity pin depends on the two never
     diverging)."""
@@ -386,9 +496,13 @@ def build_tiled_host(
     ``i`` then lives at tile row ``base[i] + p // 128``, lane ``p % 128``
     — so the neighbor fetch becomes 2-D ROW gathers (measured ~115-145M
     rows/s on v5e) + an in-register one-hot lane select, instead of
-    one-element gathers (~45-90M/s). Exact for every
-    degree — no copy-all/hub split. Memory: ceil-padding to 128 costs
-    ~(E + 64*N)/E x the flat CSR (products: 1.45 GB vs 0.49 GB).
+    one-element gathers (~45-90M/s). A node of degree <= 128 has ONE tile
+    row, so at a large hop `_tiled_resolve` fetches every seed's first row
+    once and reads all its draws from it; the seeds with a draw past that
+    row go through a compacted list. Exact for every degree: the RESULT
+    has no copy-all/hub split and no cap, whichever way a hop fetched.
+    Memory: ceil-padding to 128 costs ~(E + 64*N)/E x the flat CSR
+    (products: 1.45 GB vs 0.49 GB).
 
     Replaces the flat-CSR read path of the reference's sample_kernel
     (srcs/cpp/src/quiver/cuda/quiver_sample.cu:134-200) — GPU warps read
@@ -571,7 +685,25 @@ def tiled_temporal_sample_layer(
     w_rows = temporal_weight_rows(ts_rows, t.astype(jnp.float32), recency,
                                   cutoff=cutoff)
     pos, valid = gumbel_topk_positions(key, deg, k, w_rows)
-    return _tiled_resolve(tiles, base, pos, k), valid
+    return _tiled_resolve(tiles, base, pos, k)[0], valid
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def tiled_sample_hop(
+    bd: jax.Array,
+    tiles: jax.Array,
+    seeds: jax.Array,
+    seed_valid: jax.Array,
+    k: int,
+    key: jax.Array,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """`tiled_sample_layer`'s ``(nbrs, valid)`` and, third,
+    `_tiled_resolve`'s ``one_fetch`` scalar, for the caller that counts
+    (`pyg.sage_sampler.sample_dense_program`)."""
+    base, deg = row_windows(bd, seeds, seed_valid)
+    pos, valid = fisher_yates_positions(key, deg, k)
+    nbrs, one_fetch = _tiled_resolve(tiles, base, pos, k)
+    return nbrs, valid, one_fetch
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -587,12 +719,12 @@ def tiled_sample_layer(
 
     Draw-identical to :func:`sample_layer` on the same key (same
     Fisher-Yates positions; only the fetch path differs): positions are
-    resolved via k 2-D row gathers + one-hot lane selects. Measured at
-    products hop-3 shape: fetch 6.5 vs 9.0 ms.
+    resolved by `_tiled_resolve`, one 2-D row gather a seed plus a
+    compacted list of far seeds at a large hop, k row gathers a seed
+    otherwise, and one-hot lane selects. Measured at products hop-3 shape,
+    k-fetch against element gathers: 6.5 vs 9.0 ms.
     """
-    base, deg = row_windows(bd, seeds, seed_valid)
-    pos, valid = fisher_yates_positions(key, deg, k)
-    return _tiled_resolve(tiles, base, pos, k), valid
+    return tiled_sample_hop(bd, tiles, seeds, seed_valid, k, key)[:2]
 
 
 def neighbor_prob(

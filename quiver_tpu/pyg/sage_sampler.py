@@ -40,8 +40,8 @@ from ..ops.sample import (
     pad_widths,
     sample_layer as _sample_layer_op,
     sample_prob as _sample_prob,
-    tiled_sample_layer as _tiled_sample_layer_op,
-    tiled_weighted_sample_layer as _tiled_weighted_sample_layer_op,
+    tiled_sample_hop as _tiled_sample_hop_op,
+    tiled_weighted_sample_hop as _tiled_weighted_sample_hop_op,
     weighted_sample_layer as _weighted_sample_layer_op,
 )
 from ..ops.reindex import local_reindex
@@ -112,6 +112,12 @@ class DenseSample(NamedTuple):
     # `caps_from_counts` to recalibrate instead of re-probing.
     cap_overflow: Optional[jax.Array] = None
     raw_counts: Optional[jax.Array] = None
+    # one_fetch_hops: scalar int32, how many of the call's hops resolved
+    # their positions through one tile-row fetch a seed
+    # (`ops.sample._tiled_resolve`); set by `sample_dense_program` over the
+    # tile layout, None elsewhere (flat layout, host engine, a caller that
+    # binds the hop inside its own program). Nobody reads it inside a step.
+    one_fetch_hops: Optional[jax.Array] = None
 
 
 def sample_dense_fused(
@@ -478,7 +484,7 @@ def caps_from_counts(
     return tuple(caps)
 
 
-def one_hop_binder(layout: str, weighted: bool, max_deg: int):
+def one_hop_binder(layout: str, weighted: bool, max_deg: int, one_fetch=None):
     """``bind(graph) -> sample_fn``: the one-hop op of a TPU-mode sampler
     over the device-array pytree `GraphSageSampler.fused_sample_spec` hands
     out beside it: ``(bd, tiles[, wtiles])`` tiled, ``(windows, rows)``
@@ -487,21 +493,30 @@ def one_hop_binder(layout: str, weighted: bool, max_deg: int):
     indices, weights)`` weighted flat (1-D arrays: that one stacks its
     table in the program, `ops.sample.row_windows`). The one source of
     that closure: every program that samples in-jit calls ``bind`` on its
-    TRACED graph argument, and hands the pair through as it is."""
+    TRACED graph argument, and hands the pair through as it is.
+    ``one_fetch``: a list to which every hop over the tile layout appends
+    its scalar of `ops.sample._tiled_resolve`, for a caller that is one
+    program and adds them up (`sample_dense_program`); ``sample_fn`` keeps
+    its ``(nbrs, valid)`` either way."""
+    tally = (lambda flag: None) if one_fetch is None else one_fetch.append
 
     def bind(g):
         if layout == "tiled" and weighted:
             bd, tiles, wtiles = g
 
             def sample_fn(cur, cur_valid, k, key):
-                return _tiled_weighted_sample_layer_op(
+                nbrs, valid, flag = _tiled_weighted_sample_hop_op(
                     bd, tiles, wtiles, cur, cur_valid, k, key, max_deg
                 )
+                tally(flag)
+                return nbrs, valid
         elif layout == "tiled":
             bd, tiles = g
 
             def sample_fn(cur, cur_valid, k, key):
-                return _tiled_sample_layer_op(bd, tiles, cur, cur_valid, k, key)
+                nbrs, valid, flag = _tiled_sample_hop_op(bd, tiles, cur, cur_valid, k, key)
+                tally(flag)
+                return nbrs, valid
         elif weighted:
             indptr, indices, w = g
 
@@ -530,15 +545,18 @@ def sample_dense_program(key0, call, seeds, graph, *, sizes, caps, dedup, hop):
     ARGUMENTS (`fused_sample_spec` says why; a seed baked in would also
     compile anew for every seed); ``hop`` is `one_hop_binder`'s argument
     triple. Traced once per batch shape and static set, for every sampler
-    of the process: a twin built for warm-up warms the one it stands for."""
+    of the process: a twin built for warm-up warms the one it stands for.
+    ``one_fetch_hops`` of the result adds up the hops' flags over the tile
+    layout (`DenseSample`); the flat layout has none to add."""
     key = jax.random.fold_in(key0, call)
-    sample_fn = one_hop_binder(*hop)(graph)
+    fetches: List[jax.Array] = []
+    sample_fn = one_hop_binder(*hop, one_fetch=fetches)(graph)
     if dedup:
         ds = sample_dense_pure(None, None, key, seeds, sizes, caps, sample_fn=sample_fn)
     else:
         ds = sample_dense_fused(None, None, key, seeds, sizes, sample_fn=sample_fn)
     # a Python int leaf would come back as a device array
-    return ds._replace(batch_size=None)
+    return ds._replace(batch_size=None, one_fetch_hops=sum(fetches) if fetches else None)
 
 
 class GraphSageSampler:

@@ -823,15 +823,21 @@ def _weighted_graph():
 def _eager_sample_dense(sampler, call, seeds):
     """The op-by-op composition `sample_dense` ran before it was one program:
     the pure functions called directly with the key of call ``call``."""
-    from quiver_tpu.pyg.sage_sampler import sample_dense_fused, sample_dense_pure
+    from quiver_tpu.pyg.sage_sampler import (
+        one_hop_binder, sample_dense_fused, sample_dense_pure,
+    )
 
-    graph, bind, id_dtype = sampler._graph_and_bind()
+    graph, _, id_dtype = sampler._graph_and_bind()
+    fetches = []  # the tile layout's one-fetch flags, a hop each
+    sample_fn = one_hop_binder(*sampler._hop(), one_fetch=fetches)(graph)
     key = jax.random.fold_in(jax.random.key(sampler._seed), call)
     seeds = jnp.asarray(np.asarray(seeds), id_dtype)
     if sampler.dedup:
-        return sample_dense_pure(None, None, key, seeds, sampler.sizes, sampler.caps,
-                                 sample_fn=bind(graph))
-    return sample_dense_fused(None, None, key, seeds, sampler.sizes, sample_fn=bind(graph))
+        ds = sample_dense_pure(None, None, key, seeds, sampler.sizes, sampler.caps,
+                               sample_fn=sample_fn)
+    else:
+        ds = sample_dense_fused(None, None, key, seeds, sampler.sizes, sample_fn=sample_fn)
+    return ds._replace(one_fetch_hops=sum(fetches) if fetches else None)
 
 
 def _assert_same_bits(got, want):
@@ -864,6 +870,8 @@ def test_one_program_sample_dense_matches_the_eager_composition(graph, mode):
             ds._replace(batch_size=None)))
         assert (ds.cap_overflow is None) == (not sampler.dedup)
         assert all((adj.cols is None) == (not sampler.dedup) for adj in ds.adjs)
+        # hops of 16 and 80 seeds keep the k-fetch; the flat layout counts nothing
+        assert ds.one_fetch_hops is None if mode == "flat" else int(ds.one_fetch_hops) == 0
         _assert_same_bits(ds, _eager_sample_dense(sampler, call, seeds))
     assert sampler._call == 3
 
@@ -964,3 +972,136 @@ def test_auto_grow_caps_builds_one_program_a_regrow_and_ends_without_overflow(gr
     _assert_same_bits(ds, _eager_sample_dense(s, rungs - 1, seeds))
     s.sample_dense(seeds)  # the grown caps hold: no new program
     assert s._call == rungs + 1 and _programs_built() == rungs
+
+
+# -- the tile layout's position fetch: one row a seed (ops/sample._tiled_resolve) --
+
+
+def _tile_table(degs, n_ids, seed=0):
+    from quiver_tpu.ops.sample import build_tiled_host
+
+    rng = np.random.default_rng(seed)
+    indptr = np.zeros(len(degs) + 1, np.int64)
+    np.cumsum(degs, out=indptr[1:])
+    return build_tiled_host(indptr, rng.integers(0, n_ids, indptr[-1]))
+
+
+def _degrees(case, batch):
+    rng = np.random.default_rng(7)
+    degs = rng.integers(0, 129, batch)  # every list inside its first tile row
+    if case == "far_under_width":
+        degs[rng.choice(batch, 600, replace=False)] = rng.integers(2000, 6000, 600)
+    elif case == "far_over_width":
+        degs[rng.choice(batch, 2000, replace=False)] = rng.integers(4000, 6000, 2000)
+    elif case == "degrees_127_128_129":
+        degs = np.tile([127, 128, 129], batch // 3 + 1)[:batch]
+    return degs
+
+
+# case -> (seeds of the hop, fan-out, the flag the case was built to read)
+RESOLVE_CASES = {
+    "no_far_seed": (8192, 15, 1),
+    "far_under_width": (8192, 15, 1),
+    "far_over_width": (8192, 5, 0),        # ~2000 far seeds against a list of 1024
+    "degrees_127_128_129": (8192, 15, 1),
+    "invalid_seeds": (8192, 5, 1),
+    "masked_draws_past_the_first_row": (8192, 5, 1),
+    "k1": (8192, 1, 0), "k2": (8192, 2, 0), "k5": (8192, 5, 1), "k15": (8192, 15, 1),
+    "below_the_static_line": (8184, 15, 0),
+}
+
+
+@pytest.mark.parametrize("case", list(RESOLVE_CASES))
+def test_tiled_resolve_reads_what_the_plain_index_reads(case):
+    """`_tiled_resolve` (one fetch of a seed's first tile row, the far seeds
+    compacted, or the k-fetch where the hop is small, k <= 2 or the far
+    seeds outnumber the list) against ``tiles[base + pos // 128, pos % 128]``,
+    every lane of every seed, and its flag against what the case was built
+    to take."""
+    from quiver_tpu.ops import sample as ops
+
+    batch, k, flag = RESOLVE_CASES[case]
+    degs = _degrees("far_under_width" if case in ("k1", "k2", "k5", "k15") else case, batch)
+    bd, tiles = _tile_table(degs, 1 << 20)
+    rng = np.random.default_rng(11)
+    seeds = rng.permutation(batch).astype(np.int32)
+    seed_valid = np.ones(batch, bool)
+    if case == "invalid_seeds":
+        seed_valid[::3] = False
+        seeds[::6] = np.iinfo(np.int32).max  # garbage where invalid
+        seeds[3::6] = -5
+        degs = np.asarray(degs).copy()
+        degs[::50] = 0
+        bd, tiles = _tile_table(degs, 1 << 20)
+    base, deg = ops.row_windows(jnp.asarray(bd), jnp.asarray(seeds), jnp.asarray(seed_valid))
+    pos, _ = ops.fisher_yates_positions(jax.random.key(3), deg, k)
+    if case == "masked_draws_past_the_first_row":
+        # what a weighted layer's masked slots may hold: any position of the window
+        stray = rng.random((batch, k)) < 0.01
+        pos = jnp.where(jnp.asarray(stray), jnp.asarray(rng.integers(0, 512, (batch, k)), jnp.int32), pos)
+    program = jax.jit(ops._tiled_resolve, static_argnums=3)
+    ids, one_fetch = program(jnp.asarray(tiles), base, pos, k)
+    base_h, pos_h = np.asarray(base, np.int64), np.asarray(pos, np.int64)
+    rows = np.clip(base_h[:, None] + pos_h // ops.LANE, 0, tiles.shape[0] - 1)
+    assert ids.dtype == tiles.dtype and ids.shape == (batch, k)
+    np.testing.assert_array_equal(np.asarray(ids), tiles[rows, pos_h % ops.LANE])
+    assert int(one_fetch) == flag
+    far = int((pos_h >= ops.LANE).any(axis=1).sum())
+    width = ops.far_width(batch, k)
+    text = program.lower(jnp.asarray(tiles), base, pos, k).as_text()
+    if width:  # both branches are in the program and the count chose this one
+        assert width == batch // 8 and (far <= width) == bool(flag) and "stablehlo.case" in text
+        if case in ("far_under_width", "degrees_127_128_129", "k5", "k15",
+                    "masked_draws_past_the_first_row"):
+            assert far > 0
+        if case == "degrees_127_128_129":  # only a list of 129 can reach a second row
+            assert set(np.asarray(deg)[(pos_h >= ops.LANE).any(axis=1)]) == {129}
+    else:  # the parent's program: no branch to choose
+        assert "stablehlo.case" not in text and "stablehlo.sort" not in text
+
+
+# sizes and the frontier each case hands the hops: the flag count it must read
+DENSE_CASES = {
+    "every_hop_one_fetch": dict(hub_share=0.0, dedup=True, caps=(16384, 32768), hops=2),
+    "no_hop_one_fetch": dict(hub_share=0.5, dedup=True, caps=(16384, 32768), hops=0),
+    "fused_every_hop": dict(hub_share=0.0, dedup=False, caps=None, hops=2),
+    "small_batch_keeps_the_k_fetch": dict(hub_share=0.0, dedup=True, caps=None,
+                                          hops=0, batch=1024),
+}
+
+
+@pytest.mark.parametrize("case", list(DENSE_CASES))
+def test_sample_dense_is_the_k_fetch_samplers_sample_bit_for_bit(case, monkeypatch):
+    """`sample_dense` on one key with the k-fetch forced on every hop (the
+    sampler as it was) and as it is: ``n_id``, ``count``, every block and
+    counter equal, and ``one_fetch_hops`` reads what the graph was built to
+    make the hops take."""
+    from quiver_tpu.ops import sample as ops
+
+    spec = DENSE_CASES[case]
+    n, batch = 12000, spec.get("batch", 8192)
+    rng = np.random.default_rng(5)
+    degs = rng.integers(1, 20, n)
+    hubs = rng.random(n) < spec["hub_share"]
+    degs[hubs] = rng.integers(2000, 3000, int(hubs.sum()))
+    indptr = np.zeros(n + 1, np.int64)
+    np.cumsum(degs, out=indptr[1:])
+    topo = CSRTopo(indptr=indptr, indices=rng.integers(0, n, indptr[-1]))
+    seeds = rng.choice(n, batch, replace=False)
+
+    def sample():
+        sampler = GraphSageSampler(topo, sizes=[4, 3], mode="TPU", seed=9,
+                                   dedup=spec["dedup"], caps=spec["caps"])
+        return sampler.sample_dense(seeds)
+
+    monkeypatch.setattr(ops, "far_width", lambda batch, k: 0)
+    jax.clear_caches()
+    before = sample()
+    monkeypatch.undo()
+    jax.clear_caches()
+    after = sample()
+    assert int(before.one_fetch_hops) == 0 and int(after.one_fetch_hops) == spec["hops"]
+    _assert_same_bits(after._replace(one_fetch_hops=None),
+                      before._replace(one_fetch_hops=None))
+    if spec["dedup"]:
+        assert int(after.cap_overflow) == 0

@@ -57,7 +57,7 @@ sys.path.insert(0, REPO)
 from chip_smoke import PRODUCTS, SIZES, make_model, make_train_step, ring_sampler
 from quiver_tpu.feature import _padded_gather, _padded_gather_ordered
 from quiver_tpu.inference import make_serve_step
-from quiver_tpu.ops.sample import LANE, tiled_sample_layer
+from quiver_tpu.ops.sample import LANE, far_width, pad_widths, tiled_sample_layer
 from quiver_tpu.parallel import make_sharded_topo_train_step, make_sharded_train_step
 from quiver_tpu.parallel.topology import ShardedTopology
 from quiver_tpu.pyg.sage_sampler import sample_dense_fused, sample_dense_pure
@@ -170,7 +170,7 @@ def compile_serve_bucket(v5e, bucket=SERVE_BUCKET):
          _sds((N, PRODUCTS["dim"]), jnp.float32)), one_chip)
     compiled = jax.jit(serve_step, donate_argnums=(2,)).lower(
         *args, None, _struct(_graph_structs(), one_chip)).compile()
-    return _fits(compiled, f"serve bucket {bucket}")
+    return _fits(compiled, f"serve bucket {bucket}"), compiled.as_text()
 
 
 def compile_sharded_topo_step(v5e, n_devices=4, batch=PRODUCTS["batch"]):
@@ -270,6 +270,30 @@ def _results_of_size(text, size):
     has_size = re.compile(rf"\[(?:\d+,)*{size}(?:,\d+)*\]")
     lines = re.findall(r"^\s*(?:ROOT )?%\S+ = (.*?) ([\w-]+)\(", text, re.M)
     return {opcode for result, opcode in lines if has_size.search(result)}
+
+
+def _computations(text):
+    """``{name: [instruction lines]}`` of an optimized HLO text."""
+    out, body = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.-]+) \(.*\{\s*$", line)
+        if head:
+            body = out.setdefault(head.group(1), [])
+        elif body is not None and " = " in line:
+            body.append(line.strip())
+    return out
+
+
+def _tile_fetch_branches(text, width, k):
+    """The `conditional` of `ops.sample._tiled_resolve` at a hop of ``width``
+    seeds and fan-out ``k``: how many ``[width, 128]`` row gathers each of
+    its two branches holds, (one-fetch branch, k-fetch branch)."""
+    comps = _computations(text)
+    (cond,) = [line for body in comps.values() for line in body
+               if re.match(rf"(?:ROOT )?%\S+ = \(?s32\[{width},{k}\]\S* conditional\(", line)]
+    k_fetch, one_fetch = re.search(r"branch_computations=\{%([\w.-]+), %([\w.-]+)\}", cond).groups()
+    rows = re.compile(rf"(?:ROOT )?%\S+ = s32\[{width},{LANE}\]\S* fusion\(")
+    return tuple(sum(bool(rows.match(line)) for line in comps[name]) for name in (one_fetch, k_fetch))
 
 
 def _entry_operations(compiled):
@@ -439,7 +463,10 @@ def test_dedup_train_step_compiles_for_v5e(v5e):
 
 
 def test_serve_bucket_compiles_for_v5e(v5e):
-    compile_serve_bucket(v5e, 8)
+    # hops of 8, 128 and 1408 seeds keep the tile layout's k-fetch (as every
+    # hop of the benchmark's serve cell does, 704 seeds at the widest): no
+    # branch in the program, which is the parent's text for text
+    assert " conditional(" not in compile_serve_bucket(v5e, 8)[1]
 
 
 def test_sharded_topo_step_compiles_for_four_v5e_at_products_size(v5e):
@@ -522,6 +549,14 @@ def test_sample_dense_program_compiles_for_v5e_at_the_train_cells_shapes(v5e, ce
     graph_bytes = 4 * (2 * shape["nodes"] + LANE * shape["tile_rows"])
     assert graph_bytes <= memory.argument_size_in_bytes < graph_bytes + 2**20
     assert memory.temp_size_in_bytes < 0.5 * 2**30, memory.temp_size_in_bytes / 2**30
+    # the tile fetch (`ops.sample._tiled_resolve`, PR 35): at every hop of
+    # 8,192 seeds and more, a branch with ONE first-row gather a seed (the
+    # far seeds' list is an eighth as wide) beside the k-fetch's k
+    widths = pad_widths(shape["batch"], shape["sizes"], shape["caps"])
+    engaged = [(width, k) for width, k in zip(widths, shape["sizes"]) if far_width(width, k)]
+    assert text.count(" conditional(") == len(engaged)  # the smaller hops hold no branch
+    for width, k in engaged:
+        assert _tile_fetch_branches(text, width, k) == (1, k), (width, k)
 
 
 # papers100M-sage-tiered.train-hot6g (qbench/workloads): half of
@@ -592,6 +627,7 @@ def test_tiered_step_and_flat_sampler_compile_for_v5e_at_papers_size(v5e):
     rows = f"s32[{TIERED['edge_rows']},{LANE}]"
     entry = text[text.index("ENTRY"):]
     assert re.search(rf"= {re.escape(rows)}\S* parameter\(", entry)
+    assert " conditional(" not in text  # `flat_resolve` fetches every position: no branch
     # every fetch from the edges is a row gather: [W, 128] out of [R, 128]
     fetches = re.findall(rf"\(param_\S+: {re.escape(rows)}, param_\S+: s32\[(\d+)\]\) -> (\S+) ", text)
     assert len(fetches) == sum(SIZES), fetches  # one row gather a drawn position
@@ -669,8 +705,8 @@ if __name__ == "__main__":
          lambda: compile_train_step(desc, None, PRODUCTS["batch"])),
         ("dedup train step, batch 1024",
          lambda: compile_train_step(desc, DEDUP_CAPS, PRODUCTS["batch"])),
-        ("serve bucket 64", lambda: compile_serve_bucket(desc)),
-        ("serve bucket 1", lambda: compile_serve_bucket(desc, 1)),
+        ("serve bucket 64", lambda: compile_serve_bucket(desc)[0]),
+        ("serve bucket 1", lambda: compile_serve_bucket(desc, 1)[0]),
         ("sharded-topology step at the products size, 4 devices",
          lambda: compile_sharded_topo_step(desc)),
         ("sharded-topology step at the products size, 1 device (the twin)",
