@@ -1181,8 +1181,8 @@ class _Flush:
 
     __slots__ = ("keys", "slots", "params", "seeds", "bucket", "ds", "key",
                  "padded", "extra", "error", "fid", "ids", "rids",
-                 "tenant_ix", "graph_version", "binding", "t_dispatch",
-                 "t_done")
+                 "tenant_ix", "graph_version", "binding", "t_begin",
+                 "t_dispatch", "t_done")
 
     def __init__(self, keys, slots, params):
         self.keys = keys
@@ -1210,9 +1210,10 @@ class _Flush:
         # draw (assemble and seal happen under one _seq hold, so nothing
         # can interleave an increment between them)
         self.fid = -1
-        # the dispatch stage's two stamps (`ServeEngine.flush`), kept for
-        # the per-request stage split `_observe_stages` adds when tracing
-        self.t_dispatch = self.t_done = 0.0
+        # `ServeEngine.flush`'s stamps, kept for the per-request stage
+        # split `_observe_stages` adds when tracing: the call of `flush()`
+        # (read only while tracing) and the dispatch stage's two
+        self.t_begin = self.t_dispatch = self.t_done = 0.0
 
 
 def _admit_chunk_fast(eng, keys, nodes, tenants, i, now, events,
@@ -1459,9 +1460,14 @@ def _observe_stages(fl, t_res: float) -> None:
     of `EventJournal.request_breakdown`, with one difference: a waiter that
     coalesced onto the flush after a stage began is charged that stage
     from its own arrival, so a request's three stages add up to the
-    latency ``stats.latency`` recorded for it."""
+    latency ``stats.latency`` recorded for it. ``quiver.serve.pending`` is
+    the first part of the queue stage: submit stamp -> the call of the
+    `flush()` that took the request (0 for a waiter that came later); what
+    is left of the queue stage is spent inside that flush, under the
+    per-flush spans ``seq_wait``, ``assemble``, ``window_wait``, ``seal``."""
     t0s = np.fromiter((w[0] for s in fl.slots for w in s.waiters), np.float64)
     t_disp, t_done = fl.t_dispatch, fl.t_done
+    observe("quiver.serve.pending", np.maximum(fl.t_begin - t0s, 0.0))
     observe("quiver.serve.queue", np.maximum(t_disp - t0s, 0.0))
     observe("quiver.serve.device",
             np.maximum(t_done - np.maximum(t0s, t_disp), 0.0))
@@ -1703,6 +1709,10 @@ class ServeEngine:
         self._window = threading.BoundedSemaphore(self.config.max_in_flight)
         self._inflight_flushes = 0             # guarded by _lock
         self._dispatch_index = 0               # guarded by _seq
+        # `pump()` calls ever, and how many of them came before the newest
+        # flush resolved: `quiver.serve.pumps` observes the difference
+        # (plain ints: a lost update costs a count, no lock)
+        self._pumps = self._pumps_observed = 0
         # round-24 commit serialization: one zero-stall commit at a time
         # (update_graph / expire_edges / compact_graph / the lifecycle
         # daemons) — the off-fence build phase must not interleave with
@@ -1745,7 +1755,9 @@ class ServeEngine:
         contract rides this exact admission sequence."""
         if not trace_enabled():  # the one per-request site: off, no object
             return self.submit_many((node_id,), tenant=tenant)[0]
-        with trace_scope("quiver.serve.submit"):
+        # registry and timeline, no annotation: 64k profiler events a window
+        # were most of what tracing cost the median, and nothing read them
+        with trace_scope("quiver.serve.submit", annotate=False):
             return self.submit_many((node_id,), tenant=tenant)[0]
 
     def submit_many(self, node_ids, t=None,
@@ -2043,6 +2055,7 @@ class ServeEngine:
         ``max_delay_ms`` demands it. Returns seeds dispatched (0 if the
         policy held). This is the deterministic-test / external-event-loop
         surface; the background threads just call it on a poll timer."""
+        self._pumps += 1  # a plain int, no lock: `quiver.serve.pumps` reads it
         return self.flush() if self.should_flush() else 0
 
     # -- the three flush stages -------------------------------------------
@@ -2277,8 +2290,13 @@ class ServeEngine:
             self.stats.spans.record("resolve", t_res0, self._clock())
             self.journal.record_many((("resolve", -1, fl.fid,
                                        len(fl.keys), 0),))
+        # `pump()` calls since the flush before this one, traced or not (two
+        # flushes resolving at once split the count between them)
+        pumps = self._pumps
         if fl.error is None and trace_enabled():
             _observe_stages(fl, now)
+            observe("quiver.serve.pumps", pumps - self._pumps_observed)
+        self._pumps_observed = pumps
 
     def flush(self) -> int:
         """Dispatch up to ``max_batch`` pending unique seeds as one bucket-
@@ -2297,12 +2315,22 @@ class ServeEngine:
         key stream stay deterministic at any admission interleaving."""
         fl = None
         have_permit = False
+        # while tracing, the call's own stamp: a request is PENDING until
+        # here and inside this flush from here (`_observe_stages`)
+        t_begin = self._clock() if trace_enabled() else 0.0
+        # entered and left by hand: it ends INSIDE `with self._seq`, which
+        # stays a `with` so that no interrupt can leave the lock held
+        seq_wait = trace_scope("quiver.serve.seq_wait").__enter__()
         try:
             with self._seq:
-                # spans open AFTER _seq is held, and the window wait is
-                # excluded: a caller blocked behind another flush (or a
-                # full window) is idle, not working, and counting the wait
-                # would fake stage overlap
+                seq_wait.set(fid=self._dispatch_index + 1)
+                seq_wait.__exit__(None, None, None)
+                # `stats.spans` open AFTER _seq is held, and leave the
+                # window wait out: a caller blocked behind another flush
+                # (or a full window) is idle, not working, and counting the
+                # wait there would fake stage overlap. The two waits have
+                # spans of their own (`seq_wait`, `window_wait`): they are
+                # part of the queue a request sits in
                 t0 = self._clock()
                 with trace_scope("quiver.serve.assemble",
                                  fid=self._dispatch_index + 1):
@@ -2311,6 +2339,7 @@ class ServeEngine:
                     self.stats.spans.record("assemble", t0, self._clock())
                 if fl is None:
                     return 0
+                fl.t_begin = t_begin
                 # flush-ahead prefetch: issue the expected closure's disk
                 # reads NOW, before the window wait — they land while the
                 # previous flush's dispatch (and this one's window wait)
@@ -2322,13 +2351,14 @@ class ServeEngine:
                 try:
                     jr = self.journal
                     t_w0 = self._clock() if jr.enabled else 0.0
-                    self._window.acquire()
-                    have_permit = True
+                    with trace_scope("quiver.serve.window_wait", fid=fl.fid):
+                        self._window.acquire()
+                        have_permit = True
                     if jr.enabled:
                         jr.emit("window_wait", -1, fl.fid,
                                 self._clock() - t_w0)
                     t0 = self._clock()
-                    with trace_scope("quiver.serve.assemble", fid=fl.fid):
+                    with trace_scope("quiver.serve.seal", fid=fl.fid):
                         self._seal_assembled(fl)  # errors land in fl.error
                     self.stats.spans.record("assemble", t0, self._clock())
                 finally:

@@ -7,9 +7,12 @@ Re-design of the reference's observability surface (SURVEY.md section 5):
   (include/quiver/trace.hpp:6-14, setup.py:45-46) -> :class:`trace_scope`,
   the library's one span primitive: on when the same env var is set or a
   `jax.profiler` session is recording, each span written to the profiler
-  (on the device lines' clock) and aggregated in a process-local registry
-  (:func:`trace_report`); :func:`observe` adds durations a caller computed
-  from stamps it already held;
+  (on the device lines' clock), aggregated in a process-local registry
+  (:func:`trace_report`) and kept with its two stamps on a bounded timeline
+  (:func:`trace_timeline`) that a reader can lay on the device trace;
+  :func:`observe` adds durations a caller computed from stamps it already
+  held; while spans record, a watch thread times its own 5 ms sleeps and
+  names the process's stalls (:func:`stall_report`);
 - ad-hoc benchmark metrics (SEPS, benchmarks/sample/bench_sampler.py:14-16;
   GB/s, benchmarks/feature/bench_feature.py:44-46) -> :func:`seps` /
   :func:`gbps` helpers so every bench reports identically.
@@ -18,8 +21,10 @@ Re-design of the reference's observability surface (SURVEY.md section 5):
 from __future__ import annotations
 
 import bisect
+import gc
 import math
 import os
+import resource
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -145,48 +150,72 @@ class trace_scope:
     clock is read and nothing is recorded. On, the span is written to the
     profiler as a ``TraceAnnotation(name, **ids)`` (it lands on the host
     plane of the session's ``.xplane.pb``, on the clock of the device
-    lines, with ``ids`` as the event's stats) and ``(1, duration)`` is
-    added to the registry under ``name``. While `jax.jit` (or any other
-    transformation) traces the enclosing function nothing is recorded:
-    that would time the tracing, once, and not the work.
+    lines, with ``ids`` as the event's stats), ``(1, duration)`` is added
+    to the registry under ``name`` and ``(name, t0, t1, thread id, ids)``
+    to the timeline (`trace_timeline`), the duration being ``t1 - t0`` of
+    those two clock reads. While `jax.jit` (or any other transformation)
+    traces the enclosing function nothing is recorded: that would time the
+    tracing, once, and not the work.
 
     JAX dispatch is asynchronous, so a bare wall clock measures *enqueue*
     time, not device time. Pass the scope's output arrays via ``sync=`` (or
     assign them inside: ``with trace_scope("s") as b: b.sync = out``) and
     the scope calls ``jax.block_until_ready`` before stopping the clock.
-    No hot path does: a wait changes what it measures."""
+    No hot path does: a wait changes what it measures.
 
-    __slots__ = ("name", "sync", "_ids", "_span", "_t0")
+    ``annotate=False`` leaves the profiler out: registry and timeline alone.
+    The one site that runs once a REQUEST takes it (`ServeEngine.submit`):
+    its 64k annotations a window were 6.6 of the 11.2% that tracing cost the
+    serve cell's median, and no reader looked at them (PERF.md, PR 36)."""
 
-    def __init__(self, name: str, sync=None, **ids):
+    __slots__ = ("name", "sync", "_annotate", "_ids", "_span", "_t0")
+
+    def __init__(self, name: str, sync=None, annotate: bool = True, **ids):
         self.name = name
         self.sync = sync
+        self._annotate = annotate
         self._ids = ids
-        self._span = None
+        self._span = self._t0 = None
 
     def __enter__(self) -> "trace_scope":
         if trace_enabled() and jax.core.trace_ctx.is_top_level():
-            self._span = TraceAnnotation(self.name, **self._ids)
-            self._span.__enter__()
+            if _watch is None:
+                _start_watch()
+            if self._annotate:
+                self._span = TraceAnnotation(self.name, **self._ids)
+                self._span.__enter__()
             self._t0 = time.perf_counter()
         return self
 
     def set(self, **ids) -> None:
-        """Ids known only once the work ran (was a program built?): added
-        to the open span's stats; nothing when the span is off."""
-        if self._span is not None:
-            self._span.set_metadata(**ids)
+        """Ids known only once the work ran (was a program built? which
+        flush is this?): added to the open span's stats and to its
+        timeline entry; nothing when the span is off."""
+        if self._t0 is not None:
+            if self._span is not None:
+                self._span.set_metadata(**ids)
+            self._ids.update(ids)
 
     def __exit__(self, *exc) -> None:
-        span = self._span
-        if span is None:
+        t0 = self._t0
+        if t0 is None:
             return
         if self.sync is not None:
             jax.block_until_ready(self.sync)
-        dt = time.perf_counter() - self._t0
-        span.__exit__(*exc)
-        self._span = None
-        _add(self.name, 1, dt, dt)
+        t1 = time.perf_counter()
+        if self._span is not None:
+            self._span.__exit__(*exc)
+            self._span = None
+        self._t0 = None
+        _record_span(self.name, t0, t1, self._ids)
+
+
+def _record_span(name: str, t0: float, t1: float, ids=None) -> None:
+    """One closed span on the calling thread: the registry gets its
+    duration, the timeline its two stamps."""
+    dt = t1 - t0
+    _add(name, 1, dt, dt)
+    _timeline.record(name, t0, t1, threading.get_ident(), ids or None)
 
 
 def observe(name: str, seconds) -> None:
@@ -196,6 +225,8 @@ def observe(name: str, seconds) -> None:
     whose durations cost something to compute asks `trace_enabled` first."""
     if not trace_enabled():
         return
+    if _watch is None:
+        _start_watch()
     arr = np.asarray(seconds, np.float64).reshape(-1)
     if arr.size:
         _add(name, arr.size, float(arr.sum()), float(arr.max()))
@@ -306,8 +337,10 @@ class SpanRecorder:
 
         self._spans = collections.deque(maxlen=maxlen)
 
-    def record(self, stage: str, t0: float, t1: float) -> None:
-        self._spans.append((stage, t0, t1))
+    def record(self, stage: str, t0: float, t1: float, *more) -> None:
+        """``more`` rides behind the triple (the library's timeline keeps a
+        span's thread and ids there); the summary reads the triple alone."""
+        self._spans.append((stage, t0, t1, *more))
 
     def _snapshot(self) -> tuple:
         return _snapshot_deque(self._spans)
@@ -352,7 +385,7 @@ class SpanRecorder:
             return {}
         busy: dict = {}
         events = []
-        for stage, t0, t1 in spans:
+        for stage, t0, t1, *_ in spans:
             busy[stage] = busy.get(stage, 0.0) + (t1 - t0)
             events.append((t0, 1))
             events.append((t1, -1))
@@ -376,6 +409,147 @@ class SpanRecorder:
                 round((total_busy - covered) / total_busy, 4) if total_busy else 0.0
             ),
         }
+
+
+# -- the timeline, and the stall watch ------------------------------------------
+
+# Every span that closes while `trace_enabled`, as ``(name, t0, t1, thread
+# id, ids or None)`` on `time.perf_counter`'s clock: the one store behind
+# `trace_timeline`. A profiler session's clock differs from any monotonic
+# clock by a constant for the session, and a reader finds that constant from
+# spans it sees on both sides (qbench/readers/span_device_gap.py), so these
+# stamps can be laid on the device lines. Bounded: the serve cell's traced
+# window leaves ~85k entries (one a request, six a flush), a train cell a few
+# thousand; the newest TIMELINE_SPANS win.
+TIMELINE_SPANS = 400_000
+_timeline = SpanRecorder(maxlen=TIMELINE_SPANS)
+
+TICK_S = 0.005    # the watch's sleep
+STALL_S = 0.030   # a tick late by more than this is a `quiver.host.stall`
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_watch: Optional[threading.Thread] = None   # the running watch, if any
+_watch_lock = threading.Lock()
+_gc_started: Dict[int, float] = {}          # thread id -> its collection's start
+
+
+def trace_timeline(reset: bool = False) -> Tuple:
+    """The timeline's entries, oldest first: ``(name, t0, t1, thread id,
+    ids)`` with both stamps on `time.perf_counter`'s clock and ``ids`` a
+    dict or None. ``reset=True`` empties it behind the copy."""
+    out = _timeline._snapshot()
+    if reset:
+        _timeline.clear()
+    return out
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """`gc.callbacks` entry. A collection starts wherever a thread
+    allocates, inside `_add`'s locked section too, so this takes no lock:
+    the timeline alone (one atomic append), not the registry."""
+    tid = threading.get_ident()
+    if phase == "start":
+        _gc_started[tid] = time.perf_counter()
+        return
+    t0 = _gc_started.pop(tid, None)
+    if t0 is not None:
+        _timeline.record("quiver.host.gc", t0, time.perf_counter(), tid,
+                         {"generation": info["generation"],
+                          "collected": info["collected"]})
+
+
+def _on_compile(event: str, duration: float, **_) -> None:
+    if event == COMPILE_EVENT:
+        t1 = time.perf_counter()
+        _record_span("quiver.host.compile", t1 - duration, t1)
+
+
+def _start_watch() -> None:
+    global _watch
+    with _watch_lock:
+        if _watch is None:
+            _watch = threading.Thread(target=_watch_loop,
+                                      name="quiver-trace-watch", daemon=True)
+            _watch.start()
+
+
+def _host_counters(schedstat_fd: Optional[int]) -> Tuple[float, ...]:
+    """(process CPU s, this thread's run-queue wait s, minor faults, major
+    faults, involuntary context switches): what a stall's ids are deltas of."""
+    wait_s = 0.0
+    if schedstat_fd is not None:
+        # "<on-cpu ns> <run-queue wait ns> <timeslices>" of the opening thread
+        wait_s = int(os.pread(schedstat_fd, 96, 0).split()[1]) * 1e-9
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return (time.process_time(), wait_s, ru.ru_minflt, ru.ru_majflt, ru.ru_nivcsw)
+
+
+def _watch_loop() -> None:
+    """The stall watch: sleeps `TICK_S` at a time for as long as spans
+    record, and observes by how much each sleep overshot under
+    ``quiver.host.tick``: the time this thread, runnable again, waited for
+    a core and for the interpreter. A tick late by more than `STALL_S` goes
+    to the timeline as ``quiver.host.stall`` with the tick's deltas of
+    `_host_counters`: CPU near the wall time = something in the process held
+    the interpreter (`stall_report` says which spans were open); CPU ~0 and
+    run-queue wait ~wall = runnable, and no core; both ~0 = every thread
+    blocked, or the guest paused. The collector's and the compiler's own
+    spans (``quiver.host.gc``, ``quiver.host.compile``) are registered
+    here, for as long as the watch runs."""
+    global _watch
+    import jax.monitoring
+
+    try:
+        fd = os.open("/proc/thread-self/schedstat", os.O_RDONLY)
+    except OSError:  # a guest without schedstats: the wait reads 0
+        fd = None
+    gc.callbacks.append(_on_gc)
+    jax.monitoring.register_event_duration_secs_listener(_on_compile)
+    try:
+        while trace_enabled():
+            before = _host_counters(fd)
+            t0 = time.perf_counter()
+            time.sleep(TICK_S)
+            t1 = time.perf_counter()
+            if not trace_enabled():  # switched off over the sleep: its
+                break                # reader has taken the registry already
+            late = max(t1 - t0 - TICK_S, 0.0)
+            _add("quiver.host.tick", 1, late, late)
+            if late > STALL_S:
+                cpu, wait, minflt, majflt, nivcsw = (
+                    b - a for a, b in zip(before, _host_counters(fd)))
+                _timeline.record(
+                    "quiver.host.stall", t0 + TICK_S, t1, threading.get_ident(),
+                    {"cpu_s": cpu, "runq_wait_s": wait, "minor_faults": minflt,
+                     "major_faults": majflt, "invol_switches": nivcsw})
+    finally:
+        jax.monitoring.unregister_event_duration_listener(_on_compile)
+        gc.callbacks.remove(_on_gc)
+        _gc_started.clear()
+        if fd is not None:
+            os.close(fd)
+        with _watch_lock:
+            _watch = None
+
+
+def stall_report() -> List[Dict[str, object]]:
+    """Each ``quiver.host.stall`` of the timeline with what was open across
+    it: ``{"t0", "t1", "wall_s", "cpu_s", "runq_wait_s", "minor_faults",
+    "major_faults", "invol_switches", "open"}``, ``open`` being ``{thread
+    id: [(name, t0, t1, ids), ...]}`` of the library spans, collections and
+    compiles that overlap the stall (a span still open when this is called
+    is not on the timeline yet)."""
+    entries = trace_timeline()
+    out = []
+    for name, s0, s1, _tid, ids in entries:
+        if name != "quiver.host.stall":
+            continue
+        held: Dict[int, list] = {}
+        for other, t0, t1, tid, other_ids in entries:
+            if t0 < s1 and t1 > s0 and other != "quiver.host.stall":
+                held.setdefault(tid, []).append((other, t0, t1, other_ids))
+        out.append(dict(ids, t0=s0, t1=s1, wall_s=s1 - s0, open=held))
+    return out
 
 
 # -- serving metrics ----------------------------------------------------------

@@ -13,7 +13,12 @@ The contracts:
   named where the work happens, the per-request stages add up to the latency
   the engine recorded;
 - OBSERVE-ONLY (the rule of tests/test_obs.py): a traced and an untraced run
-  serve bit-equal rows, log bit-equal dispatches and train bit-equal losses.
+  serve bit-equal rows, log bit-equal dispatches and train bit-equal losses;
+- (ISSUE 36) ON, every closed span is also on the timeline with the two stamps
+  its duration is the difference of, and a reader lays them on the session's
+  trace through an enclosing span both sides see; a watch thread lives while
+  spans record and names the stalls of the process; a flush's six spans tile
+  it; OFF there is no entry, no thread, no object.
 """
 
 import contextlib
@@ -43,14 +48,28 @@ DIM = 16
 SIZES = [4, 4]
 SAMPLER_SEED = 3
 SERVE_STAGES = ("quiver.serve.queue", "quiver.serve.device", "quiver.serve.resolved")
+FLUSH_SPANS = tuple(f"quiver.serve.{s}" for s in (
+    "seq_wait", "assemble", "window_wait", "seal", "dispatch", "resolve"))
+
+
+def watch_ended(timeout=2.0):
+    """Whether the stall watch (a thread that lives while spans record) has
+    ended itself: it looks every `qtrace.TICK_S`."""
+    deadline = time.monotonic() + timeout
+    while qtrace._watch is not None and time.monotonic() < deadline:
+        time.sleep(qtrace.TICK_S)
+    return qtrace._watch is None
 
 
 @pytest.fixture(autouse=True)
 def clean_registry(monkeypatch):
     monkeypatch.delenv(qtrace.TRACE_ENV, raising=False)
+    assert watch_ended()
     trace_report(reset=True)
+    qtrace.trace_timeline(reset=True)
     yield
     trace_report(reset=True)
+    qtrace.trace_timeline(reset=True)
 
 
 @contextlib.contextmanager
@@ -353,7 +372,11 @@ def test_traced_and_untraced_serve_bit_equal(setup, tmp_path):
     eng_on = make_engine(setup)
     with session(tmp_path):
         out_on = np.asarray(eng_on.predict(nodes))
-    assert trace_report()["quiver.serve.queue"][0] == len(nodes)
+    rep = trace_report()
+    assert rep["quiver.serve.queue"][0] == rep["quiver.serve.pending"][0] == len(nodes)
+    n_flush = eng_on.stats.dispatches
+    for site in FLUSH_SPANS + ("quiver.serve.pumps",):  # ISSUE 36's sites ran too
+        assert rep[site][0] >= n_flush, site
     assert np.array_equal(out_on.view(np.uint32), out_off.view(np.uint32))
     assert len(eng_on.dispatch_log) == len(eng_off.dispatch_log) > 0
     for (p_on, n_on), (p_off, n_off) in zip(eng_on.dispatch_log, eng_off.dispatch_log):
@@ -404,6 +427,221 @@ def test_traced_and_untraced_train_losses_bit_equal(tmp_path):
     rep = trace_report()
     assert rep["quiver.sample"][0] == rep["quiver.feature.lookup"][0] == 3
     assert np.array_equal(traced.view(np.uint32), untraced.view(np.uint32))
+
+
+# -- the timeline and the stall watch (ISSUE 36) --------------------------------
+
+
+def timeline_of(*names):
+    return [e for e in qtrace.trace_timeline() if e[0] in names]
+
+
+def test_timeline_stamps_and_the_anchors_offset_reproduce_the_spans_own_event(tmp_path):
+    """No Python clock is the trace's, but the two differ by a constant for
+    the session: found through an enclosing span that both sides see, it puts
+    a span's timeline stamps where the profiler put the span's own event."""
+    from jax.profiler import ProfileData, TraceAnnotation
+    from qbench.reduce import Event
+    from qbench.readers.span_device_gap import anchor_offset
+
+    with session(tmp_path) as s:
+        for i in range(24):
+            with TraceAnnotation("qbench.outer"):
+                with trace_scope("quiver.test.inner", fid=i):
+                    time.sleep(0.002)
+            time.sleep(0.001)
+    events = {"qbench.outer": [], "quiver.test.inner": []}
+    for plane in ProfileData.from_file(s["path"]).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in events:
+                    events[e.name].append(Event(e.name, e.start_ns, e.start_ns + e.duration_ns))
+    outer, own = (sorted(events[k], key=lambda e: e.start_ns) for k in events)
+    inner = [(t0 * 1e9, t1 * 1e9) for _, t0, t1, _, _ in timeline_of("quiver.test.inner")]
+    assert len(outer) == len(own) == len(inner) == 24
+    assert [e[4] for e in timeline_of("quiver.test.inner")] == [{"fid": i} for i in range(24)]
+    offset = anchor_offset(outer, inner)
+    assert offset is not None
+    off_by = sorted(max(abs(t0 + offset - e.start_ns), abs(t1 + offset - e.end_ns))
+                    for (t0, t1), e in zip(inner, own))
+    # to 50 us; a thread taken off its core between the profiler's stamp and
+    # the span's own clock read may spoil a span or two on a loaded machine
+    assert off_by[len(off_by) // 2] < 50e3 and off_by[int(0.8 * len(off_by))] < 50e3, off_by
+    # the registry's duration is the difference of the timeline's two stamps
+    total = trace_report()["quiver.test.inner"][1]
+    assert total == pytest.approx(
+        sum(e[2] - e[1] for e in timeline_of("quiver.test.inner")), rel=1e-12)
+
+
+def test_a_span_that_does_not_annotate_is_in_registry_and_timeline_and_not_in_the_trace(
+        setup, tmp_path):
+    """``annotate=False``: what the one per-request site takes. The span is
+    counted and stamped like any other; the profiler is not told of it."""
+    eng = make_engine(setup)
+    with session(tmp_path) as s:
+        with trace_scope("quiver.test.quiet", annotate=False, fid=3) as span:
+            span.set(late=1)
+            time.sleep(0.002)
+        with trace_scope("quiver.test.loud", fid=4):
+            pass
+        for v in range(5):
+            eng.submit(v)
+        eng.flush()
+    assert [e[4] for e in timeline_of("quiver.test.quiet")] == [{"fid": 3, "late": 1}]
+    count, total = trace_report()["quiver.test.quiet"]
+    assert count == 1 and total >= 0.002
+    names = {e[1] for e in host_events(s["path"], "quiver.")}
+    assert "quiver.test.loud" in names and "quiver.test.quiet" not in names
+    # the submit site: five requests counted and on the timeline (the anchor
+    # of `span_device_gap` pairs them with the benchmark's spans), no event
+    assert trace_report()["quiver.serve.submit"][0] == 5
+    assert len(timeline_of("quiver.serve.submit")) == 5
+    assert "quiver.serve.submit" not in names and "quiver.serve.seal" in names
+
+
+def test_off_no_timeline_entry_no_thread_no_object(setup, monkeypatch):
+    import gc
+
+    recorded = []
+    monkeypatch.setattr(qtrace._timeline, "record", lambda *a: recorded.append(a))
+    monkeypatch.setattr(qtrace, "_start_watch", lambda: recorded.append("watch"))
+    with trace_scope("off.span", fid=1):
+        pass
+    observe("off.observed", 1.0)
+    eng = make_engine(setup)
+    eng.predict(zipfian_trace(N_NODES, 24, alpha=1.1, seed=3))  # every serve site, off
+    assert eng.stats.dispatches > 0
+    assert recorded == [] and qtrace.trace_timeline() == () and trace_report() == {}
+    assert qtrace._watch is None and qtrace.stall_report() == []
+    assert not [t for t in threading.enumerate() if t.name == "quiver-trace-watch"]
+    assert not any(cb is qtrace._on_gc for cb in gc.callbacks)
+
+
+def test_the_watch_lives_while_spans_record_and_ticks_into_the_registry(monkeypatch):
+    import gc
+
+    monkeypatch.setenv(qtrace.TRACE_ENV, "1")
+    observe("watch.starter", 1.0)  # an `observe` starts it as a span does
+    watch = qtrace._watch
+    assert watch is not None and watch.daemon and watch.name == "quiver-trace-watch"
+    time.sleep(0.1)
+    gc.collect()
+    with trace_scope("watch.second_span"):
+        pass
+    assert qtrace._watch is watch  # one watch, not one a span
+    assert any(cb is qtrace._on_gc for cb in gc.callbacks)
+    count, total, longest = trace_report(with_max=True)["quiver.host.tick"]
+    assert 2 <= count <= 0.1 / qtrace.TICK_S + 1 and 0.0 <= longest <= total
+    (collection,) = [e for e in timeline_of("quiver.host.gc") if e[4]["generation"] == 2]
+    assert collection[3] == threading.get_ident() and collection[1] <= collection[2]
+    monkeypatch.delenv(qtrace.TRACE_ENV)
+    assert watch_ended() and not watch.is_alive()
+    assert not any(cb is qtrace._on_gc for cb in gc.callbacks)
+
+
+def test_a_compile_is_on_the_timeline_by_its_thread(monkeypatch):
+    monkeypatch.setenv(qtrace.TRACE_ENV, "1")
+    with trace_scope("compile.around"):
+        jax.jit(lambda x: x * 3 + 1).lower(jnp.ones(7)).compile()
+    (around,) = timeline_of("compile.around")
+    compiles = timeline_of("quiver.host.compile")
+    assert compiles and all(c[3] == threading.get_ident() for c in compiles)
+    assert all(around[1] <= c[2] <= around[2] for c in compiles)
+    assert trace_report()["quiver.host.compile"][0] == len(compiles)
+
+
+def test_a_thread_that_holds_the_interpreter_is_one_stall_with_its_span_named(monkeypatch):
+    """One C call that keeps the interpreter lock (a sort of floats compares
+    without releasing it) stops every Python thread, the watch too: ONE tick
+    comes late, by the length of the call, the process burned CPU through
+    it, and the report names the span that was open around it."""
+    values = np.random.default_rng(0).random(2_000_000).tolist()
+    monkeypatch.setenv(qtrace.TRACE_ENV, "1")
+    with trace_scope("quiver.test.warm"):
+        pass
+    time.sleep(4 * qtrace.TICK_S)  # the watch is up and ticking
+    with trace_scope("quiver.test.hold", fid=7):
+        kept = sorted(values)
+    time.sleep(4 * qtrace.TICK_S)
+    assert len(kept) == len(values)
+    (hold,) = timeline_of("quiver.test.hold")
+    assert hold[2] - hold[1] > 2 * qtrace.STALL_S, "the sort was too short to stall anything"
+    across = [s for s in qtrace.stall_report() if s["t0"] < hold[2] and s["t1"] > hold[1]]
+    assert len(across) == 1, across
+    (stall,) = across
+    assert stall["wall_s"] == pytest.approx(hold[2] - hold[1], rel=0.25)
+    assert stall["cpu_s"] >= 0.5 * stall["wall_s"]
+    assert set(stall) == {"t0", "t1", "wall_s", "cpu_s", "runq_wait_s", "minor_faults",
+                          "major_faults", "invol_switches", "open"}
+    assert [(name, ids) for name, _, _, ids in stall["open"][threading.get_ident()]] == [
+        ("quiver.test.hold", {"fid": 7})]
+    # the registry has the tick: its longest reading is the stall
+    assert trace_report(with_max=True)["quiver.host.tick"][2] == pytest.approx(
+        stall["wall_s"], abs=1e-6)
+
+
+def test_a_flushs_six_spans_tile_it_on_one_thread_with_two_pollers(setup, tmp_path):
+    nodes = zipfian_trace(N_NODES, 160, alpha=1.1, seed=13)
+    eng = make_engine(setup, max_delay_ms=1.0, max_in_flight=2)
+    with session(tmp_path):
+        drive(eng, nodes)
+    n_flush = eng.stats.dispatches
+    by_thread = {}
+    for name, t0, t1, tid, ids in timeline_of(*FLUSH_SPANS):
+        by_thread.setdefault(tid, []).append((t0, t1, name.rsplit(".", 1)[1], ids["fid"]))
+    whole, stages = [], [s.rsplit(".", 1)[1] for s in FLUSH_SPANS]
+    for spans in by_thread.values():
+        spans.sort()
+        # one thread's flushes come one after the other: no span overlaps the next
+        assert all(a[1] <= b[0] for a, b in zip(spans, spans[1:]))
+        i = 0
+        while i < len(spans):
+            assert [s[2] for s in spans[i:i + 2]] == stages[:2]
+            if [s[2] for s in spans[i + 2:i + 6]] == stages[2:]:
+                assert len({s[3] for s in spans[i:i + 6]}) == 1  # one fid on all six
+                whole.append(spans[i:i + 6])
+                i += 6
+            else:  # the queue was drained by the other thread: waited, found nothing
+                i += 2
+    assert sorted(f[0][3] for f in whole) == list(range(1, n_flush + 1))
+    assert len(by_thread) >= 2  # two pollers (and a client's inline flush at a fill)
+    rep = trace_report()
+    for stage in ("window_wait", "seal", "dispatch", "resolve"):
+        assert rep[f"quiver.serve.{stage}"][0] == n_flush
+    assert "quiver.serve.assemble" in rep and rep["quiver.serve.seq_wait"][0] >= n_flush
+    # `pump()` calls are counted between flushes: the two pollers made them
+    count, total = rep["quiver.serve.pumps"]
+    assert count == n_flush and 0 < total <= eng._pumps
+
+
+def test_pending_and_the_flushs_own_part_add_up_to_the_queue_stage(setup, monkeypatch):
+    """A request is pending until the flush that takes it is CALLED and inside
+    that flush from then to its dispatch: with synchronous flushes everyone
+    is there from the call on, so the queue stage less the pending stage is
+    the flush's width times (call -> dispatch), which the spans give."""
+    monkeypatch.setenv(qtrace.TRACE_ENV, "1")
+    eng = make_engine(setup)
+    trace_report(reset=True)
+    qtrace.trace_timeline(reset=True)
+    waiters, held = [], 0
+    for v in zipfian_trace(N_NODES, 40, alpha=1.1, seed=5):
+        eng.submit(int(v))
+        held += 1
+        if held % 8 == 0:
+            time.sleep(0.003)  # something to be pending for
+            eng.flush()
+            waiters.append(8)
+    rep = trace_report()
+    assert rep["quiver.serve.pending"][0] == rep["quiver.serve.queue"][0] == 40
+    starts = {}
+    for name, t0, _, _, ids in timeline_of("quiver.serve.seq_wait", "quiver.serve.dispatch"):
+        starts.setdefault(ids["fid"], {})[name] = t0
+    in_flush = sum(n * (starts[fid]["quiver.serve.dispatch"] - starts[fid]["quiver.serve.seq_wait"])
+                   for fid, n in zip(sorted(starts), waiters))
+    assert len(starts) == len(waiters) == 5
+    assert rep["quiver.serve.pending"][1] >= 40 * 0.003 * 0.5
+    assert rep["quiver.serve.queue"][1] - rep["quiver.serve.pending"][1] == pytest.approx(
+        in_flush, abs=40 * 200e-6)
 
 
 # -- the sampler's one program, by name (ISSUE 31) ------------------------------
