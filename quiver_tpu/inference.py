@@ -186,12 +186,45 @@ def batch_logits(
 
 # -- fused one-dispatch serving (ROADMAP item 4a/4b) --------------------------
 
-def draw_sample_key(sampler):
-    """Consume the sampler's next key WITHOUT sampling — the fused serve
-    path draws keys host-side in dispatch-index order (inside the engine's
-    sequencing lock, exactly where `sample_batch` used to run) and defers
-    the sample itself into the one pre-bound device program."""
-    return sampler.next_key()
+_THREEFRY_ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
+
+
+def fold_in_call(key0, call):
+    """``jax.random.fold_in(key0, call)`` bit for bit (tests/test_serve.py
+    holds it to that), for the head of a serve program: the key of
+    dispatch ``call`` (a uint32 scalar) from the sampler's base key.
+
+    For the default threefry keys this is Threefry-2x32 of the counter
+    ``(0, call)`` under ``key0``, as `jax.random.fold_in` defines it,
+    written out in `lax` primitives on scalars. `jax.random.fold_in`
+    itself costs each program that holds it one more lowering of JAX's
+    unrolled threefry rule, which traces its ~130 `jnp` operations anew in
+    every module: 0.65 s a bucket program on the serving host, 4.4 s of
+    `ServeEngine.warmup()` over the seven buckets (chip runs, PR 37). The
+    same operations bound directly lower in milliseconds. Any other key
+    implementation goes through `jax.random.fold_in`."""
+    impl = jax.random.key_impl(key0)
+    if impl != "threefry2x32":
+        return jax.random.fold_in(key0, call)
+    u32 = np.uint32
+    data = jax.random.key_data(key0)
+    ks = [lax.index_in_dim(data, 0, keepdims=False),
+          lax.index_in_dim(data, 1, keepdims=False)]
+    ks.append(lax.bitwise_xor(lax.bitwise_xor(ks[0], ks[1]), u32(0x1BD11BDA)))
+    x0 = ks[0]                       # the counter's high word is 0
+    x1 = lax.add(lax.convert_element_type(call, u32), ks[1])
+    for i in range(5):
+        for r in _THREEFRY_ROTATIONS[i % 2]:
+            x0 = lax.add(x0, x1)
+            x1 = lax.bitwise_or(lax.shift_left(x1, u32(r)),
+                                lax.shift_right_logical(x1, u32(32 - r)))
+            x1 = lax.bitwise_xor(x0, x1)
+        x0 = lax.add(x0, ks[(i + 1) % 3])
+        x1 = lax.add(lax.add(x1, ks[(i + 2) % 3]), u32(i + 1))
+    return jax.random.wrap_key_data(
+        lax.concatenate([lax.expand_dims(x0, [0]), lax.expand_dims(x1, [0])], 0),
+        impl=impl,
+    )
 
 
 def feature_gather_spec(feature):
@@ -226,21 +259,26 @@ def make_serve_step(model, sampler):
     sample + feature gather + forward for a padded seed batch.
 
     Returns ``(serve_step, graph, id_dtype)`` where ``serve_step(params,
-    key, seeds, table, index_map, graph)`` reproduces
+    key0, call, seeds, table, index_map, graph)`` reproduces
     `sample_batch` + `forward_logits` bit-for-bit in one program (the
     bit-parity tests in tests/test_serve.py pin it), ``graph`` is the
     sampler's device-array pytree (a jit ARGUMENT of every call — big
     closure constants are the slow-compile trap, NEXT.md), and
     ``id_dtype`` the seed dtype the program was built for. The sampler's
-    key is an argument too: the ENGINE owns the key stream and draws it in
-    dispatch order (`draw_sample_key`), so fused and split engines consume
-    identical key indices."""
+    key is derived INSIDE the program, the bits of ``fold_in(key0, call)``
+    that `sample_dense_program` derives (`fold_in_call`): ``key0`` is the
+    sampler's base key (an argument: a seed baked in would compile anew
+    for every seed) and ``call`` a uint32 scalar, the call index the
+    ENGINE draws in dispatch order (`GraphSageSampler.next_call`), so
+    fused and split engines consume identical key indices and the host
+    derives no key."""
     from .pyg.sage_sampler import sample_dense_fused, sample_dense_pure
 
     graph, bind, id_dtype = sampler.fused_sample_spec()
     sizes, caps, dedup = sampler.sizes, sampler.caps, sampler.dedup
 
-    def serve_step(params, key, seeds, table, index_map, graph):
+    def serve_step(params, key0, call, seeds, table, index_map, graph):
+        key = fold_in_call(key0, call)
         sample_fn = bind(graph)
         if dedup:
             ds = sample_dense_pure(
@@ -262,7 +300,7 @@ def make_serve_step(model, sampler):
 
 def make_temporal_serve_step(model, sampler):
     """The TEMPORAL analog of :func:`make_serve_step` (round 19,
-    `quiver_tpu.workloads`): ``serve_step(params, key, seeds, table,
+    `quiver_tpu.workloads`): ``serve_step(params, key0, call, seeds, table,
     index_map, graph, t)`` runs the masked temporal sample
     (`workloads.temporal.temporal_sample_dense`) + gather + forward as ONE
     program. ``t`` is the padded per-seed query-time vector — a jit
@@ -281,7 +319,8 @@ def make_temporal_serve_step(model, sampler):
     sizes, max_deg = sampler.sizes, sampler.max_deg
     id_dtype = graph[1].dtype
 
-    def serve_step(params, key, seeds, table, index_map, graph, t):
+    def serve_step(params, key0, call, seeds, table, index_map, graph, t):
+        key = fold_in_call(key0, call)
         ds = temporal_sample_dense(
             graph, key, seeds, t, sizes, recency=recency, max_deg=max_deg
         )
@@ -354,9 +393,10 @@ class BucketPrograms:
             )
             self._n_extra = 0
         self._sampler = sampler
+        self._key0 = sampler._key0  # the base key every dispatch folds from
         self._caps = sampler.caps  # snapshot the program was built for
         self._table, self._map = feature_gather_spec(feature)
-        self._jit = jax.jit(self._fn, donate_argnums=(2,))
+        self._jit = jax.jit(self._fn, donate_argnums=(3,))
         self._exes: dict = {}
         self._sealed = False
         try:
@@ -464,7 +504,6 @@ class BucketPrograms:
             if exe is not None:
                 self._exes[bucket] = exe
                 return
-        key = jax.random.fold_in(jax.random.key(0), 0)
         seeds = jnp.zeros((bucket,), self._id_dtype)
         extras = (
             (jnp.zeros((bucket,), jnp.float32),) if self._n_extra else ()
@@ -479,8 +518,8 @@ class BucketPrograms:
                 "ignore", message="Some donated buffers were not usable"
             )
             exe = self._jit.lower(
-                params, key, seeds, self._table, self._map, self._graph,
-                *extras,
+                params, self._key0, np.uint32(0), seeds, self._table,
+                self._map, self._graph, *extras,
             ).compile()
         if cache_key is not None:
             with _SERVE_EXE_LOCK:
@@ -499,13 +538,16 @@ class BucketPrograms:
         rebind swaps references, never bits)."""
         return (self._table, self._map, self._graph)
 
-    def __call__(self, bucket: int, params, key, seeds, *extra,
+    def __call__(self, bucket: int, params, call: int, seeds, *extra,
                  binding=None) -> jax.Array:
         """ONE execute call: the whole sample+gather+forward for a padded
-        seed batch at ``bucket``. Misses compile lazily before `seal()`,
-        raise RuntimeError after. Temporal programs take one ``extra``
-        argument — the padded per-seed query-time vector, float32
-        ``[bucket]`` (the engine pads it exactly like the seeds).
+        seed batch at ``bucket``, drawn with the key of call index
+        ``call`` (a Python int, `GraphSageSampler.next_call`: the program
+        folds it into the sampler's base key itself). Misses compile
+        lazily before `seal()`, raise RuntimeError after. Temporal
+        programs take one ``extra`` argument — the padded per-seed
+        query-time vector, float32 ``[bucket]`` (the engine pads it
+        exactly like the seeds).
         ``binding=`` (a `binding()` snapshot) overrides the live
         table/map/graph arguments — the epoch-pinning hook."""
         if len(extra) != self._n_extra:
@@ -545,7 +587,10 @@ class BucketPrograms:
             binding if binding is not None
             else (self._table, self._map, self._graph)
         )
-        return exe(params, key, seeds, table, imap, graph, *extra)
+        return exe(
+            params, self._key0, np.uint32(call), seeds, table, imap, graph,
+            *extra,
+        )
 
 
 def time_eval_split(

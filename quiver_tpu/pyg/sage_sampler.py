@@ -831,25 +831,32 @@ class GraphSageSampler:
             )
         return self._host_engine
 
-    def _next_key(self) -> jax.Array:
-        key = jax.random.fold_in(jax.random.key(self._seed), self._call)
-        self._call += 1
-        return key
-
-    def next_key(self) -> jax.Array:
-        """Consume and return the next key of this sampler's deterministic
-        stream WITHOUT running a sample — key i is exactly the key
-        `sample_dense`'s i-th call would have drawn. The fused serve path
-        (`inference.serve_step`) draws keys host-side in dispatch order and
-        runs the sample itself inside the one pre-bound device program, so
-        the key stream (and any replay of the dispatch log through a twin
-        sampler) stays identical to the split sample/forward path."""
+    def next_call(self) -> int:
+        """Consume and return the next call index of this sampler's
+        deterministic stream WITHOUT deriving a key: call i draws
+        ``fold_in(key(seed), i)``, and the programs that take
+        ``(key0, call)`` (`sample_dense_program`, the fused serve step of
+        `inference.make_serve_step`) fold it on the device. The serve
+        engine draws one index a dispatch under its sequencing lock, a
+        host integer and nothing else, so the index stream (and any
+        replay of the dispatch log through a twin sampler) stays
+        identical to the split sample/forward path."""
         if self.mode != "TPU":
             raise TypeError(
-                "next_key() draws the TPU-mode jax key stream; HOST/CPU "
+                "next_call() draws the TPU-mode jax key stream; HOST/CPU "
                 "samplers derive their RNG seed inside sample_dense"
             )
-        return self._next_key()
+        call, self._call = self._call, self._call + 1
+        return call
+
+    def next_key(self) -> jax.Array:
+        """Consume the next call index (`next_call`) and return its key,
+        ``fold_in(key(seed), i)``, derived eagerly on the host: key i is
+        exactly the key `sample_dense`'s i-th call draws. For callers
+        that sample op by op or skip an index (a replay twin); the hot
+        paths pass the index into their one program instead."""
+        call = self.next_call()  # first: the TypeError of a HOST/CPU sampler
+        return jax.random.fold_in(self._key0, call)
 
     def fused_sample_spec(self):
         """``(graph, bind, id_dtype)`` for building FUSED in-jit
@@ -931,7 +938,7 @@ class GraphSageSampler:
                     f"t has {tv.shape[0]} entries for {seeds.shape[0]} seeds"
                 )
             return temporal_sample_dense(
-                graph, self._next_key(), seeds, jnp.asarray(tv),
+                graph, self.next_key(), seeds, jnp.asarray(tv),
                 self.sizes, recency=recency, max_deg=self.max_deg,
             )
         if t is not None:
@@ -1054,7 +1061,7 @@ class GraphSageSampler:
             graph, bind, id_dtype = self._graph_and_bind()
             seeds_d = jnp.asarray(np.asarray(seeds), id_dtype)
             nbrs, valid = bind(graph)(
-                seeds_d, jnp.ones(seeds_d.shape, bool), size, self._next_key()
+                seeds_d, jnp.ones(seeds_d.shape, bool), size, self.next_key()
             )
             nbrs, valid = np.asarray(nbrs), np.asarray(valid)
         else:
@@ -1113,7 +1120,7 @@ class GraphSageSampler:
             # its latest commit with no retrace (shapes never change)
             graph, bind, id_dtype = self._graph_and_bind()
             counts = probe_hop_counts(
-                None, None, self._next_key(),
+                None, None, self.next_key(),
                 jnp.asarray(batches.astype(np.dtype(id_dtype))), self.sizes,
                 graph=graph, bind=bind, cache=self._probe_scan_cache,
             )

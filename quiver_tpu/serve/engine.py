@@ -102,7 +102,6 @@ import numpy as np
 from ..inference import (
     BucketPrograms,
     _cached_apply,
-    draw_sample_key,
     forward_logits,
     pad_seed_batch,
     sample_batch,
@@ -1167,8 +1166,8 @@ class _Flush:
     imposes (`ServeEngine._dispatch_index` counts it). ``bucket`` is fixed
     at drain time; late admission may append to ``keys``/``slots`` up to it
     until `_seal_assembled` closes the flush. The fused path carries the
-    drawn sampler ``key`` + the ``padded`` seed batch into its one-program
-    dispatch; the split path carries the pre-run sample ``ds``.
+    drawn sampler ``call`` index + the ``padded`` seed batch into its
+    one-program dispatch; the split path carries the pre-run sample ``ds``.
 
     Round 20 (array-native internals): once sealed, the flush also carries
     SLOT ARRAYS — ``ids`` (int64 seed ids), ``rids`` (int64 journal
@@ -1179,7 +1178,7 @@ class _Flush:
     slot INDEX instead of walking per-request objects. ``slots`` itself
     stays: waiters/version/resolution state is per-request by nature."""
 
-    __slots__ = ("keys", "slots", "params", "seeds", "bucket", "ds", "key",
+    __slots__ = ("keys", "slots", "params", "seeds", "bucket", "ds", "call",
                  "padded", "extra", "error", "fid", "ids", "rids",
                  "tenant_ix", "graph_version", "binding", "t_begin",
                  "t_dispatch", "t_done")
@@ -1191,7 +1190,7 @@ class _Flush:
         self.seeds = None
         self.bucket = 0
         self.ds = None
-        self.key = None
+        self.call = None
         self.padded = None
         # round-24 epoch pin, stamped at seal (under _seq): the graph
         # version this flush dispatches against, plus the fused program's
@@ -1701,8 +1700,8 @@ class ServeEngine:
         # in-flight flush to resolve before swapping the weights
         self._fence = threading.Condition(self._lock)
         # sequencing lock: orders queue drain + dispatch-index assignment +
-        # dispatch-log append + the sampler's key draw, so the key stream
-        # and the replay log stay deterministic in dispatch order
+        # dispatch-log append + the sampler's call-index draw, so the key
+        # stream and the replay log stay deterministic in dispatch order
         self._seq = threading.Lock()
         # bounded in-flight window: at most max_in_flight flushes between
         # assemble and resolve (blocking acquire = backpressure on callers)
@@ -2119,9 +2118,11 @@ class ServeEngine:
     def _seal_assembled(self, fl: _Flush) -> None:
         """Stage 1b (caller holds ``_seq`` and a window permit): close late
         admission, then draw the dispatch index, append the dispatch-log
-        entry, and consume the sampler's next key. Everything that must be
-        ordered by dispatch index happens HERE — admitted seeds are already
-        in ``fl.keys``, so the log and the key stream see the final batch
+        entry, and consume the sampler's next call index (a host integer:
+        the fused program derives the key from it on the device, as the
+        split path's `sample_dense` does). Everything that must be ordered
+        by dispatch index happens HERE — admitted seeds are already in
+        ``fl.keys``, so the log and the key stream see the final batch
         composition exactly once."""
         with self._lock:
             self._open = None
@@ -2172,17 +2173,18 @@ class ServeEngine:
                 )
             # round-24 epoch pin (caller holds _seq — the commit flip
             # also runs under _seq, so the stamp, the binding snapshot,
-            # and the upcoming key draw are all of ONE epoch)
+            # and the upcoming call-index draw are all of ONE epoch)
             fl.graph_version = self.graph_version
             if self.config.record_dispatches:
                 self.dispatch_log.append(self._dispatch_log_entry(fl, padded))
                 self.dispatch_graph_versions.append(fl.graph_version)
             if self._programs is not None:
-                # fused path: draw the key in dispatch order, defer the
-                # sample into the one-program dispatch stage; the binding
-                # snapshot pins the graph arrays this flush will execute
-                # against even if a zero-stall commit rebinds mid-flight
-                fl.key = draw_sample_key(self._sampler)
+                # fused path: draw the call index in dispatch order, defer
+                # the key and the sample into the one-program dispatch
+                # stage; the binding snapshot pins the graph arrays this
+                # flush will execute against even if a zero-stall commit
+                # rebinds mid-flight
+                fl.call = self._sampler.next_call()
                 fl.padded = padded
                 fl.binding = self._programs.binding()
             else:
@@ -2215,7 +2217,7 @@ class ServeEngine:
         self.journal.emit("dispatch", -1, fl.fid, fl.bucket)
         if fl.ds is None and self._programs is not None:
             logits = np.asarray(
-                self._programs(fl.bucket, fl.params, fl.key, fl.padded,
+                self._programs(fl.bucket, fl.params, fl.call, fl.padded,
                                *(fl.extra or ()), binding=fl.binding)
             )
             n_exec = 1
@@ -2363,7 +2365,7 @@ class ServeEngine:
                     self.stats.spans.record("assemble", t0, self._clock())
                 finally:
                     # _seal_assembled's first act already closed admission
-                    # (it MUST happen under _lock before the key draw);
+                    # (it MUST happen under _lock before the index draw);
                     # this repeat only covers an interrupt landing between
                     # the window acquire and the seal
                     with self._lock:
@@ -3119,7 +3121,7 @@ class ServeEngine:
            the sealed programs' graph arguments, prefetch-intent drop.
            A flush sealing before the flip pinned the old binding and
            stamped the old version; one sealing after gets the new —
-           never a mix (the stamp, the binding snapshot and the key draw
+           never a mix (the stamp, the binding snapshot and the index draw
            share one ``_seq`` hold in `_seal_assembled`).
         3. POST-FLIP (no fence): the closure-touched nodes' cache
            graph-version floors rise (`EmbeddingCache.raise_floor` —

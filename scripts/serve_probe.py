@@ -3212,11 +3212,11 @@ def main():
     timer_eng.warmup()
     twin = make_full_sampler()
     seeds = np.arange(args.max_batch, dtype=np.int64)
-    np.asarray(timer_eng._programs(args.max_batch, params, twin.next_key(), seeds))
+    np.asarray(timer_eng._programs(args.max_batch, params, twin.next_call(), seeds))
     t0 = time.perf_counter()
     iters = 20
     for _ in range(iters):
-        out = timer_eng._programs(args.max_batch, params, twin.next_key(), seeds)
+        out = timer_eng._programs(args.max_batch, params, twin.next_call(), seeds)
     np.asarray(out)
     t_fused = (time.perf_counter() - t0) / iters
     overhead = max((t_sample + t_forward) - t_fused, 0.0)

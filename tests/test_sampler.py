@@ -888,6 +888,34 @@ def test_next_key_after_i_calls_is_the_key_call_i_would_draw(graph):
     _assert_same_bits(sampler.sample_dense(seeds), _eager_sample_dense(sampler, 3, seeds))
 
 
+def test_next_call_and_next_key_share_one_cursor(graph):
+    sampler = GraphSageSampler(graph, sizes=[4, 3], mode="TPU", seed=9, dedup=False)
+    seeds = np.arange(16)
+    assert sampler.next_call() == 0 and type(sampler.next_call()) is int  # 0, 1
+    key2 = sampler.next_key()                   # consumes call 2
+    assert sampler.next_call() == 3
+    sampler.sample_dense(seeds)                 # call 4
+    key5 = sampler.next_key()
+    assert sampler._call == 6
+    for drawn, i in ((key2, 2), (key5, 5)):
+        want = jax.random.fold_in(jax.random.key(9), i)
+        assert (jax.random.key_data(drawn) == jax.random.key_data(want)).all()
+    # an index handed out by next_call is the one a sample would have drawn
+    twin = GraphSageSampler(graph, sizes=[4, 3], mode="TPU", seed=9, dedup=False)
+    for _ in range(4):
+        twin.next_call()
+    _assert_same_bits(twin.sample_dense(seeds), _eager_sample_dense(sampler, 4, seeds))
+
+
+@pytest.mark.parametrize("mode", ["HOST", "CPU"])
+def test_next_call_is_the_tpu_streams_alone(graph, mode):
+    sampler = GraphSageSampler(graph, sizes=[4, 3], mode=mode, seed=9)
+    for draw in (sampler.next_call, sampler.next_key):
+        with pytest.raises(TypeError, match="TPU-mode"):
+            draw()
+    assert sampler._call == 0
+
+
 def test_a_program_is_built_once_a_batch_shape_and_the_span_says_so(graph, monkeypatch):
     from quiver_tpu import trace as qtrace
 

@@ -633,6 +633,117 @@ def test_fused_and_split_paths_bit_identical(setup):
         assert np.array_equal(outs[0][i], oracle[int(nid)])
 
 
+@pytest.mark.parametrize("mif", [1, 2])
+def test_sealed_fused_engine_derives_no_key_on_the_host(setup, monkeypatch, mif):
+    """The seal draws a call INDEX, a host integer; the key is folded inside
+    the sealed program. With `jax.random.key` and `jax.random.fold_in` made
+    to raise (the sealed programs are compiled: nothing traces them again) a
+    run of flushes still answers, and answers what the replay expects."""
+    eng = make_engine(setup, max_batch=8, max_delay_ms=1e9, max_in_flight=mif)
+    eng.warmup()
+    assert eng._programs is not None and eng._programs.sealed
+
+    def no_host_key(*a, **kw):
+        raise AssertionError("a sampler key was derived on the host")
+
+    with monkeypatch.context() as m:
+        m.setattr(jax.random, "key", no_host_key)
+        m.setattr(jax.random, "fold_in", no_host_key)
+        m.setattr(GraphSageSampler, "next_key", no_host_key)
+        next_id = iter(range(N_NODES))
+        handles = []
+        for n in (3, 8, 1, 2, 5):
+            ids = [next(next_id) for _ in range(n)]
+            handles += [(i, eng.submit(i)) for i in ids]
+            eng.flush()                     # a full batch flushed inline
+        rows = [(nid, h.result(timeout=5)) for nid, h in handles]
+    assert eng._sampler._call == 5 == len(eng.dispatch_log)
+    oracle = replay_oracle(setup, eng)
+    for nid, row in rows:
+        assert np.array_equal(row, oracle[nid])
+
+
+@pytest.mark.parametrize("mif", [1, 2])
+def test_fused_split_and_a_skipping_twin_give_the_same_rows(setup, mif):
+    """Fused engine, split engine and `sample_batch(twin)` + `forward_logits`
+    give bit-equal rows over several dispatches; the twin steps over the
+    dispatches it does not replay with `next_key()` (as the benchmark's
+    check does), and both engines leave the cursor at the same index."""
+    from quiver_tpu.inference import forward_logits, sample_batch
+
+    model, params, feat = setup
+    batches = [list(range(s, s + n)) for s, n in
+               ((0, 3), (10, 8), (30, 1), (40, 5), (60, 2), (70, 8), (90, 4))]
+    served = []
+    for mode in ("fused", "split"):
+        eng = make_engine(setup, max_batch=8, max_delay_ms=1e9,
+                          dispatch_mode=mode, max_in_flight=mif)
+        eng.warmup()
+        rows = []
+        for ids in batches:
+            hs = [eng.submit(i) for i in ids]
+            eng.flush()
+            rows.append(np.stack([h.result(timeout=5) for h in hs]))
+        served.append((eng, rows))
+    (fused, rows_f), (split, rows_s) = served
+    assert fused._programs is not None and split._programs is None
+    assert fused._sampler._call == split._sampler._call == len(batches)
+    for rf, rs in zip(rows_f, rows_s):
+        assert np.array_equal(rf.view(np.uint32), rs.view(np.uint32))
+    apply = _cached_apply(model)
+    twin, at = make_sampler(), 0
+    for pos in (1, 4, 6):           # dispatches 0, 2, 3, 5 are stepped over
+        while at < pos:
+            twin.next_key()
+            at += 1
+        padded, n_valid = fused.dispatch_log[pos]
+        ds = sample_batch(twin, padded)
+        at += 1
+        replay = np.asarray(forward_logits(apply, params, feat, ds))[:n_valid]
+        assert np.array_equal(replay.view(np.uint32), rows_f[pos].view(np.uint32))
+    assert twin._call == 7
+
+
+@pytest.mark.parametrize("seed", [0, 3, 2**31 - 1])
+def test_fold_in_call_is_jax_fold_in_bit_for_bit(seed):
+    """The head of the serve programs: Threefry-2x32 written in `lax`
+    primitives gives `jax.random.fold_in`'s key for every (base key, call)."""
+    from quiver_tpu.inference import fold_in_call
+
+    key0 = jax.random.key(seed)
+    folded = jax.jit(fold_in_call)
+    rng = np.random.default_rng(seed)
+    calls = [0, 1, 2, 2**31, 2**32 - 1] + [int(c) for c in rng.integers(0, 2**32, 12)]
+    for call in calls:
+        want = jax.random.key_data(jax.random.fold_in(key0, np.uint32(call)))
+        for got in (folded(key0, np.uint32(call)), fold_in_call(key0, np.uint32(call))):
+            assert jax.random.key_impl(got) == jax.random.key_impl(key0)
+            assert np.array_equal(np.asarray(jax.random.key_data(got)), np.asarray(want))
+    # another key implementation is handed to jax.random.fold_in itself
+    rbg = jax.random.key(seed, impl="rbg")
+    assert np.array_equal(
+        np.asarray(jax.random.key_data(folded(rbg, np.uint32(7)))),
+        np.asarray(jax.random.key_data(jax.random.fold_in(rbg, 7))))
+
+
+def test_bucket_programs_take_the_call_index(setup):
+    """`BucketPrograms.__call__(bucket, params, call, seeds)`: the call index
+    as a Python int, any order, the rows `batch_logits` gives at that index."""
+    from quiver_tpu.inference import BucketPrograms
+
+    model, params, feat = setup
+    programs = BucketPrograms(model, make_sampler(), feat)
+    apply = _cached_apply(model)
+    padded = pad_seed_batch(np.arange(5, dtype=np.int64), 8)
+    for call in (4, 0, 2**31 + 7):
+        twin = make_sampler()
+        twin._call = call
+        want = np.asarray(batch_logits(apply, params, twin, feat, padded))
+        got = np.asarray(programs(8, params, call, padded))
+        assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
+    assert programs._sampler._call == 0     # the program consumes no index
+
+
 def test_dispatch_mode_validation_and_forced_fused(setup):
     model, params, feat = setup
     with pytest.raises(ValueError, match="dispatch_mode"):
@@ -778,10 +889,10 @@ def test_flush_error_resolves_waiters(setup):
     with pytest.raises(Boom):
         h.result(timeout=1)
     assert not eng._drainable() and not eng._inflight
-    # fused path: the key draw raises mid-seal — same resolution contract
+    # fused path: the call-index draw raises mid-seal — same resolution contract
     eng2 = make_engine(setup, max_batch=8, max_delay_ms=1e9)
     assert eng2._programs is not None
-    eng2._sampler.next_key = broken
+    eng2._sampler.next_call = broken
     h2 = eng2.submit(1)
     with pytest.raises(Boom):
         eng2.flush()

@@ -341,16 +341,41 @@ def test_temporal_engine_replay_parity(setup, mif):
     model, params, feat = setup
     eng = make_engine(setup, max_in_flight=mif)
     eng.warmup()
+    # the fused temporal program: (params, key0, call, seeds, ..., t), the
+    # seal hands it a call index and the replay's twin draws the same keys
+    assert eng._programs is not None and eng._programs.sealed
     rng = np.random.default_rng(13)
     nodes = rng.integers(0, N_NODES, 24)
     tq = rng.uniform(0, 60, 24)
     rows = eng.predict(nodes, t=tq, timeout=60)
+    assert eng._sampler._call == len(eng.dispatch_log) > 0
     oracle = replay_temporal_log(
         eng.dispatch_log, model, params, make_temporal_sampler(), feat
     )
     for node, t, row in zip(nodes, tq, rows):
         k = (int(node), float(np.float32(quantize_t(t, 4.0))))
         assert any(np.array_equal(row, c) for c in oracle.get(k, [])), k
+
+
+def test_temporal_program_folds_the_key_of_the_call_index(setup):
+    # one signature for the plain and the temporal program: the call index
+    # as an int, then seeds, then the padded query times
+    from quiver_tpu.inference import BucketPrograms, _cached_apply, forward_logits
+
+    model, params, feat = setup
+    programs = BucketPrograms(model, make_temporal_sampler(), feat)
+    seeds = np.arange(8, dtype=np.int64) * 7
+    tv = np.linspace(5.0, 60.0, 8).astype(np.float32)
+    apply = _cached_apply(model)
+    for call in (3, 0):
+        twin = make_temporal_sampler()
+        twin._call = call
+        ds = twin.sample_dense(seeds, t=tv)
+        want = np.asarray(forward_logits(apply, params, feat, ds))
+        got = np.asarray(programs(8, params, call, seeds, tv))
+        assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
+    with pytest.raises(TypeError, match="extra"):
+        programs(8, params, 0, seeds)
 
 
 def test_composite_cache_keys_hit_miss_and_params_invalidate(setup):

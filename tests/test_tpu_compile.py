@@ -166,9 +166,10 @@ def compile_serve_bucket(v5e, bucket=SERVE_BUCKET):
     serve_step, _, id_dtype = make_serve_step(model, ring_sampler())
     one_chip = SingleDeviceSharding(v5e.devices[0])
     args = _struct(
-        (params, key, _sds((bucket,), id_dtype),
+        (params, key, _sds((), jnp.uint32), _sds((bucket,), id_dtype),
          _sds((N, PRODUCTS["dim"]), jnp.float32)), one_chip)
-    compiled = jax.jit(serve_step, donate_argnums=(2,)).lower(
+    # (params, key0, call, seeds, ...): the seed buffer is argument 3
+    compiled = jax.jit(serve_step, donate_argnums=(3,)).lower(
         *args, None, _struct(_graph_structs(), one_chip)).compile()
     return _fits(compiled, f"serve bucket {bucket}"), compiled.as_text()
 
