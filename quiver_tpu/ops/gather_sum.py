@@ -305,3 +305,75 @@ def _attention_bwd(slope, saved, g):
 
 
 gather_attention_sum.defvjp(_attention_fwd, _attention_bwd)
+
+
+# --- bipartite attention (models/rgat.py) -----------------------------------
+# A relation's `GATConv((-1, -1), add_self_loops=False)`: the sources are
+# another segment than the targets, so there is no self slot, and the targets'
+# half of every score comes in as its own argument. `_target_blocks`, `_tiles`
+# and `_rows` as above; the backward gathers each block again and scatters
+# every slot's gradient into its source row in one scatter-add, likewise.
+
+
+def bipartite_block(rows, valid, att, t, slope):
+    """`attention_block` with no self slot: ``sum_j alpha_j row_j`` over the
+    valid slots alone, the softmax in float32; a target with no valid slot
+    gets 0 (its output is the bias alone)."""
+    att = att.astype(rows.dtype)
+    pre = (rows * att).sum(axis=-1) + t
+    e = jnp.where(valid, jax.nn.leaky_relu(pre, slope), jnp.asarray(-1e9, pre.dtype))
+    e = e.astype(jnp.float32)
+    p = jnp.where(valid, jnp.exp(e - jax.lax.stop_gradient(e.max(axis=0))), 0.0)
+    total = p.sum(axis=0)
+    alpha = (p / jnp.where(total > 0, total, 1.0)).astype(rows.dtype)
+    return (alpha[..., None] * rows).sum(axis=0)
+
+
+def _bipartite_tiled(z, c, m, t_b, att):
+    """`_tiled_block` without the targets' own rows."""
+    h = att.shape[0]
+    ids = jnp.clip(c.T, 0, z.shape[0] - 1)
+    return ids, (_tiles(jnp.take(z, ids, axis=0), h), m.T.reshape(m.shape[1], -1, 1, 8),
+                 att[:, None, :], t_b.reshape(-1, 8, h).swapaxes(-2, -1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def bipartite_attention_sum(z, cols, mask, att, t, slope):
+    """`bipartite_block` of every target over ``z[clip(cols)]``: ``[W, k]``
+    ids into the source rows ``z[N, H * D]`` (heads side by side; ``att [H,
+    D]`` says how a row splits) and the targets' score halves ``t [W, H]``
+    give ``[W, H * D]``. The ``[W, k, H, D]`` rows are never laid out whole,
+    forward or backward."""
+    w = cols.shape[0]
+
+    def block(args):
+        _, (rows, valid, att_t, t_b) = _bipartite_tiled(z, *args, att)
+        return _rows(bipartite_block(rows, valid, att_t, t_b, slope))
+
+    out = jax.lax.map(block, tuple(_target_blocks(w, cols, mask, t)))
+    return out.reshape(-1, z.shape[1])[:w]
+
+
+def _bipartite_fwd(z, cols, mask, att, t, slope):
+    return bipartite_attention_sum(z, cols, mask, att, t, slope), (z, cols, mask, att, t)
+
+
+def _bipartite_bwd(slope, saved, g):
+    z, cols, mask, att, t = saved
+    w, h = cols.shape[0], att.shape[0]
+
+    def block(carry, args):
+        dz, datt = carry
+        ids, (rows, valid, att_t, t_b) = _bipartite_tiled(z, *args[:-1], att)
+        _, vjp = jax.vjp(lambda r, a, tb: bipartite_block(r, valid, a, tb, slope),
+                         rows, att_t, t_b)
+        drows, datt_b, dt_b = vjp(_tiles(args[-1], h))
+        return ((dz.at[ids].add(_rows(drows)), datt + datt_b[:, 0]),
+                dt_b.swapaxes(-2, -1).reshape(-1, h))
+
+    (dz, datt), dt = jax.lax.scan(block, (jnp.zeros_like(z), jnp.zeros_like(att)),
+                                  tuple(_target_blocks(w, cols, mask, t, g)))
+    return dz, None, None, datt, dt.reshape((-1,) + t.shape[1:])[:w]
+
+
+bipartite_attention_sum.defvjp(_bipartite_fwd, _bipartite_bwd)

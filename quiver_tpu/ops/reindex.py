@@ -120,25 +120,13 @@ class ReindexResult(NamedTuple):
     nbr_valid: jax.Array   # [S, k] validity mask (propagated from sampling)
 
 
-@functools.partial(jax.jit, static_argnames=())
-def local_reindex(
-    seeds: jax.Array,
-    seed_valid: jax.Array,
-    nbrs: jax.Array,
-    nbr_valid: jax.Array,
-) -> ReindexResult:
-    """Build ``n_id`` (valid seeds verbatim, then unique new neighbors
-    ascending) and rewrite neighbors to canonical local ids.
-
-    Matches ``TorchQuiver::reindex_single`` semantics
-    (quiver_sample.cu:305-357) including duplicate seeds: each valid seed
-    keeps its own slot, lookups resolve to the first slot with the value.
-
-    ``seeds`` is [S]; ``nbrs`` is [S, k]. cap = S + S*k.
-    """
+def _reindex(seeds, seed_valid, nbrs, nbr_valid):
+    """The body of `local_reindex`, over any ``[R, C]`` block of neighbours
+    (flattened transposed: entry (i, j) at position ``S + j*R + i``).
+    Returns (n_id, count, seed_valid as bool, output slot of each seed,
+    canonical local id of every position, seeds first)."""
     S = seeds.shape[0]
-    k = nbrs.shape[1]
-    W = S + S * k
+    W = S + nbrs.size
     idt = jnp.promote_types(seeds.dtype, nbrs.dtype)
     sentinel = jnp.asarray(jnp.iinfo(idt).max, idt)
 
@@ -161,7 +149,7 @@ def local_reindex(
     # payload 2: a seed's output slot, or S for neighbors/invalid
     slotpay = jnp.concatenate([
         jnp.where(seed_valid, seed_slot, S),
-        jnp.full((S * k,), S, jnp.int32),
+        jnp.full((nbrs.size,), S, jnp.int32),
     ])
     sv, order, spay = _sort3(all_nodes, pos, slotpay)
 
@@ -191,6 +179,28 @@ def local_reindex(
     _, n_id = _sort2(outkey, outval)
 
     count = n_seed + new_unique.sum().astype(jnp.int32)
+    return n_id, count, seed_valid, seed_slot, local_all
+
+
+@functools.partial(jax.jit, static_argnames=())
+def local_reindex(
+    seeds: jax.Array,
+    seed_valid: jax.Array,
+    nbrs: jax.Array,
+    nbr_valid: jax.Array,
+) -> ReindexResult:
+    """Build ``n_id`` (valid seeds verbatim, then unique new neighbors
+    ascending) and rewrite neighbors to canonical local ids.
+
+    Matches ``TorchQuiver::reindex_single`` semantics
+    (quiver_sample.cu:305-357) including duplicate seeds: each valid seed
+    keeps its own slot, lookups resolve to the first slot with the value.
+
+    ``seeds`` is [S]; ``nbrs`` is [S, k]. cap = S + S*k.
+    """
+    S = seeds.shape[0]
+    k = nbrs.shape[1]
+    n_id, count, seed_valid, seed_slot, local_all = _reindex(seeds, seed_valid, nbrs, nbr_valid)
     return ReindexResult(
         n_id=n_id,
         count=count,
@@ -198,6 +208,17 @@ def local_reindex(
         local_nbrs=local_all[S:].reshape(k, S).T,
         nbr_valid=nbr_valid,
     )
+
+
+def segment_reindex(seeds: jax.Array, seed_valid: jax.Array, flat: jax.Array,
+                    flat_valid: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """`local_reindex` over a flat ``[M]`` list of neighbours that need not
+    come ``k`` to a seed: the draws of several relations into one node type
+    (`pyg.hetero_sampler`). ``seeds`` ([S], S may be 0) keep their slots
+    verbatim as the prefix of ``n_id``. Returns (n_id ``[S + M]``, count,
+    the canonical local id of each entry of ``flat``)."""
+    n_id, count, _, _, local_all = _reindex(seeds, seed_valid, flat[:, None], flat_valid[:, None])
+    return n_id, count, local_all[seeds.shape[0]:]
 
 
 def reindex_single(

@@ -13,6 +13,7 @@ from .sage_sampler import (
     sample_dense_fused,
     sample_dense_pure,
 )
+from .hetero_sampler import HeteroBlocks, HeteroCSRTopo, HeteroDenseSample, HeteroSampler
 from .mixed_sampler import MixedGraphSageSampler, SampleJob, TrainSampleJob
 
 __all__ = [
@@ -20,6 +21,10 @@ __all__ = [
     "DenseAdj",
     "DenseSample",
     "GraphSageSampler",
+    "HeteroBlocks",
+    "HeteroCSRTopo",
+    "HeteroDenseSample",
+    "HeteroSampler",
     "MixedGraphSageSampler",
     "SampleJob",
     "TrainSampleJob",

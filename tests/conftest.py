@@ -110,6 +110,11 @@ def make_chain_graph(n_layers=4, width=5):
 # after them. tests/qbench/test_qbench_gat_manifest.py holds every other
 # assertion of it (what each PR appended stands together, in order) and says
 # nothing of what follows, so the next appended metric keeps it.
+# PR 38 met the same in PR 34's file: four tests want the attention cell, its
+# configuration and the shared metrics' last entry to be the LAST of their
+# lists, and `model_device_ms.train` to be the attention cell's alone.
+# tests/qbench/test_qbench_rgat_manifest.py holds every other assertion of the
+# four, with no last place.
 OUTGROWN = {
     "test_qbench_manifest.py::test_every_cell_loads_by_name[benchmark]":
         'asserts kind in ("train", "serve"); papers100M-sage.train-sharded4 is of kind '
@@ -130,6 +135,18 @@ OUTGROWN = {
     "test_what_each_pr_appended_stands_together_in_order_and_this_prs_comes_last":
         "asserts that PR 32's five are the last five of per_layer; PR 34's five are appended "
         "after them (test_qbench_gat_manifest.py holds the order PR by PR, with no last place)",
+    "test_qbench_gat_manifest.py::test_the_metrics_this_cell_added_are_its_own":
+        "asserts that model_device_ms.train lists the attention cell alone; PR 38's typed "
+        "cell reports it too (test_qbench_rgat_manifest.py holds the rest)",
+    "test_qbench_gat_manifest.py::"
+    "test_each_new_metric_loads_by_name_with_a_reader_the_benchmark_had[model_device_ms.train]":
+        "asserts that no other cell reports model_device_ms.train; PR 38's typed cell does",
+    "test_qbench_gat_manifest.py::test_the_cell_loads_by_name_with_the_siblings_traffic":
+        "asserts that the attention cell is the last of workloads and of the shared metrics' "
+        "lists; PR 38 appended the typed cell (test_qbench_rgat_manifest.py holds the rest)",
+    "test_qbench_gat_manifest.py::test_the_configuration_is_the_siblings_with_the_model_changed":
+        "asserts that igb-small-gat is the last of configs; PR 38 appended igbh-small-rgat "
+        "(test_qbench_rgat_manifest.py holds the rest)",
 }
 
 

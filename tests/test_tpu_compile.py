@@ -37,6 +37,7 @@ feature tables, which decide what fits, keep their full size);
 prints what the compiler reports.
 """
 
+import collections
 import os
 import re
 import sys
@@ -696,6 +697,182 @@ def test_feature_gather_is_the_row_gather_alone_on_v5e(
         4 if program is _padded_gather_ordered else 2), ops
 
 
+# igbh-small-rgat.train-dedup (qbench/workloads): IGBH-small's typed stand-in on
+# ONE chip, the 6.41 GB bfloat16 table and seven relations resident beside the step
+RGAT_CELL = "igbh-small-rgat.train-dedup"
+RGAT_RESIDENT = 6.41e9 + 0.66e9  # the table; the relations' tiles and lane rows (the sampler's arguments)
+
+
+def _rgat_plan(batch=1024):
+    from qbench import manifest
+    from quiver_tpu.pyg.hetero_sampler import SamplePlan, relation_key
+
+    cell = manifest.load_cell(RGAT_CELL)
+    cfg, caps = cell.config, cell.traffic["caps"]
+    types = tuple(cfg["node_types"])
+    rels = tuple(tuple(r["relation"]) for r in cfg["relations"])
+    return cfg, SamplePlan(
+        types, tuple(cfg["node_types"].values()), rels, cfg["seed_type"], tuple(cfg["fanout"]),
+        tuple(tuple(caps[t][l] for t in types) for l in range(len(cfg["fanout"]))),
+        tuple((cell.traffic["layouts"][relation_key(r)], False, 0) for r in rels))
+
+
+def compile_rgat_step(v5e, batch=1024):
+    """The benchmark's typed step (`qbench.kinds.train.make_train_step` over
+    `RGAT` as `qbench/configs/igbh-small-rgat.json` states it) over typed
+    blocks at the cell's per-(hop, type) caps: (compiled, the entry's
+    operations as a device trace names them)."""
+    from qbench.kinds import train
+    from quiver_tpu.models import RGAT
+    from quiver_tpu.pyg import HeteroBlocks
+    from quiver_tpu.pyg.hetero_sampler import relation_key
+    from quiver_tpu.pyg.sage_sampler import DenseAdj
+
+    cfg, plan = _rgat_plan(batch)
+    widths = plan.widths(batch)
+    scalar = _sds((), jnp.int32)
+    layers = tuple({relation_key(r): DenseAdj(_sds((widths[l][r[2]], k), jnp.int32),
+                                              _sds((widths[l][r[2]], k), jnp.bool_), scalar, scalar)
+                    for r in plan.relations if r[2] in widths[l]}
+                   for l, k in enumerate(plan.sizes))[::-1]
+    blocks = HeteroBlocks(layers, tuple((t, widths[-1][t]) for t in plan.node_types
+                                        if t in widths[-1]))
+    x = _sds((sum(widths[-1].values()), cfg["feat_dim"]), jnp.bfloat16)
+    model = RGAT(out_dim=cfg["classes"], hidden_dim=cfg["hidden_dim"], heads=cfg["heads"],
+                 num_layers=cfg["num_layers"], relation_aggr=cfg["relation_aggr"], dropout=0.0)
+    tx = optax.adam(cfg["lr"])
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    params = jax.eval_shape(model.init, key, x, blocks)
+    args = (params, jax.eval_shape(tx.init, params), key, x, blocks, _sds((batch,), jnp.int32))
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    compiled = train.make_train_step(model, tx, None).lower(*_struct(args, one_chip)).compile()
+    return compiled, _entry_operations(compiled)
+
+
+def test_rgat_step_fits_a_v5e_beside_the_table_and_its_metrics_patterns_meet_operations(v5e):
+    """The typed cell's step at batch 1024: it loads beside the 6.41 GB table
+    and the relations (the first layer's relations rematerialised, its input
+    sliced inside them), lays no k-fold rows out, multiplies matrices in the
+    projections and the head alone, and every pattern of `rgat_project_mfu`
+    and `rgat_edge_ms.train` names operations of the optimized program."""
+    import json
+
+    from quiver_tpu.ops import gather_sum
+
+    compiled, operations = compile_rgat_step(v5e)
+    m = compiled.memory_analysis()
+    step = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes
+    print(f"igbh R-GAT step: arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB")
+    assert m.temp_size_in_bytes < 5e9 and step + RGAT_RESIDENT < 0.95 * 16.9e9, step / 1e9
+    text = compiled.as_text()
+    _, plan = _rgat_plan()
+    widths = plan.widths(1024)
+    for l, k in enumerate(plan.sizes):
+        # a layer of one block of targets gathers its k-fold rows at once: by design
+        for w in {w for w in widths[l].values() if w > gather_sum.ATTENTION_BLOCK}:
+            for shape in (f"[{w},{k},4,128]", f"[{k},{w},4,128]", f"[{w * k},512]"):
+                assert f"f32{shape}" not in text, shape
+    assert " dot(" not in text
+    matched = {}
+    for metric in ("rgat_project_mfu", "rgat_edge_ms.train"):
+        with open(os.path.join(REPO, "qbench", "metrics", f"{metric}.json")) as f:
+            params = json.load(f)["params"]
+        assert params["line"] == "ops" and not params.get("exclude")
+        matched[metric] = [op for op in operations
+                           if any(re.search(p, op) for p in params["include"])]
+    # each of the first layer's 7 relations: two projections forward, again in the
+    # backward pass (rematerialised), and the two weight gradients (with Adam's update)
+    assert len(matched["rgat_project_mfu"]) == 42, len(matched["rgat_project_mfu"])
+    assert all(" fusion(" in op for op in matched["rgat_project_mfu"])
+    # one loop forward and one backward for each relation whose targets span more
+    # than one block, and none for the others (a one-block loop is unrolled): the
+    # relations `train_rgat.block_sizes` marks `looped`, whose bytes alone
+    # `rgat_edge_roofline` counts. A loop is told by its slots' [blocks, block, k]
+    loops = matched["rgat_edge_ms.train"]
+    assert all(" while(" in op for op in loops)
+    blocks = collections.Counter()
+    for l, k in enumerate(plan.sizes):
+        for r in plan.relations:
+            w = widths[l].get(r[2], 0)
+            if w > gather_sum.ATTENTION_BLOCK:
+                n = -(-w // gather_sum.ATTENTION_BLOCK)
+                blocks[(n, -(-w // (8 * n)) * 8, k)] += 2
+    assert sum(blocks.values()) == 18  # 6 relations of the first layer, 3 of the second
+    slots = (re.search(r"s32\[(\d+),(\d+),(\d+)\]", op).groups() for op in loops)
+    got = collections.Counter(tuple(int(v) for v in g) for g in slots)
+    assert got == blocks, (got, blocks)
+
+
+def compile_hetero_sample_program(v5e, batch=1024):
+    """`HeteroSampler.sample_dense`'s one program at the typed cell's caps and
+    relation sizes, the graph handed over as shapes: (memory_analysis,
+    seconds). About three minutes here: the script's list alone runs it."""
+    from quiver_tpu.pyg.hetero_sampler import hetero_sample_program
+
+    cfg, plan = _rgat_plan(batch)
+    edges = {tuple(r["relation"]): r.get("n_edges") for r in cfg["relations"]}
+    for r in cfg["relations"]:
+        if "reverse_of" in r:
+            edges[tuple(r["relation"])] = edges[tuple(r["reverse_of"])]
+    graphs = tuple(
+        (_sds((cfg["node_types"][rel[2]], 2), jnp.int32),
+         _sds((TRAIN_CELLS["igb-small-sage.train-dedup"]["tile_rows"] if hop[0] == "tiled"
+               else -(-edges[rel] // LANE), LANE), jnp.int32))
+        for rel, hop in zip(plan.relations, plan.hops))
+    key0 = jax.eval_shape(lambda: jax.random.key(0))
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    t0 = time.time()
+    compiled = hetero_sample_program.lower(
+        *_struct((key0, _sds((), jnp.uint32), _sds((batch,), jnp.int32), graphs), one_chip),
+        plan=plan).compile()
+    return compiled.memory_analysis(), time.time() - t0
+
+
+# sha256 (16 hex digits) of the lowered text, debug information off and the Pallas
+# kernel's serialised body (which holds its source path) left out, of the steps of
+# the attention and the igb SAGE cells and of the igb cell's sampler program, as
+# the parent commit f9d9c48 lowers them for the described chip (PR 38)
+PARENT_PROGRAMS = {"gat": "e713996108c8312f", "sage": "bf403ecebd8f3c23",
+                   "sampler": "66cc6c057f78ed4e"}
+
+
+def _lowered_digest(lowered):
+    import hashlib
+
+    text = re.sub(r'backend_config = "\{\\22custom_call_config\\22: \{\\22body\\22: '
+                  r'\\22[A-Za-z0-9+/=]*\\22', 'backend_config = "KERNEL',
+                  lowered.as_text(debug_info=False))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("program", sorted(PARENT_PROGRAMS))
+def test_the_homogeneous_programs_lower_to_the_parents(v5e, program):
+    """PR 38 added typed sampling beside `local_reindex` (now sharing its body
+    with `segment_reindex`) and a bipartite attention beside the GAT's: the
+    programs of the cells that do not use them are the parent's, text for text."""
+    from qbench.kinds import train
+    from quiver_tpu.models import GAT, GraphSAGE
+    from quiver_tpu.pyg.sage_sampler import sample_dense_program
+
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    tx = optax.adam(0.01)
+    if program == "sampler":
+        key0 = jax.eval_shape(lambda: jax.random.key(0))
+        graph = (_sds((1_000_000, 2), jnp.int32), _sds((1_007_613, LANE), jnp.int32))
+        lowered = sample_dense_program.lower(
+            *_struct((key0, _sds((), jnp.uint32), _sds((IGB["batch"],), jnp.int32), graph),
+                     one_chip),
+            sizes=IGB["fanout"], caps=IGB_CAPS, dedup=True, hop=("tiled", False, 512))
+    else:
+        model = (GAT(hidden_dim=128, out_dim=19, heads=4, out_heads=4, num_layers=2, dropout=0.0,
+                     activation=jax.nn.relu) if program == "gat" else
+                 GraphSAGE(hidden_dim=128, out_dim=19, num_layers=2, dropout=0.0))
+        lowered = train.make_train_step(model, tx, None).lower(
+            *_struct(_igb_step_args(model, tx, IGB_CAPS), one_chip))
+    assert _lowered_digest(lowered) == PARENT_PROGRAMS[program]
+
+
 if __name__ == "__main__":
     from jax.experimental import topologies
 
@@ -722,6 +899,11 @@ if __name__ == "__main__":
          lambda: compile_igb_model_step(desc, IGB_CAPS_DEFAULT)[1] / 2**30),
         ("igb GAT step (the attention cell): temporaries GiB",
          lambda: compile_igb_gat_step(desc)[0].memory_analysis().temp_size_in_bytes / 2**30),
+        ("igbh R-GAT step (the typed cell): temporaries GiB",
+         lambda: compile_rgat_step(desc)[0].memory_analysis().temp_size_in_bytes / 2**30),
+        ("typed sample program (the typed cell): temporaries GiB, compile seconds",
+         lambda: [(m.temp_size_in_bytes / 2**30, sec) for m, sec in
+                  [compile_hetero_sample_program(desc)]][0]),
         *((f"sample_dense program, {cell}: temporaries GiB, compile seconds",
            lambda shape=shape: [(m.temp_size_in_bytes / 2**30, s) for _, m, s in
                                 [compile_sample_dense_program(desc, **shape)]][0])
