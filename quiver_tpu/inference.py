@@ -576,13 +576,13 @@ class BucketPrograms:
                 )
             self.compile_bucket(int(bucket), params)
             exe = self._exes[int(bucket)]
-        # cast on the HOST: jnp.asarray(int64 ids, int32) is an eager
-        # device-side convert that compiles once per bucket shape — on the
-        # live path, after warmup() sealed the table (chip_smoke counts it)
-        seeds = jnp.asarray(np.asarray(seeds, np.dtype(self._id_dtype)))
-        extra = tuple(
-            jnp.asarray(np.asarray(e, np.float32)) for e in extra
-        )
+        # cast on the HOST and hand the executable host arrays: its one C++
+        # call transfers them with the call index. A device-side convert
+        # would be an eager program compiled per bucket shape after warmup()
+        # sealed the table, and an eager transfer before the call is one
+        # more point where the dispatching thread gives up the interpreter
+        seeds = np.asarray(seeds, np.dtype(self._id_dtype))
+        extra = tuple(np.asarray(e, np.float32) for e in extra)
         table, imap, graph = (
             binding if binding is not None
             else (self._table, self._map, self._graph)

@@ -1003,8 +1003,12 @@ class ServeStats:
     sample + forward ledger; the split sample leg is itself op-by-op
     eager dispatch, so 2 is that ledger's floor, not an op count). The 2→1
     dispatch claim is OBSERVABLE as ``execute_calls == dispatches`` on a
-    fused engine, not inferred. ``late_admitted`` counts seeds admitted
-    into an assembled flush's pad lanes (recovered bucket slack)."""
+    fused engine, not inferred. Both are taken when the flush resolves,
+    from what its dispatch stage recorded, so the stage itself takes no
+    lock (a flush still in its dispatch is not counted yet; a routed
+    engine's own dispatch counts neither). ``late_admitted`` counts seeds
+    admitted into an assembled flush's pad lanes (recovered bucket
+    slack)."""
 
     requests: int = 0
     coalesced: int = 0
@@ -1181,7 +1185,7 @@ class _Flush:
     __slots__ = ("keys", "slots", "params", "seeds", "bucket", "ds", "call",
                  "padded", "extra", "error", "fid", "ids", "rids",
                  "tenant_ix", "graph_version", "binding", "t_begin",
-                 "t_dispatch", "t_done")
+                 "t_dispatch", "t_done", "n_exec")
 
     def __init__(self, keys, slots, params):
         self.keys = keys
@@ -1213,6 +1217,10 @@ class _Flush:
         # split `_observe_stages` adds when tracing: the call of `flush()`
         # (read only while tracing) and the dispatch stage's two
         self.t_begin = self.t_dispatch = self.t_done = 0.0
+        # what `ServeEngine._dispatch` ran, counted into ServeStats at
+        # resolve: None = the stage was not entered, else the device
+        # program legs that returned (0 when the stage raised first)
+        self.n_exec: Optional[int] = None
 
 
 def _admit_chunk_fast(eng, keys, nodes, tenants, i, now, events,
@@ -2208,27 +2216,27 @@ class ServeEngine:
         return sample_batch(self._sampler, padded)
 
     def _dispatch(self, fl: _Flush) -> Optional[np.ndarray]:
-        """Stage 2 (no engine lock held): the device work + blocking D2H —
-        ONE pre-bound execute call on the fused path, the round-9
-        sample(-in-assemble) + forward pair on the split path. Concurrent
-        across flushes up to the window bound."""
-        with self._lock:
-            self.stats.dispatch_calls += 1
+        """Stage 2 (no engine lock held, none taken): the device work +
+        blocking D2H — ONE pre-bound execute call on the fused path, the
+        round-9 sample(-in-assemble) + forward pair on the split path.
+        Concurrent across flushes up to the window bound. What it ran is
+        recorded on the flush (``fl.n_exec``) and counted into
+        ``stats.dispatch_calls`` / ``execute_calls`` by `_resolve`, under
+        the lock it holds anyway."""
+        fl.n_exec = 0
         self.journal.emit("dispatch", -1, fl.fid, fl.bucket)
         if fl.ds is None and self._programs is not None:
             logits = np.asarray(
                 self._programs(fl.bucket, fl.params, fl.call, fl.padded,
                                *(fl.extra or ()), binding=fl.binding)
             )
-            n_exec = 1
+            fl.n_exec = 1
         else:
             logits = np.asarray(
                 forward_logits(self._apply, fl.params, self._feature, fl.ds)
             )
-            n_exec = 2  # the sample leg ran in _seal_assembled
-        with self._lock:
-            self.stats.execute_calls += n_exec
-        self.journal.emit("execute_done", -1, fl.fid, n_exec)
+            fl.n_exec = 2  # the sample leg ran in _seal_assembled
+        self.journal.emit("execute_done", -1, fl.fid, fl.n_exec)
         # rows of this array are handed to every waiter AND the cache;
         # read-only makes an in-place mutation by one caller a loud
         # ValueError instead of silently corrupting every later cache hit
@@ -2280,6 +2288,9 @@ class ServeEngine:
                         ms = (now - t0) * 1e3
                         self.stats.latency.record_ms(ms)
                         self.stats.tenant_hist(tenant).record_ms(ms)
+            if fl.n_exec is not None:
+                self.stats.dispatch_calls += 1
+                self.stats.execute_calls += fl.n_exec
             if fl.error is None:
                 self.stats.dispatches += 1
                 self.stats.dispatched_seeds += len(fl.keys)

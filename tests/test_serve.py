@@ -744,6 +744,138 @@ def test_bucket_programs_take_the_call_index(setup):
     assert programs._sampler._call == 0     # the program consumes no index
 
 
+def make_temporal_sampler():
+    from quiver_tpu.workloads import TemporalTiledGraph
+
+    topo = CSRTopo(edge_index=make_random_graph(N_NODES, 2000, seed=0))
+    ts = np.random.default_rng(11).uniform(
+        0.0, 50.0, topo.indices.shape[0]
+    ).astype(np.float32)
+    sampler = GraphSageSampler(topo, sizes=SIZES, mode="TPU",
+                               seed=SAMPLER_SEED, dedup=False, max_deg=128)
+    return sampler.bind_temporal(TemporalTiledGraph(topo, ts), recency=0.02)
+
+
+@pytest.mark.parametrize("temporal", [False, True], ids=["plain", "temporal"])
+def test_bucket_programs_hand_the_executable_host_arrays(
+        setup, monkeypatch, temporal):
+    """A fused call hands the executable its padded seeds (and the temporal
+    program's query times) as host arrays, and the executable's own call
+    moves them: once a bucket has run, a call makes no eager transfer, runs
+    no eager primitive and stays on JAX's C++ call path, and its rows are
+    the unfused path's at the same call index, bit for bit."""
+    from jax._src import api, dispatch, stages
+
+    from quiver_tpu.inference import BucketPrograms, forward_logits
+
+    model, params, feat = setup
+    make = make_temporal_sampler if temporal else make_sampler
+    programs = BucketPrograms(model, make(), feat)
+    apply = _cached_apply(model)
+    padded = pad_seed_batch(np.arange(5, dtype=np.int64) * 3, 8)
+    extra = (np.linspace(5.0, 60.0, 8).astype(np.float32),) if temporal else ()
+    programs(8, params, 0, padded, *extra)     # compiles; fills the C++ path
+    counts = dict.fromkeys(("transfer", "primitive", "python_call"), 0)
+
+    def counting(kind, fn):
+        def wrapper(*args, **kwargs):
+            counts[kind] += 1
+            return fn(*args, **kwargs)
+        return wrapper
+
+    for mod, name in ((jax, "device_put"), (api, "device_put"),
+                      (jnp, "asarray"), (jnp, "array")):
+        monkeypatch.setattr(mod, name, counting("transfer", getattr(mod, name)))
+    monkeypatch.setattr(dispatch, "xla_primitive_callable", counting(
+        "primitive", dispatch.xla_primitive_callable))
+    monkeypatch.setattr(stages.Compiled, "call", staticmethod(counting(
+        "python_call", stages.Compiled.call)))
+    got = {}
+    for call in (4, 2**31 + 7):
+        got[call] = np.asarray(programs(8, params, call, padded, *extra))
+    monkeypatch.undo()
+    assert counts == dict.fromkeys(counts, 0), counts
+    for call, rows in got.items():
+        twin = make()
+        twin._call = call
+        if temporal:
+            ds = twin.sample_dense(padded, t=extra[0])
+            want = np.asarray(forward_logits(apply, params, feat, ds))
+        else:
+            want = np.asarray(batch_logits(apply, params, twin, feat, padded))
+        assert np.array_equal(rows.view(np.uint32), want.view(np.uint32))
+
+
+def test_dispatch_counters_are_taken_at_resolve(setup):
+    """`ServeStats.dispatch_calls` / `execute_calls` keep their meaning with
+    `_resolve` counting them: a clean run reads both equal to `dispatches`,
+    a dispatch that raises counts as a dispatch call that ran no program,
+    and a flush whose seal raises never entered the stage. The dispatch
+    stage takes no engine lock: it runs to its end while another thread
+    holds it. The log still replays bit for bit."""
+
+    class Boom(RuntimeError):
+        pass
+
+    eng = make_engine(setup, max_batch=8, max_delay_ms=1e9)
+    eng.warmup()
+    staged = eng._dispatch
+
+    def dispatch_while_the_lock_is_held(fl):
+        held, release, result = threading.Event(), threading.Event(), {}
+
+        def hold():
+            with eng._lock:
+                held.set()
+                release.wait(10)
+
+        def run():
+            try:
+                result["rows"] = staged(fl)
+            except BaseException as exc:
+                result["error"] = exc
+
+        holder = threading.Thread(target=hold)
+        holder.start()
+        assert held.wait(5)
+        worker = threading.Thread(target=run)
+        worker.start()
+        worker.join(5)
+        finished = not worker.is_alive()
+        release.set()
+        holder.join(5)
+        worker.join(5)
+        assert finished, "the dispatch stage waited for the engine lock"
+        if "error" in result:
+            raise result["error"]
+        return result["rows"]
+
+    eng._dispatch = dispatch_while_the_lock_is_held
+    trace = zipfian_trace(N_NODES, 40, alpha=0.9, seed=7)
+    rows = eng.predict(trace)
+    st = eng.stats
+    n = st.dispatches
+    assert n > 0 and st.dispatch_calls == st.execute_calls == n
+    oracle = replay_oracle(setup, eng)
+    for nid, row in zip(trace, rows):
+        assert np.array_equal(row, oracle[int(nid)])
+    fresh = iter(sorted(set(range(N_NODES)) - {int(i) for i in trace}))
+    del eng._programs._exes[1]              # the sealed table now refuses bucket 1
+    eng.submit(next(fresh))
+    with pytest.raises(RuntimeError, match="no pre-bound executable"):
+        eng.flush()
+    assert (st.dispatch_calls, st.execute_calls, st.dispatches) == (n + 1, n, n)
+
+    def broken(*_a, **_k):
+        raise Boom("call index")
+
+    eng._sampler.next_call = broken
+    eng.submit(next(fresh))
+    with pytest.raises(Boom):
+        eng.flush()
+    assert (st.dispatch_calls, st.execute_calls, st.dispatches) == (n + 1, n, n)
+
+
 def test_dispatch_mode_validation_and_forced_fused(setup):
     model, params, feat = setup
     with pytest.raises(ValueError, match="dispatch_mode"):
